@@ -19,6 +19,9 @@ def main() -> None:
     from benchmarks import (complexity_reduction, fa_overhead,
                             roofline_table, serving, spatial, throughput,
                             topk_hit)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     modules = [fa_overhead, complexity_reduction, topk_hit, throughput,

@@ -810,7 +810,7 @@ def phase_spatial_child(out_path: str) -> None:
 
 def phase_breakdown_spatial() -> dict:
     """Run the 2-shard phase measurement in a fake-device subprocess
-    (the parent's XLA device count is already fixed)."""
+    (the parent's XLA device count is already fixed). CPU only."""
     import subprocess
     import tempfile
     from repro.spatial.topology import FORCE_FLAG
@@ -835,8 +835,14 @@ def phase_breakdown_spatial() -> dict:
 
 
 def phase_breakdown(cfg, params) -> dict:
-    return {"paged": phase_breakdown_paged(cfg, params),
-            "spatial_2shard": phase_breakdown_spatial()}
+    from repro.spatial.topology import cpu_only
+    m = {"paged": phase_breakdown_paged(cfg, params)}
+    if cpu_only():
+        m["spatial_2shard"] = phase_breakdown_spatial()
+    else:
+        print("phase_breakdown: spatial_2shard skipped: its child runs on "
+              "fake CPU devices (set JAX_PLATFORMS=cpu)", file=sys.stderr)
+    return m
 
 
 def _phase_breakdown(cfg, params, results):
@@ -1352,15 +1358,17 @@ if __name__ == "__main__":
     ap.add_argument("--phase-spatial", metavar="PATH", default=None,
                     help=argparse.SUPPRESS)   # internal child entrypoint
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.phase_spatial:
         phase_spatial_child(args.phase_spatial)
         sys.exit(0)
-    if args.spatial and len(jax.devices()) < max(SPATIAL_SHARDS):
-        from repro.spatial import respawn_with_devices
+    if args.spatial:
+        from repro.spatial import require_devices
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         argv = ["-m", "benchmarks.serving", "--spatial"] + \
             (["--json", os.path.abspath(args.json)] if args.json else [])
-        sys.exit(respawn_with_devices(max(SPATIAL_SHARDS), argv, cwd=repo))
+        require_devices(max(SPATIAL_SHARDS), argv, cwd=repo)
     print("name,us_per_call,derived")
     if args.decode_sparse:
         run_decode_sparse(json_path=args.json)

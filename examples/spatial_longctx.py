@@ -13,11 +13,10 @@ front door's QoS path on the same mesh. The request mix comes from the
 same scenario builder the spatial benchmark uses
 (``repro.serving.scenarios.longctx_mix``).
 
-Run:  PYTHONPATH=src python examples/spatial_longctx.py
-(relaunches itself with xla_force_host_platform_device_count=4)
+Run:  JAX_PLATFORMS=cpu PYTHONPATH=src python examples/spatial_longctx.py
+(relaunches itself with xla_force_host_platform_device_count=4; on a host
+with 4 chips it runs on them directly)
 """
-
-import sys
 
 N_SHARDS = 4
 
@@ -89,8 +88,6 @@ def main():
 
 
 if __name__ == "__main__":
-    import jax
-    if len(jax.devices()) < N_SHARDS:
-        from repro.spatial import respawn_with_devices
-        sys.exit(respawn_with_devices(N_SHARDS, [__file__]))
+    from repro.spatial import require_devices
+    require_devices(N_SHARDS, [__file__])
     main()

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 
 from repro.core.sads import NEG_INF
 from repro.core.star_attention import STARConfig, star_attention
-from repro.shardlib import pvary, shard_map
 
 
 def _local_attn_stats(q, k, v, *, scale, mask):
@@ -92,7 +91,7 @@ def dr_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 (qc, m, l, o, owner), axis, perm)
             return (qc, m, l, o, owner), None
 
-        vary = lambda x: pvary(x, (axis,))
+        vary = lambda x: jax.lax.pcast(x, (axis,), to="varying")
         init = (q_loc,
                 vary(jnp.full((chunk,), NEG_INF, jnp.float32)),
                 vary(jnp.zeros((chunk,), jnp.float32)),
@@ -103,7 +102,7 @@ def dr_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out = o / jnp.maximum(l, 1e-30)[:, None]
         return out.astype(q_loc.dtype)
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P(axis), P(axis), P(axis)),
                        out_specs=P(axis))
     return fn(q, k, v)
@@ -140,7 +139,7 @@ def distributed_decode_merge(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out = o_g[0] / jnp.maximum(l_g[0], 1e-30)
         return out.astype(k_loc.dtype)
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P(), P(axis), P(axis)),
                        out_specs=P())
     return fn(q, k, v)

@@ -16,12 +16,16 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
+_LANES = 128
 
 
 def _pow2_bitwise(x: jax.Array) -> jax.Array:
@@ -59,13 +63,17 @@ def _dlzs_kernel(q_ref, k_ref, bmax_ref, *, scale: float, causal: bool,
         kv_pos = ki * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_kv), 1)
         s = jnp.where(kv_pos <= q_pos, s, NEG_INF)
-    bmax_ref[0, 0, 0] = s.max()
+    # one predicted max per (q_tile, kv_tile), broadcast over a lane
+    # row so the output block keeps a full (1, 128) minor tile
+    bmax_ref[...] = jnp.full(bmax_ref.shape, s.max(), jnp.float32)
 
 
 def dlzs_block_scores(q: jax.Array, k: jax.Array, *, causal: bool = True,
                       scale: float | None = None, block_q: int = 128,
-                      block_kv: int = 128, interpret: bool = True):
+                      block_kv: int = 128,
+                      interpret: Optional[bool] = None):
     """q [BH, T, d], k [BH, S, d] -> predicted block maxima [BH, n_qt, n_kt].
+    ``interpret`` None resolves by ``repro.kernels.resolve_interpret``.
     """
     bh, t, d = q.shape
     s = k.shape[1]
@@ -84,8 +92,10 @@ def dlzs_block_scores(q: jax.Array, k: jax.Array, *, causal: bool = True,
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((bh, n_qt, n_kt), jnp.float32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((1, 1, 1, 1, _LANES),
+                               lambda b, i, j: (b, i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, n_qt, n_kt, 1, _LANES),
+                                       jnp.float32),
+        interpret=resolve_interpret(interpret),
     )(q, k)
-    return bmax
+    return bmax[..., 0, 0]

@@ -1,29 +1,34 @@
 """FlashAttention-2 Pallas TPU kernel — the paper's formal-compute baseline.
 
 Grid (batch*heads, q_tiles, kv_tiles); the kv dim is the innermost
-(sequential on TPU), so the (m, l, o) accumulators live in revisited output
-blocks in VMEM across kv steps — the standard TPU flash pattern. Block
-shapes are explicit BlockSpecs sized for VMEM (q/k/v tiles of
-[block x head_dim], fp32 accumulator [block_q x head_dim]).
+(sequential on TPU), so the (m, l, o) accumulators live in VMEM scratch
+across kv steps and the normalized tile is written at the last kv step —
+the standard TPU flash pattern. Block shapes are explicit BlockSpecs sized
+for VMEM (q/k/v tiles of [block x head_dim], fp32 accumulator
+[block_q x head_dim]).
 
 This kernel intentionally keeps FA-2's per-tile max refresh + rescale — the
 overhead SU-FA (kernels/sufa.py) removes. Validated in interpret mode vs
-ref.flash_ref; on a real TPU the same code lowers to Mosaic.
+ref.flash_ref; on a TPU the same code lowers to Mosaic.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
                   scale: float, causal: bool, block_q: int, block_kv: int,
                   q_offset: int = 0):
     qi = pl.program_id(1)
@@ -31,9 +36,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
 
     @pl.when(ki == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
 
     q = q_ref[0].astype(jnp.float32)                 # [Bq, d]
     k = k_ref[0].astype(jnp.float32)                 # [Bc, d]
@@ -47,28 +52,31 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
             jnp.int32, (block_q, block_kv), 1)
         s = jnp.where(kv_pos <= q_pos, s, NEG_INF)
 
-    m_prev = m_ref[0]                                # [Bq]
-    l_prev = l_ref[0]
+    m_prev = m_sc[...]                               # [Bq, 1]
+    l_prev = l_sc[...]
     # FA-2 line 5-8: per-tile max refresh + accumulator rescale.
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
-    p = jnp.exp(s - m_new[:, None])
+    p = jnp.exp(s - m_new)
     p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-    l_new = l_prev * alpha + p.sum(axis=-1)
-    o_new = o_ref[0] * alpha[:, None] + jnp.dot(
+    m_sc[...] = m_new
+    l_sc[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+    acc_sc[...] = acc_sc[...] * alpha + jnp.dot(
         p, v, preferred_element_type=jnp.float32)
 
-    m_ref[0] = m_new
-    l_ref[0] = l_new
-    o_ref[0] = o_new
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: float | None = None,
                     block_q: int = 128, block_kv: int = 128,
-                    interpret: bool = True):
-    """q [BH, T, d], k/v [BH, S, d] -> [BH, T, d] (fp32 accumulate)."""
+                    interpret: Optional[bool] = None):
+    """q [BH, T, d], k/v [BH, S, d] -> [BH, T, d] (fp32 accumulate).
+    ``interpret`` None resolves by ``repro.kernels.resolve_interpret``."""
     bh, t, d = q.shape
     s = k.shape[1]
     scale = scale or (1.0 / math.sqrt(d))
@@ -79,7 +87,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_kv=block_kv,
                                q_offset=s - t)
-    o, m, l = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -87,17 +95,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
+            pltpu.VMEM((block_q, d), jnp.float32),   # unnormalized o
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, t), jnp.float32),
-            jax.ShapeDtypeStruct((bh, t), jnp.float32),
-        ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
-    out = o / jnp.maximum(l, 1e-30)[..., None]
-    return out.astype(q.dtype)

@@ -5,8 +5,8 @@
   -> jax.lax.top_k over the block-max matrix (SADS tile selection, desc)
   -> XLA gather of the selected KV tiles
   -> sufa_attention (descend-updating block-sparse flash).
-Interpret mode executes the kernel bodies on CPU for validation; on TPU the
-same calls lower to Mosaic.
+``interpret=None`` resolves by ``repro.kernels.resolve_interpret``: the
+kernel bodies run in the interpreter off the TPU and lower to Mosaic on it.
 """
 
 from __future__ import annotations
@@ -27,13 +27,13 @@ NEG_INF = -1e30
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_kv",
                                              "interpret"))
 def flash(q, k, v, *, causal=True, block_q=128, block_kv=128,
-          interpret=True):
+          interpret=None):
     return flash_attention(q, k, v, causal=causal, block_q=block_q,
                            block_kv=block_kv, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("strict", "interpret"))
-def sufa(q, kg, vg, mask, *, strict=False, interpret=True):
+def sufa(q, kg, vg, mask, *, strict=False, interpret=None):
     return sufa_attention(q, kg, vg, mask, strict=strict,
                           interpret=interpret)
 
@@ -41,7 +41,7 @@ def sufa(q, kg, vg, mask, *, strict=False, interpret=True):
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_kv",
                                              "interpret"))
 def dlzs_blockmax(q, k, *, causal=True, block_q=128, block_kv=128,
-                  interpret=True):
+                  interpret=None):
     return dlzs_block_scores(q, k, causal=causal, block_q=block_q,
                              block_kv=block_kv, interpret=interpret)
 
@@ -50,7 +50,7 @@ def dlzs_blockmax(q, k, *, causal=True, block_q=128, block_kv=128,
     "causal", "block_q", "block_kv", "keep", "strict", "interpret"))
 def star_attention_fused(q, k, v, *, keep: int, causal=True, block_q=128,
                          block_kv=128, radius=5.0, strict=False,
-                         interpret=True):
+                         interpret=None):
     """Full kernel-side STAR pipeline. q/k/v [BH, T|S, d] -> [BH, T, d]."""
     bh, t, d = q.shape
     s = k.shape[1]

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import math
 import os
+import warnings
 from typing import Optional
 
 import jax
@@ -41,8 +42,9 @@ def default_backend() -> str:
     on CPU. Note the engine's decode path reads this inside a jitted
     function, so the override is captured at FIRST COMPILATION per engine:
     set the env var before constructing the engine, not between steps.
-    Tests assert kernel/fallback parity in interpret mode, so the numerics
-    are identical either way.
+    Tests assert kernel/fallback parity in interpret mode and
+    ``chip_smoke.py`` on the chip, so the numerics agree to bf16 rounding
+    either way.
     """
     env = os.environ.get("REPRO_PAGED_BACKEND", "").strip().lower()
     if env:
@@ -51,12 +53,6 @@ def default_backend() -> str:
                 f"REPRO_PAGED_BACKEND={env!r}: choose from {_BACKENDS}")
         return env
     return "pallas" if jax.default_backend() == "tpu" else "xla"
-
-
-def default_interpret() -> bool:
-    """Pallas interpret mode: False on real TPU (lower to Mosaic), True
-    anywhere else so a forced ``REPRO_PAGED_BACKEND=pallas`` still runs."""
-    return jax.default_backend() != "tpu"
 
 
 def _group(q: jax.Array, n_kv: int) -> jax.Array:
@@ -218,20 +214,22 @@ def paged_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                  phys: jax.Array, logical: jax.Array, kv_len: jax.Array, *,
                  n_kv: int, scale: Optional[float] = None,
                  backend: Optional[str] = None,
-                 interpret: Optional[bool] = None,
                  quant=None) -> jax.Array:
     """Backend dispatch. ``backend``: 'xla' (gather fallback) or 'pallas'
     (block-table kernel); None resolves via ``default_backend()`` —
-    pallas on TPU, xla elsewhere, ``REPRO_PAGED_BACKEND`` overriding.
-    ``interpret`` only affects the pallas backend: None resolves to False
-    on real TPU (lower to Mosaic) and True anywhere else. ``quant`` (the
+    pallas on TPU, xla elsewhere, ``REPRO_PAGED_BACKEND`` overriding. The
+    kernel compiles to Mosaic on a TPU and runs in the Pallas interpreter
+    anywhere else (``repro.kernels.resolve_interpret``). ``quant`` (the
     int8 cold-tier inputs, see ``_gather_hot``) is served by the XLA
     gather path — the Pallas kernel has no dequant lane yet, so a quant
-    request falls back to XLA regardless of ``backend``."""
+    request falls back to XLA regardless of ``backend``, with a warning
+    at trace time."""
     if backend is None:
         backend = default_backend()
-    if interpret is None:
-        interpret = default_interpret()
+    if backend == "pallas" and quant is not None:
+        warnings.warn("paged decode: the int8 cold tier has no Pallas "
+                      "lane; this step runs the XLA gather instead",
+                      stacklevel=2)
     if backend == "xla" or quant is not None:
         return paged_gather_decode(q, k_pages, v_pages, phys, logical,
                                    kv_len, n_kv=n_kv, scale=scale,
@@ -242,10 +240,7 @@ def paged_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     b, nh, d = q.shape
     scale = scale or (1.0 / math.sqrt(d))
     qg = _group(q, n_kv)
-    # pool slab [P, page, nkv, d] -> kernel layout [nkv, P, page, d]
-    kh = jnp.moveaxis(k_pages, 2, 0)
-    vh = jnp.moveaxis(v_pages, 2, 0)
-    o = kpaged.paged_decode_attention(qg, kh, vh, jnp.maximum(phys, 0),
-                                      logical, kv_len, scale=scale,
-                                      interpret=interpret)
+    o = kpaged.paged_decode_attention(qg, k_pages, v_pages,
+                                      jnp.maximum(phys, 0),
+                                      logical, kv_len, scale=scale)
     return o.reshape(b, nh, d).astype(q.dtype)

@@ -7,10 +7,10 @@ one of the three backends:
   chunked prefill and the preemption scheduler (batched varlen prefill
   with the ``prefill_tokens="auto"`` budget controller by default).
 * ``--engine spatial`` — the sequence-sharded multi-device runtime
-  (``--shards N``): context length scales with device count. When the
-  process has fewer devices than shards it re-executes itself with
-  ``xla_force_host_platform_device_count`` set, so the fake-device
-  harness works out of the box on a laptop.
+  (``--shards N``): context length scales with device count. With
+  ``JAX_PLATFORMS=cpu`` and fewer devices than shards it re-executes
+  itself with ``xla_force_host_platform_device_count`` set (the
+  fake-device harness); on an accelerator it needs N chips.
 * ``--engine dense``   — the retired slot-based engine, kept as the
   parity oracle and footprint baseline (tests/benchmarks); serve it
   only to compare against the pool-backed engines.
@@ -26,9 +26,10 @@ Requests carry an SLA class (``--sla-mix`` cycles interactive / standard
 admitted first and preempted last. ``--sla-deadlines`` enforces the
 SLA-tier default TTFT/end-to-end budgets and ``--shed-watermarks HIGH
 LOW`` turns on hysteresis admission shedding of low-priority traffic
-under backlog (see docs/serving.md, "Robustness"). Smoke configs serve
-on CPU; ``--full --mesh`` builds the production mesh exactly as the
-dry-run does.
+under backlog (see docs/serving.md, "Robustness"). ``--full`` serves the
+architecture at its published widths with seeded random weights; without
+it the smoke config serves. The process exits nonzero when any request
+ends ``failed`` (a backend fault that exhausted its retries).
 
 Telemetry (``repro.obs``, see docs/observability.md) is on by default:
 
@@ -106,14 +107,11 @@ def main(argv=None):
     args = _parse_args(argv)
 
     if args.engine == "spatial":
-        # the XLA device count is fixed at first jax init: grow it in a
-        # child process when this one is too small for the mesh
-        import jax
-        if len(jax.devices()) < args.shards:
-            from repro.spatial import respawn_with_devices
-            sys.exit(respawn_with_devices(
-                args.shards, ["-m", "repro.launch.serve"]
-                + (argv if argv is not None else sys.argv[1:])))
+        # the XLA device count is fixed at first jax init: on the CPU
+        # grow it in a child process, on a chip fail clearly
+        from repro.spatial import require_devices
+        require_devices(args.shards, ["-m", "repro.launch.serve"]
+                        + (argv if argv is not None else sys.argv[1:]))
 
     import dataclasses
     import pathlib
@@ -123,11 +121,13 @@ def main(argv=None):
 
     from repro import obs
     from repro.configs import ARCHS, get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import lm
     from repro.serving import (LLM, AdmissionCfg, EngineCfg,
                                PagedEngineCfg, SchedulerCfg)
     from repro.spatial import SpatialEngineCfg
 
+    enable_compile_cache()
     if args.arch not in ARCHS:
         raise SystemExit(f"unknown arch {args.arch}; choose from "
                          f"{sorted(ARCHS)}")
@@ -189,12 +189,12 @@ def main(argv=None):
 
     rng = np.random.default_rng(0)
     t0 = time.time()
-    for i in range(args.requests):
-        llm.submit(rng.integers(0, cfg.vocab, size=args.prompt_len,
-                                dtype=np.int32),
-                   max_tokens=args.max_tokens,
-                   sla=SLA_CYCLE[i % len(SLA_CYCLE)]
-                   if args.sla_mix else None)
+    handles = [llm.submit(rng.integers(0, cfg.vocab, size=args.prompt_len,
+                                       dtype=np.int32),
+                          max_tokens=args.max_tokens,
+                          sla=SLA_CYCLE[i % len(SLA_CYCLE)]
+                          if args.sla_mix else None)
+               for i in range(args.requests)]
     done = llm.run_until_done()
     rep = llm.metrics()
     n_tok = rep.get("tokens", sum(len(v) for v in done.values()))
@@ -256,6 +256,14 @@ def main(argv=None):
                 pathlib.Path(args.metrics).write_text(text)
                 print(f"[serve] metrics -> {args.metrics} "
                       f"({len(text.splitlines())} lines)")
+
+    # a backend exception is isolated to its requests (retry, then
+    # quarantine): the run still fails if any request ended failed
+    failed = [h.rid for h in handles if h.outcome == "failed"]
+    if failed:
+        raise SystemExit(f"[serve] {len(failed)} of {len(handles)} "
+                         f"requests failed (rids {failed}); see the "
+                         f"fault warnings above")
 
 
 if __name__ == "__main__":

@@ -630,9 +630,13 @@ def apply_decode_spatial(params, cfg: AttentionCfg, x, cache, lengths,
             quant=quant)
 
     def _neutral(_):
-        return (jnp.full((b, g, r), NEG_INF, jnp.float32),
-                jnp.zeros((b, g, r), jnp.float32),
-                jnp.zeros((b, g, r, cfg.head_dim), jnp.float32))
+        # constants are axis-invariant; cast them so both branches vary
+        # over ``axis`` like the gather's outputs
+        return jax.lax.pcast(
+            (jnp.full((b, g, r), NEG_INF, jnp.float32),
+             jnp.zeros((b, g, r), jnp.float32),
+             jnp.zeros((b, g, r, cfg.head_dim), jnp.float32)),
+            (axis,), to="varying")
 
     m, l, o = jax.lax.cond(jnp.any(page_state["logical"] >= 0),
                            _stats, _neutral, None)

@@ -571,8 +571,6 @@ def prefill_chunk_batch_spatial(params, cfg: ModelCfg, batch, cache,
     against its local past pages; the merge is the same pmax/psum tree
     as the per-sequence spatial path (see attention).
     """
-    from repro.shardlib import shard_map
-
     shard_spec, rep_spec = _spatial_specs(mesh, axis)
     sharded = {"past_phys", "past_lane", "past_logical", "chunk_phys"}
     ps_specs = {k: shard_spec if k in sharded else rep_spec
@@ -592,7 +590,7 @@ def prefill_chunk_batch_spatial(params, cfg: ModelCfg, batch, cache,
         logits = _logits(p, cfg, x_last)[0]
         return logits, jax.tree.map(lambda leaf: leaf[None], new_layers)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: rep_spec, params), rep_spec,
                   jax.tree.map(lambda _: shard_spec, cache["layers"]),
@@ -642,8 +640,6 @@ def prefill_chunk_spatial(params, cfg: ModelCfg, batch, cache, chunk_state,
 
     Returns (logits [B, vocab_padded], {"layers": updated stacked slabs}).
     """
-    from repro.shardlib import shard_map
-
     shard_spec, rep_spec = _spatial_specs(mesh, axis)
     sharded = {"past_phys", "past_logical", "chunk_phys"}
     cs_specs = {k: shard_spec if k in sharded else rep_spec
@@ -664,7 +660,7 @@ def prefill_chunk_spatial(params, cfg: ModelCfg, batch, cache, chunk_state,
         logits = _logits(p, cfg, x_last)[:, 0]
         return logits, jax.tree.map(lambda leaf: leaf[None], new_layers)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: rep_spec, params), rep_spec,
                   jax.tree.map(lambda _: shard_spec, cache["layers"]),
@@ -698,8 +694,6 @@ def decode_step_spatial(params, cfg: ModelCfg, tokens, cache, page_state,
     [n_shards, B, W] marks hot slots served from the int8 cold tier
     (kvcache.quant) — present only when ``SchedulerCfg.kv_quant`` is on.
     """
-    from repro.shardlib import shard_map
-
     shard_spec, rep_spec = _spatial_specs(mesh, axis)
 
     def local_fn(p, toks, layers, lengths, ps):
@@ -713,7 +707,7 @@ def decode_step_spatial(params, cfg: ModelCfg, tokens, cache, page_state,
         logits = _logits(p, cfg, x)[:, 0]
         return logits, jax.tree.map(lambda leaf: leaf[None], new_layers)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: rep_spec, params), rep_spec,
                   jax.tree.map(lambda _: shard_spec, cache["layers"]),
@@ -738,8 +732,6 @@ def audit_decode_spatial(params, cfg: ModelCfg, tokens, cache, page_state,
     The cache is NOT returned and the caller must not donate it — the
     probe is read-only from the engine's point of view.
     """
-    from repro.shardlib import shard_map
-
     shard_spec, rep_spec = _spatial_specs(mesh, axis)
 
     def local_fn(p, toks, layers, lengths, ps):
@@ -756,7 +748,7 @@ def audit_decode_spatial(params, cfg: ModelCfg, tokens, cache, page_state,
                          and k.key == "audit_mass" for k in path)]
         return jnp.stack(masses)[None]     # [1, blocks, R, B, W_local]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: rep_spec, params), rep_spec,
                   jax.tree.map(lambda _: shard_spec, cache["layers"]),

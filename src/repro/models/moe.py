@@ -33,7 +33,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models import common
 from repro.shardlib import rules as shr
-from repro.shardlib import shard_map, shd
+from repro.shardlib import shd
 
 
 @dataclasses.dataclass(frozen=True)
@@ -239,7 +239,7 @@ def apply(params, cfg: MoECfg, x):
     if cfg.gated:
         pspecs["w3"] = pspecs["w1"]
 
-    fn = shard_map(local_fn, mesh=mesh,
+    fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(bspec, pspecs),
                        out_specs=(bspec, P()))
     return fn(x, params)

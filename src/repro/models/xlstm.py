@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from repro.models import common
 from repro.models.ssm import chunked_linear_attention, linear_attention_step
-from repro.shardlib import pvary, shard_map, shd
+from repro.shardlib import shd
 
 
 @dataclasses.dataclass(frozen=True)
@@ -184,11 +184,12 @@ def _scan_shardmapped(params, carry, xs):
         ((b_ax,) if isinstance(b_ax, str) else tuple(b_ax))
 
     def local(rp, cr, xs_):
-        # pvary FIRST, over exactly the axes the activations vary on: R
-        # becomes device-varying there, so the recurrent einsum's transpose
-        # needs no per-step psum_invariant — the single psum lands at this
-        # pvary's transpose, outside the 4096-step loop (§Perf cell C5).
-        rp = jax.tree.map(lambda r: pvary(r, vary_axes), rp)
+        # Cast R to varying FIRST, over exactly the axes the activations
+        # vary on, so the recurrent einsum's transpose needs no per-step
+        # psum_invariant — the single psum lands at this cast's
+        # transpose, outside the 4096-step loop (§Perf cell C5).
+        rp = jax.tree.map(
+            lambda r: jax.lax.pcast(r, vary_axes, to="varying"), rp)
         return jax.lax.scan(lambda c, g: _slstm_step(rp, c, g), cr, xs_)
 
     if mesh is None or not vary_axes:
@@ -200,7 +201,7 @@ def _scan_shardmapped(params, carry, xs):
                                    r.shape), rparams)
     state_sp = P(b_ax)
     xs_sp = tuple(P(None, b_ax) for _ in xs)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(rspec, (state_sp,) * 3, xs_sp),
         out_specs=((state_sp,) * 3, P(None, b_ax)))

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.shardlib import pvary, shard_map
-
 
 def gpipe(stage_fn, params, x, *, mesh, axis: str):
     """Run x [M, b, ...] through the stacked stages. Returns [M, b, ...]."""
@@ -58,16 +56,16 @@ def gpipe(stage_fn, params, x, *, mesh, axis: str):
             return (buf, outs), None
 
         buf0 = jnp.zeros_like(x_loc[0])
-        outs0 = pvary(jnp.zeros_like(x_loc), (axis,))
+        outs0 = jax.lax.pcast(jnp.zeros_like(x_loc), (axis,), to="varying")
         (_, outs), _ = jax.lax.scan(
-            tick, (pvary(buf0, (axis,)), outs0),
+            tick, (jax.lax.pcast(buf0, (axis,), to="varying"), outs0),
             jnp.arange(nticks))
         # only the last stage holds real outputs; broadcast them
         outs = jax.lax.psum(
             jnp.where(me == s - 1, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P())

@@ -37,6 +37,7 @@ Most callers should not touch this class directly — the front-door
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 from typing import Optional, Protocol, runtime_checkable
 
@@ -52,6 +53,8 @@ from repro.serving.engine import Request
 from repro.serving.scheduler import (SLA_DEADLINES_MS, ExecFault,
                                      NeedPages, Scheduler, SchedulerCfg)
 from repro.serving.swap_policy import PrefillProgress as _PrefillProgress
+
+log = logging.getLogger(__name__)
 
 
 @runtime_checkable
@@ -518,10 +521,16 @@ class EngineCore:
             self.cancel(rid, outcome="expired", reason="deadline")
 
     def _note_fault(self, slots, err: BaseException, where: str) -> None:
+        """Make an isolated backend failure visible: always logged (with
+        its traceback unless injected), and recorded + counted when
+        telemetry is on. The scheduler then retries or quarantines."""
+        injected = getattr(err, "is_injected", False)
+        log.warning("%s fault on slots %s: %s: %s", where, list(slots),
+                    type(err).__name__, err,
+                    exc_info=None if injected else err)
         if not self.tel.enabled:
             return
-        kind = "fault_injected" if getattr(err, "is_injected", False) \
-            else "fault"
+        kind = "fault_injected" if injected else "fault"
         self.tel.recorder.record(kind, tick=self._tick_no, where=where,
                                  slots=list(slots),
                                  error=type(err).__name__)

@@ -1,8 +1,6 @@
-from repro.shardlib.rules import (DEFAULT_RULES, abstract_mesh, axis_rules,
-                                  batch_axes, current_mesh, current_rules,
-                                  logical_spec, pvary, shard_map, shd,
-                                  tree_shardings)
+from repro.shardlib.rules import (DEFAULT_RULES, axis_rules, batch_axes,
+                                  current_mesh, current_rules, logical_spec,
+                                  shd, tree_shardings)
 
-__all__ = ["DEFAULT_RULES", "abstract_mesh", "axis_rules", "batch_axes",
-           "current_mesh", "current_rules", "logical_spec", "pvary", "shard_map",
-           "shd", "tree_shardings"]
+__all__ = ["DEFAULT_RULES", "axis_rules", "batch_axes", "current_mesh",
+           "current_rules", "logical_spec", "shd", "tree_shardings"]

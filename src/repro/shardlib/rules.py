@@ -20,45 +20,7 @@ from typing import Mapping, Optional, Sequence, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental between 0.4.x and 0.6; the
-# experimental module is gone in newer releases, the top-level name absent in
-# older ones. All repo call sites import shard_map/pvary from here. On the
-# 0.4.x fallback, check_rep is disabled: the old replication checker has no
-# notion of the varying-manual-axes (pvary) annotations the call sites use.
-try:
-    shard_map = jax.shard_map
-except AttributeError:                                  # JAX <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, **kwargs):
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_exp(f, **kwargs)
-
-try:
-    pvary = jax.lax.pvary
-except AttributeError:                                  # JAX <= 0.4.x
-    def pvary(x, axis_names):
-        """No-op: pre-vma JAX does not track varying manual axes."""
-        del axis_names
-        return x
-
 AxisVal = Union[None, str, tuple]
-
-
-def abstract_mesh(axis_sizes: Sequence[int],
-                  axis_names: Sequence[str]) -> "jax.sharding.AbstractMesh":
-    """Version-portable ``AbstractMesh`` construction.
-
-    Newer JAX takes ``(axis_sizes, axis_names)``; 0.4.x takes a single
-    ``shape_tuple`` of ``(name, size)`` pairs. Passing the new form to the
-    old constructor raises TypeError ('int' object is not iterable), which
-    we catch and translate.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:                                   # JAX <= 0.4.x
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes)))
 
 # Default logical -> physical mapping for the production meshes.
 DEFAULT_RULES: dict[str, AxisVal] = {

@@ -45,9 +45,8 @@ from repro.spatial.engine import (SpatialBackend, SpatialEngineCfg,
                                   SpatialServingEngine)
 from repro.spatial.sharded_pool import ShardedPagePools, ShardPoolExhausted
 from repro.spatial.topology import (ShardTopology, ensure_host_devices,
-                                    respawn_with_devices)
+                                    require_devices)
 
 __all__ = ["ShardPoolExhausted", "ShardTopology",
            "ShardedPagePools", "SpatialBackend", "SpatialEngineCfg",
-           "SpatialServingEngine", "ensure_host_devices",
-           "respawn_with_devices"]
+           "SpatialServingEngine", "ensure_host_devices", "require_devices"]

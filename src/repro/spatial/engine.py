@@ -94,9 +94,12 @@ class SpatialBackend:
                 "comes from per-shard DLZS hot-page retention at decode")
         self.cfg = model_cfg
         self.pcfg = pcfg
-        self.params = params
         self.topo = ShardTopology(pcfg.n_shards)
         self.mesh = self.topo.make_mesh()
+        # every dispatch takes the weights replicated over the mesh: place
+        # them once, or each call re-broadcasts them from one device
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        self.params = jax.device_put(params, NamedSharding(self.mesh, P()))
         self.pools = ShardedPagePools(
             self.topo, pcfg.n_pages_local, pcfg.page_size,
             recent_pages=pcfg.recent_pages)
@@ -163,7 +166,6 @@ class SpatialBackend:
         # Per-shard pool slabs from a one-page probe prefill: each leaf
         # [L, 1, page, nkv, dh] becomes [n_shards, L, P_local, page, nkv,
         # dh], sharded over the mesh axis (one slab stack per device).
-        from jax.sharding import NamedSharding, PartitionSpec as P
         probe = {"tokens": jnp.zeros((1, pcfg.page_size), jnp.int32)}
         _, cache_one = jax.jit(lambda p, b: lm.prefill(
             p, model_cfg, b, last_index=jnp.zeros((1,), jnp.int32)))(

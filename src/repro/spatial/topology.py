@@ -31,26 +31,30 @@ from repro.core import mrca
 FORCE_FLAG = "--xla_force_host_platform_device_count"
 
 
+def cpu_only() -> bool:
+    """True when ``JAX_PLATFORMS`` pins JAX to the CPU. Read from the
+    environment, so asking initializes no backend and holds no chip."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
 def ensure_host_devices(n: int) -> None:
     """Request ``n`` fake host devices. MUST run before the first jax
     import of the process — XLA fixes the device count at first init, so
     multi-shard drivers (tests/benchmarks) spawn subprocesses that call
-    this at the very top."""
+    this at the very top. Only the CPU platform reads the flag."""
     flags = os.environ.get("XLA_FLAGS", "")
     if FORCE_FLAG not in flags:
         os.environ["XLA_FLAGS"] = f"{flags} {FORCE_FLAG}={n}".strip()
 
 
-def respawn_with_devices(n: int, argv: list, *, cwd: Optional[str] = None,
-                         guard: str = "_REPRO_SPATIAL_CHILD") -> int:
+def _respawn_with_devices(n: int, argv: list, *, cwd: Optional[str] = None,
+                          guard: str = "_REPRO_SPATIAL_CHILD") -> int:
     """Re-execute ``sys.executable + argv`` in a child with ``n`` forced
-    fake host devices; returns the child's exit code.
-
-    The parent's device count cannot grow after jax initialized, so
-    entrypoints that discover too few devices (benchmarks, launchers,
-    examples) call this and exit with the child's status. ``guard`` is an
-    env marker that stops an infinite respawn loop if forcing has no
-    effect (e.g. XLA_FLAGS overridden downstream)."""
+    fake host devices; returns the child's exit code. CPU only: the
+    parent's device count cannot grow after jax initialized, and on an
+    accelerator the parent holds the chip, so a child could not get it.
+    ``guard`` is an env marker that stops an infinite respawn loop if
+    forcing has no effect (e.g. XLA_FLAGS overridden downstream)."""
     import subprocess
     import sys
 
@@ -63,6 +67,28 @@ def respawn_with_devices(n: int, argv: list, *, cwd: Optional[str] = None,
         f"{env.get('XLA_FLAGS', '')} {FORCE_FLAG}={n}".strip()
     env[guard] = "1"
     return subprocess.call([sys.executable] + list(argv), env=env, cwd=cwd)
+
+
+def require_devices(n: int, argv: list, *,
+                    cwd: Optional[str] = None) -> None:
+    """Make sure this process sees ``n`` devices for an ``n``-shard mesh.
+
+    With ``JAX_PLATFORMS=cpu`` and too few devices, re-execute ``argv``
+    with ``n`` fake host devices and exit with the child's status.
+    Anywhere else never respawn — one process per chip — and fail with a
+    clear message when JAX sees fewer than ``n`` devices."""
+    import sys
+
+    import jax
+    have = len(jax.devices())
+    if have >= n:
+        return
+    if cpu_only():
+        sys.exit(_respawn_with_devices(n, argv, cwd=cwd))
+    raise SystemExit(
+        f"{n} shards need {n} devices; JAX sees {have} "
+        f"{jax.devices()[0].platform} device(s). Run on a host with {n} "
+        f"chips, or set JAX_PLATFORMS=cpu for the fake-device harness.")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -66,10 +66,14 @@ def assert_drained(router):
         assert st["swap"].entries == 0, f"{name} payload left behind"
 
 
-def scenario_disagg_parity(make_router, make_single, cfg) -> str:
+def scenario_disagg_parity(make_router, make_single, cfg,
+                           greedy_tie=None) -> str:
     """Disaggregated serving keeps token parity with a single instance
     of the decode backend, and every multi-token request crossed the
-    fabric exactly once with its pages."""
+    fabric exactly once with its pages. ``greedy_tie`` (prompt, got,
+    want) -> bool admits a divergence at an audited argmax tie — for a
+    prefill backend whose reduction order differs from the decode
+    backend's (sharded prefill); None demands exact tokens."""
     prompts = prompts_for(cfg)
     single = make_single()
     handles = [single.submit(p, max_tokens=12, rid=i)
@@ -78,7 +82,11 @@ def scenario_disagg_parity(make_router, make_single, cfg) -> str:
     want = {h.rid: h.tokens for h in handles}
     router = make_router()
     got = {h.rid: h.tokens for h in run_router(router, prompts)}
-    assert got == want, f"disagg parity broke:\n{got}\n{want}"
+    for rid in want:
+        assert got[rid] == want[rid] or (
+            greedy_tie is not None
+            and greedy_tie(prompts[rid], got[rid], want[rid])), \
+            f"disagg parity broke (rid {rid}):\n{got}\n{want}"
     tr = router.transfer
     assert tr.n_transfers == len(prompts), \
         f"expected one handoff per request, got {tr.n_transfers}"

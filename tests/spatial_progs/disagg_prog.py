@@ -28,6 +28,7 @@ from repro.configs import get_smoke_config
 from repro.models import lm
 from repro.serving import (DisaggRouter, LLM, PagedEngineCfg,
                            PagedServingEngine, SchedulerCfg)
+from repro.serving import parity
 from repro.spatial import SpatialEngineCfg, SpatialServingEngine
 
 cfg = dataclasses.replace(get_smoke_config("olmo_1b"), star=None)
@@ -60,13 +61,16 @@ def make_single():
 
 
 def _tie(prompt, got, want):
-    # recompute replay runs under different batch shapes: audit greedy
-    # argmax ties at the divergence point like the chaos conformance
-    return scen._greedy_tie(cfg, params, prompt, got, want)
+    # sharded prefill sums in another order than the paged decode
+    # instance's own replay: audit greedy argmax ties at the divergence
+    # point like the spatial chaos conformance
+    return scen._greedy_tie(cfg, params, prompt, got, want,
+                            parity.TIE_ULPS)
 
 
 print(f"[{N_SHARDS}-shard spatial -> paged] "
-      + dscen.scenario_disagg_parity(make_router, make_single, cfg)
+      + dscen.scenario_disagg_parity(make_router, make_single, cfg,
+                                     greedy_tie=_tie)
       + " OK")
 print(f"[{N_SHARDS}-shard spatial -> paged] "
       + dscen.scenario_disagg_chaos(make_router, make_single, cfg,

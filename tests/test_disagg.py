@@ -27,7 +27,7 @@ from repro.kvcache import quant
 from repro.kvcache.wire import payload_bytes, validate_payload
 from repro.models import lm
 from repro.serving import (DisaggRouter, FaultPlan, LLM, PagedEngineCfg,
-                           PagedServingEngine, SchedulerCfg)
+                           PagedServingEngine, SchedulerCfg, parity)
 
 import disagg_scenarios as dscen
 import engine_core_scenarios as scen
@@ -182,7 +182,9 @@ def test_wire_roundtrip(smoke_lm, tier):
 
 def test_adopt_recompute_replay(smoke_lm):
     """Adopt with no payload replays prompt + emitted tokens through
-    chunked prefill — exact under greedy decode."""
+    chunked prefill — exact under greedy decode up to a bf16 argmax tie:
+    the replay sums the emitted tokens' attention in prefill's order,
+    not decode's, so the audit admits ``parity.TIE_ULPS``."""
     cfg, params = smoke_lm
     prompt = np.arange(24, dtype=np.int32) % cfg.vocab
     ref = LLM(_paged(cfg, params))
@@ -202,7 +204,7 @@ def test_adopt_recompute_replay(smoke_lm):
             break
     assert req.out[:len(emitted)] == emitted, "replay rewrote history"
     assert req.out == want or scen._greedy_tie(
-        cfg, params, prompt, req.out, want)
+        cfg, params, prompt, req.out, want, parity.TIE_ULPS)
 
 
 # ---------------------------------------------------------------- the router

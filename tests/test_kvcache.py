@@ -413,9 +413,12 @@ def test_star_chunk_sparse_prefill_within_tolerance():
     params = attention.init(ks[0], acfg)
     # past pool: 3 near-zero pages + 1 dominant page. The sphere keeps
     # only the dominant page, and the dropped mass is bounded by
-    # S_past * e^-radius of the total — the tolerance below
+    # S_past * e^-radius of the total — the tolerance below. The dominant
+    # page holds key pairs (k, -k), so every query row — not only the
+    # row with the page's best score — sees a large positive score there
+    half = jax.random.normal(ks[2], (page // 2, nkv, dh)) * 20.0
     kp = jax.random.normal(ks[1], (6, page, nkv, dh), jnp.float32) * 0.01
-    kp = kp.at[4].set(jax.random.normal(ks[2], (page, nkv, dh)) * 20.0)
+    kp = kp.at[4].set(jnp.concatenate([half, -half], axis=0))
     vp = jax.random.normal(ks[3], (6, page, nkv, dh), jnp.float32)
     from repro.core import dlzs
     cache = {"k": kp, "v": vp, "k_lz": dlzs.lz_pack(kp)}

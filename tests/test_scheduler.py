@@ -691,4 +691,6 @@ def test_paged_backend_dispatch(monkeypatch):
     monkeypatch.setenv("REPRO_PAGED_BACKEND", "mosaic")
     with pytest.raises(ValueError, match="REPRO_PAGED_BACKEND"):
         pa.default_backend()
-    assert pa.default_interpret() == (jax.default_backend() != "tpu")
+    from repro.kernels import resolve_interpret
+    assert resolve_interpret() == (jax.default_backend() != "tpu")
+    assert resolve_interpret(True) and not resolve_interpret(False)

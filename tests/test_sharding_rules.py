@@ -8,7 +8,7 @@ from repro.shardlib import rules as shr
 
 
 def _mesh(shape=(1, 1), names=("data", "model")):
-    return shr.abstract_mesh(shape, names)
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(names))
 
 
 def _mesh11():
