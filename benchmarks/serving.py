@@ -42,12 +42,6 @@ Scenarios (CSV rows to stdout, optionally merged into a
   per-tick accounting counters (telemetry on), and a page-rich
   long-prompt sub-run pins a structurally nonzero measured skip
   fraction at the widest bounded width.
-* ``phase_breakdown`` (also standalone via ``--phase``) — stage-resolved
-  tick cost from the telemetry tracer (``repro.obs``): per-tick
-  milliseconds in admit / prefill / decode / swap / host for the paged
-  engine under pool pressure and the 2-shard spatial engine (fake-device
-  subprocess), measured on a warmed engine from one traced pass. The
-  entry future PRs cite to prove WHICH stage they sped up.
 * ``--spatial`` — the spatial-runtime acceptance (runs INSTEAD of the
   three above): a batch of ultra-long prompts against the sequence-
   sharded engine at 1/2/4 shards with a FIXED per-shard pool. At 1 shard
@@ -69,7 +63,6 @@ import argparse
 import dataclasses
 import json
 import os
-import sys
 import time
 
 import jax
@@ -746,117 +739,6 @@ def _disagg(cfg, params, results):
     results["disagg"] = m
 
 
-# phase_breakdown workload: the overload shape (pool pressure keeps the
-# swap bucket non-zero) at a size small enough to trace in a few seconds
-PHASE_N_PAGES = 9
-PHASE_GEN = 16
-PHASE_REQS = 8
-
-
-def _phase_requests(cfg, rid0: int, seed: int = 3):
-    rng = np.random.default_rng(seed)
-    return [Request(rid=rid0 + i,
-                    prompt=rng.integers(0, cfg.vocab, size=32,
-                                        dtype=np.int32),
-                    max_tokens=PHASE_GEN)
-            for i in range(PHASE_REQS)]
-
-
-def _phase_measure(cfg, eng) -> dict:
-    """Warm the engine, clear the trace, serve one traced pass, and
-    reduce the trace to the stored phase table."""
-    tel = obs.Telemetry()
-    eng.attach_telemetry(tel)
-    eng.run(_phase_requests(cfg, 0), max_steps=20_000)       # warmup
-    tel.tracer.clear()
-    done = eng.run(_phase_requests(cfg, 100), max_steps=20_000)
-    assert all(len(v) == PHASE_GEN for v in done.values())
-    s = obs.phase_summary(tel.tracer.events)
-    return {"ticks": s["ticks"], "wall_ms": s["wall_ms"],
-            "per_tick_ms": s["per_tick_ms"], "totals_ms": s["totals_ms"],
-            "compile_ms": s["compile_ms"], "counts": s["counts"]}
-
-
-def phase_breakdown_paged(cfg, params) -> dict:
-    """Stage-resolved tick cost of the paged engine under pool pressure:
-    per-tick milliseconds in admit/prefill/decode/swap/host from one
-    traced steady-state pass (the engine is warmed first, so
-    ``compile_ms`` ~ 0 is part of the measurement's sanity)."""
-    eng = PagedServingEngine(cfg, params, PagedEngineCfg(
-        max_batch=4, page_size=16, n_pages=PHASE_N_PAGES, hot_pages=4,
-        recent_pages=2, eos_id=-1),
-        SchedulerCfg(chunk_pages=1, swap=True))
-    return _phase_measure(cfg, eng)
-
-
-def phase_spatial_child(out_path: str) -> None:
-    """Child half of ``phase_breakdown``: the 2-shard engine under the
-    same pressure workload, run in a process whose fake-device mesh the
-    parent set up. Writes the phase table to ``out_path``."""
-    from repro.spatial import SpatialEngineCfg, SpatialServingEngine
-    cfg = dataclasses.replace(get_smoke_config("olmo_1b"), star=None)
-    params = lm.init(jax.random.PRNGKey(0), cfg)
-    # per-shard pool ~half the single-pool size: aggregate capacity is
-    # comparable and the swap bucket stays exercised on both backends
-    eng = SpatialServingEngine(cfg, params, SpatialEngineCfg(
-        n_shards=2, max_batch=4, page_size=16,
-        n_pages_local=6, hot_pages_local=4,
-        recent_pages=2, eos_id=-1),
-        SchedulerCfg(chunk_pages=1, swap=True))
-    m = _phase_measure(cfg, eng)
-    with open(out_path, "w") as f:
-        json.dump(m, f)
-
-
-def phase_breakdown_spatial() -> dict:
-    """Run the 2-shard phase measurement in a fake-device subprocess
-    (the parent's XLA device count is already fixed). CPU only."""
-    import subprocess
-    import tempfile
-    from repro.spatial.topology import FORCE_FLAG
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
-        out_path = f.name
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"{env.get('XLA_FLAGS', '')} " \
-                       f"{FORCE_FLAG}=2".strip()
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.serving",
-             "--phase-spatial", out_path],
-            cwd=repo, env=env, capture_output=True, text=True,
-            timeout=900)
-        assert proc.returncode == 0, \
-            f"spatial phase child failed:\n{proc.stderr[-800:]}"
-        with open(out_path) as f:
-            return json.load(f)
-    finally:
-        os.unlink(out_path)
-
-
-def phase_breakdown(cfg, params) -> dict:
-    from repro.spatial.topology import cpu_only
-    m = {"paged": phase_breakdown_paged(cfg, params)}
-    if cpu_only():
-        m["spatial_2shard"] = phase_breakdown_spatial()
-    else:
-        print("phase_breakdown: spatial_2shard skipped: its child runs on "
-              "fake CPU devices (set JAX_PLATFORMS=cpu)", file=sys.stderr)
-    return m
-
-
-def _phase_breakdown(cfg, params, results):
-    m = phase_breakdown(cfg, params)
-    for backend, v in m.items():
-        per = v["per_tick_ms"]
-        emit(f"serving_phase_{backend}", v["wall_ms"] * 1e3 / v["ticks"],
-             f"ticks={v['ticks']};"
-             f"prefill_ms={per['prefill']};decode_ms={per['decode']};"
-             f"swap_ms={per['swap']};host_ms={per['host']};"
-             f"admit_ms={per['admit']};compile_ms={v['compile_ms']}")
-    results["phase_breakdown"] = m
-
-
 # decode_sparse workload: decode-heavy mixed-length requests against an
 # engine whose DENSE hot-page provisioning covers the worst-case context
 # (an operator sizes ``hot_pages`` for max_len — the compiled gather
@@ -1266,16 +1148,6 @@ def write_json(path: str, results: dict) -> None:
         f.write("\n")
 
 
-def run_phase(json_path: str | None = None) -> dict:
-    cfg = dataclasses.replace(get_smoke_config("olmo_1b"), star=None)
-    params = lm.init(jax.random.PRNGKey(0), cfg)
-    results: dict = {}
-    _phase_breakdown(cfg, params, results)
-    if json_path:
-        write_json(json_path, results)
-    return results
-
-
 def run_decode_sparse(json_path: str | None = None) -> dict:
     cfg = dataclasses.replace(get_smoke_config("olmo_1b"), star=None)
     params = lm.init(jax.random.PRNGKey(0), cfg)
@@ -1318,7 +1190,6 @@ def run(json_path: str | None = None) -> dict:
     _overload_deadlines(cfg, params, results)
     _disagg(cfg, params, results)
     _decode_sparse(cfg, params, results)
-    _phase_breakdown(cfg, params, results)
     if json_path:
         write_json(json_path, results)
     return results
@@ -1350,19 +1221,9 @@ if __name__ == "__main__":
                          "(SLA-mixed overload burst with vs without "
                          "admission shedding: per-SLA goodput + "
                          "deadline-miss rate -> the 'robustness' entry)")
-    ap.add_argument("--phase", action="store_true",
-                    help="run ONLY the phase_breakdown scenario (traced "
-                         "per-tick stage costs for paged + 2-shard "
-                         "spatial; the spatial half runs in a "
-                         "fake-device subprocess)")
-    ap.add_argument("--phase-spatial", metavar="PATH", default=None,
-                    help=argparse.SUPPRESS)   # internal child entrypoint
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
-    if args.phase_spatial:
-        phase_spatial_child(args.phase_spatial)
-        sys.exit(0)
     if args.spatial:
         from repro.spatial import require_devices
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -1376,8 +1237,6 @@ if __name__ == "__main__":
         run_disagg(json_path=args.json)
     elif args.overload_deadlines:
         run_overload_deadlines(json_path=args.json)
-    elif args.phase:
-        run_phase(json_path=args.json)
     elif args.spatial:
         run_spatial(json_path=args.json)
     else:

@@ -113,4 +113,5 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, g, r, d), q.dtype),
         interpret=resolve_interpret(interpret),
+        name="paged_decode",
     )(phys, logical, kv_len, q, k_pages, v_pages)

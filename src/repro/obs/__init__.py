@@ -4,19 +4,23 @@ One ``Telemetry`` object bundles the three surfaces the engine stack
 shares:
 
 * ``tel.tracer`` — span/instant trace events per tick phase, exportable
-  as Perfetto/Chrome ``trace_event`` JSON (see ``obs.trace``);
+  as Perfetto/Chrome ``trace_event`` JSON (see ``obs.trace``); every
+  span is also a JAX profiler annotation ``engine.<name>``, recorded
+  whenever a profiler session is active, telemetry on or off;
 * ``tel.metrics`` — a ``MetricsRegistry`` of counters/gauges/histograms
   with per-SLA / per-shard labels and Prometheus text exposition;
 * ``tel.timelines`` — per-request ``RequestTimeline`` lifecycles
   (submit → admit → TTFT → per-token → done/preempted).
 
 The default everywhere is ``NULL_TELEMETRY`` — a disabled instance whose
-tracer is a no-op and whose ``enabled`` flag guards every hot-path
-write, so serving without telemetry costs a few attribute checks per
+tracer keeps nothing (its spans reach only the profiler) and whose
+``enabled`` flag guards every hot-path write, so serving without
+telemetry costs a few attribute checks and profiler annotations per
 tick (asserted <5% overhead in tests/test_obs.py). Enable by passing a
 real ``Telemetry()`` to ``LLM.from_config(..., telemetry=...)`` or
-``EngineCore.attach_telemetry``. Nothing in this package touches jax:
-all events are host-side; no device syncs are added to the hot path.
+``EngineCore.attach_telemetry``. Of jax this package imports only
+``jax.profiler``: all events are host-side; no device syncs are added
+to the hot path.
 """
 
 from __future__ import annotations
@@ -33,7 +37,7 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
 from repro.obs.recorder import NULL_RECORDER, FlightRecorder
 from repro.obs.timeline import RequestTimeline, aggregate, percentile
 from repro.obs.trace import (NULL_TRACER, NullTracer, Tracer, format_table,
-                             load_trace, phase_summary)
+                             load_trace, phase_summary, profiled_spans)
 
 
 class Telemetry:
@@ -98,7 +102,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "DEFAULT_BUCKETS",
     "RequestTimeline", "aggregate", "percentile",
     "Tracer", "NullTracer", "NULL_TRACER", "load_trace", "phase_summary",
-    "format_table",
+    "format_table", "profiled_spans",
     "FlightRecorder", "NULL_RECORDER",
     "AuditCfg", "DlzsAuditor", "score_histogram",
     "WatchdogReport", "conservation_error", "fold_snapshot",

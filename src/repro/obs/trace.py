@@ -1,10 +1,17 @@
-"""Host-side tracing: Perfetto/Chrome ``trace_event`` spans for ticks.
+"""Host-side tracing: engine spans for ticks, in two outputs at once.
 
-A ``Tracer`` collects structured span ("X" complete) and instant ("i")
-events with microsecond timestamps relative to construction. Everything
-is host-side — no device syncs, no jax imports — so enabling a trace
-never perturbs the engine's dispatch behavior, and the disabled path
-(``NullTracer``) is a handful of no-op calls per tick.
+Every span also enters a ``jax.profiler.TraceAnnotation`` named
+``engine.<span name>`` (``tick`` -> ``engine.tick``), so whenever a JAX
+profiler session is active the engine's spans land in its trace on the
+device events' clock, with telemetry on or off. Span args ride into the
+annotation only while a session is active; without one a span costs
+well under a microsecond. While a session is active the spans are also
+kept in memory, with their host times (``profiled_spans``).
+
+A ``Tracer`` (telemetry on) also collects structured span ("X" complete)
+and instant ("i") events in memory with microsecond timestamps relative
+to construction. ``NullTracer`` (telemetry off) keeps nothing. No span
+syncs the device, so tracing never perturbs the engine's dispatch.
 
 Exports:
 
@@ -13,41 +20,72 @@ Exports:
 * ``Tracer.export_jsonl(path)`` — one event per line (streamable); a
   leading ``{"meta": ...}`` header line carries run metadata.
 * ``load_trace(path)`` — round-trip loader for both formats.
-* ``phase_summary(events)`` — the per-phase time table ``tools/
-  trace_summary.py`` and ``benchmarks/serving.py`` (phase_breakdown)
-  share: per-tick ms in admit/prefill/decode/swap plus the host
-  remainder (tick time not inside any phase span).
+* ``phase_summary(events)`` — the per-phase time table
+  ``tools/trace_summary.py`` and the traced launchers print: per-tick
+  ms in admit/prefill/decode/swap plus the host remainder (tick time
+  not inside any phase span).
 """
 
 from __future__ import annotations
 
+import collections
 import json
+import threading
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
 
-class _NullSpan:
-    """Shared no-op context manager the disabled tracer hands out."""
+PREFIX = "engine."      # profiler annotations are named PREFIX + span name
 
-    __slots__ = ()
+# Every span entered while a profiler session is active, as (profiler
+# name, start s, end s, thread id) on the ``time.perf_counter`` clock:
+# the spans the session's trace holds, for readers that want them
+# without parsing its file. Bounded; the newest are kept.
+PROFILED: collections.deque = collections.deque(maxlen=1 << 17)
 
-    def __enter__(self):
-        return self
 
-    def __exit__(self, et, ev, tb):
-        return False
+def profiled_spans() -> list[tuple[str, float, float, int]]:
+    """The spans kept in ``PROFILED``, oldest first."""
+    return list(PROFILED)
+
+
+class _Annotation(TraceAnnotation):
+    """The profiler annotation a span enters. ``args`` is a throwaway,
+    so code that annotates a span mid-way works with either tracer."""
 
     @property
     def args(self) -> dict:
-        # fresh throwaway: annotations on a disabled span go nowhere
         return {}
 
 
-_NULL_SPAN = _NullSpan()
+class _Profiled(_Annotation):
+    """A span entered while a profiler session is active: the annotation
+    carries its args, and its host times go to ``PROFILED``."""
+
+    def __init__(self, name: str, args: dict):
+        super().__init__(name, **args)
+        self._name = name
+
+    def __enter__(self) -> "_Profiled":
+        self._t0 = time.perf_counter()
+        return super().__enter__()
+
+    def __exit__(self, et, ev, tb):
+        super().__exit__(et, ev, tb)
+        PROFILED.append((self._name, self._t0, time.perf_counter(),
+                         threading.get_ident()))
+        return False
+
+
+def _annotation(name: str, args: dict) -> _Annotation:
+    if TraceAnnotation.is_enabled():            # a profiler session is on
+        return _Profiled(PREFIX + name, args)
+    return _Annotation(PREFIX + name)
 
 
 class NullTracer:
-    """Disabled tracer: every call is a no-op returning shared objects.
+    """Disabled tracer: keeps nothing; its spans reach only the profiler.
 
     ``enabled`` is the guard hot paths check before building event
     arguments; span()/instant() still exist so cold paths can skip the
@@ -56,8 +94,8 @@ class NullTracer:
     __slots__ = ()
     enabled = False
 
-    def span(self, name: str, tid: int = 0, **args) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, tid: int = 0, **args) -> _Annotation:
+        return _annotation(name, args)
 
     def instant(self, name: str, tid: int = 0, **args) -> None:
         return None
@@ -77,12 +115,14 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    """One open span; emits a complete ("X") event when it exits.
+    """One open span; emits a complete ("X") event when it exits, and is
+    a profiler annotation meanwhile.
 
     ``args`` is mutable until exit, so callers can annotate outcomes
-    discovered mid-span (pages freed, wave splits, ...)."""
+    discovered mid-span (pages freed, wave splits, ...); the annotation
+    carries them as they were at entry."""
 
-    __slots__ = ("_tr", "name", "tid", "args", "_t0")
+    __slots__ = ("_tr", "name", "tid", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, tid: int, args: dict):
         self._tr = tracer
@@ -91,12 +131,15 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
+        self._ann = _annotation(self.name, self.args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, et, ev, tb):
-        self._tr._complete(self.name, self.tid, self._t0,
-                           time.perf_counter(), self.args)
+        t1 = time.perf_counter()
+        self._ann.__exit__(et, ev, tb)
+        self._tr._complete(self.name, self.tid, self._t0, t1, self.args)
         return False
 
 

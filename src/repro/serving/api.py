@@ -276,20 +276,22 @@ class LLM:
     def tick(self) -> list[Request]:
         """One engine step; stamps TTFT / completion times."""
         finished = self._step_engines()
-        now = time.perf_counter()
-        for rec in self._pending.values():
-            if rec.first_token_t is None and rec.req.out:
-                rec.first_token_t = now
-        for fin in finished:
-            # cancel() may have closed the record already
-            rec = self._pending.pop(fin.rid, None)
-            if rec is None:
-                continue
-            if rec.done_t is None:      # engine telemetry may have stamped
-                rec.done_t = now
-            rec.n_tokens = len(fin.out or ())
-            if rec.outcome is None:
-                rec.outcome = getattr(fin, "finish_reason", None) or "done"
+        with self.tel.tracer.span("api.tick"):
+            now = time.perf_counter()
+            for rec in self._pending.values():
+                if rec.first_token_t is None and rec.req.out:
+                    rec.first_token_t = now
+            for fin in finished:
+                # cancel() may have closed the record already
+                rec = self._pending.pop(fin.rid, None)
+                if rec is None:
+                    continue
+                if rec.done_t is None:  # engine telemetry may have stamped
+                    rec.done_t = now
+                rec.n_tokens = len(fin.out or ())
+                if rec.outcome is None:
+                    rec.outcome = (getattr(fin, "finish_reason", None)
+                                   or "done")
         return finished
 
     def cancel(self, rid: int, *, reason: str = "client") -> bool:

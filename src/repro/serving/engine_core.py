@@ -827,9 +827,9 @@ class EngineCore:
         # prefill-finished list only once nothing can raise anymore.
         # The span covers dispatch THROUGH the host sync (np.asarray):
         # jit dispatch is async, so device time only shows at the sync.
+        tr = self.tel.tracer
         first = "decode" not in self._compiled
-        with self.tel.tracer.span("decode.step", lanes=len(slots),
-                                  compile=first):
+        with tr.span("decode.step", lanes=len(slots), compile=first):
             try:
                 logits = self.backend.decode_step(slots, self.tables,
                                                   self.lengths)
@@ -849,15 +849,17 @@ class EngineCore:
                 self._note_fault(slots, err, "decode")
                 raise ExecFault(slots, err, "decode") from err
             done_early, self._prefill_done = self._prefill_done, []
-            logits = logits[:, :self.cfg.vocab]
-            if self.backend.greedy:
-                nxt = jnp.argmax(logits, axis=-1)
-            else:
-                self.rng, sub = jax.random.split(self.rng)
-                nxt = jax.random.categorical(
-                    sub, logits / self.backend.temperature, axis=-1)
-            self.backend.commit_tokens(nxt)
-            nxt_host = np.asarray(nxt)
+            with tr.span("decode.sample"):
+                logits = logits[:, :self.cfg.vocab]
+                if self.backend.greedy:
+                    nxt = jnp.argmax(logits, axis=-1)
+                else:
+                    self.rng, sub = jax.random.split(self.rng)
+                    nxt = jax.random.categorical(
+                        sub, logits / self.backend.temperature, axis=-1)
+                self.backend.commit_tokens(nxt)
+            with tr.span("decode.sync"):
+                nxt_host = np.asarray(nxt)
         self._compiled.add("decode")
         sparsity = getattr(self.backend, "decode_sparsity", None)
         if self.tel.enabled and sparsity:
@@ -890,29 +892,30 @@ class EngineCore:
         finished = done_early
         tel_on = self.tel.enabled
         now = time.perf_counter() if tel_on else 0.0
-        for slot in slots:
-            req = self.active[slot]
-            tok = int(nxt_host[slot])
-            req.out.append(tok)
-            self.lengths[slot] += 1
-            self.budget[slot] -= 1
-            if tel_on:
-                self.tel.timeline(req.rid).token_ts.append(now)
-            limit = req.max_len
-            done = (tok == self.backend.eos_id or self.budget[slot] <= 0
-                    or (limit is not None
-                        and self.lengths[slot] + 1 >= limit))
-            if done:
-                self.backend.release_table(self.tables.pop(slot))
-                self.swap_area.discard(req.rid)   # lazily-shed pages
-                del self.active[slot]
-                del self.budget[slot]
-                self.lengths[slot] = 0
-                self.free.append(slot)
-                req.finish_reason = "done"
-                finished.append((slot, req))
+        with tr.span("decode.commit"):
+            for slot in slots:
+                req = self.active[slot]
+                tok = int(nxt_host[slot])
+                req.out.append(tok)
+                self.lengths[slot] += 1
+                self.budget[slot] -= 1
                 if tel_on:
-                    self._stamp_done(req, "done")
+                    self.tel.timeline(req.rid).token_ts.append(now)
+                limit = req.max_len
+                done = (tok == self.backend.eos_id or self.budget[slot] <= 0
+                        or (limit is not None
+                            and self.lengths[slot] + 1 >= limit))
+                if done:
+                    self.backend.release_table(self.tables.pop(slot))
+                    self.swap_area.discard(req.rid)   # lazily-shed pages
+                    del self.active[slot]
+                    del self.budget[slot]
+                    self.lengths[slot] = 0
+                    self.free.append(slot)
+                    req.finish_reason = "done"
+                    finished.append((slot, req))
+                    if tel_on:
+                        self._stamp_done(req, "done")
         return finished
 
     # -- executor protocol: lazy shed / preemption / swap -------------------
@@ -1306,11 +1309,8 @@ class EngineCore:
         re-raises."""
         self._expire_deadlines()
         try:
-            if not self.tel.enabled:
+            with self.tel.tracer.span("tick", n=self._tick_no):
                 fin = self.sched.tick(self)
-            else:
-                with self.tel.tracer.span("tick", n=self._tick_no):
-                    fin = self.sched.tick(self)
         except Exception as e:
             self._drain(e)
             raise

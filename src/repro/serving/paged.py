@@ -143,20 +143,23 @@ class PagedBackend:
                 pcfg.batch_past_pages or pcfg.n_pages - 1,
                 pow2=pcfg.bucket_pow2)
 
-        self._prefill = jax.jit(functools.partial(self._prefill_fn))
-        self._prefill_chunk = jax.jit(functools.partial(
-            self._prefill_chunk_fn))
-        self._prefill_chunk_batch = jax.jit(functools.partial(
-            self._prefill_chunk_batch_fn))
+        # each program compiles under its method's name, which the
+        # profiler trace shows (jit__prefill_fn, jit__scatter_fn, ...)
+        self._prefill = jax.jit(self._prefill_fn)
+        self._prefill_chunk = jax.jit(self._prefill_chunk_fn)
+        self._prefill_chunk_batch = jax.jit(self._prefill_chunk_batch_fn)
         # donate the cache/pool slabs: these updates would otherwise keep
         # two full copies of the page pool live per step (no-op on CPU,
-        # which lacks donation — load-bearing on TPU)
+        # which lacks donation — load-bearing on TPU). The decode step
+        # alone stays unnamed (jit__unknown): the chip benchmark finds it
+        # by the paged kernel it runs, and its CPU test holds that no
+        # program is named for decode
         self._decode = jax.jit(functools.partial(self._decode_fn),
                                donate_argnums=(2,))
-        # audit probe (obs.audit): same decode fn, but NO donation — the
+        # audit probe (obs.audit): the decode step, but NO donation — the
         # probe reads the live cache and its output tree is discarded, so
         # donating would invalidate self.cache under the engine
-        self._audit = jax.jit(functools.partial(self._decode_fn))
+        self._audit = jax.jit(self._audit_fn)
         self._scatter = jax.jit(self._scatter_fn, donate_argnums=(0,))
         self._copy_page = jax.jit(self._copy_fn, donate_argnums=(0,))
         self._gather_pages = jax.jit(self._gather_fn)
@@ -213,6 +216,9 @@ class PagedBackend:
         return lm.decode_step_paged(params, self.cfg, tokens, cache,
                                     page_state)
 
+    def _audit_fn(self, params, tokens, cache, page_state):
+        return self._decode_fn(params, tokens, cache, page_state)
+
     def _scatter_fn(self, pool_layers, one_layers, phys):
         """Write a prefilled sequence's rows into pool pages ``phys``.
 
@@ -250,7 +256,9 @@ class PagedBackend:
             pool_layers, rows_layers)
 
     def _pull_scores(self) -> np.ndarray:
-        return np.asarray(self._scores(self.cache["layers"]))
+        scores = self._scores(self.cache["layers"])
+        with self.tel.tracer.span("kv.scores.sync"):
+            return np.asarray(scores)
 
     def export_page_scores(self, table, js) -> list[float]:
         """Per-page DLZS scores for a transfer payload (advisory: the
@@ -409,81 +417,83 @@ class PagedBackend:
         self.cache["layers"] = self._scatter(
             self.cache["layers"], cache_flat["layers"],
             jnp.asarray(phys_sc))
-        logits_host = np.asarray(logits)
+        with self.tel.tracer.span("prefill.sync"):
+            logits_host = np.asarray(logits)
         return {lane["slot"]: logits_host[lane["slot"]] for lane in lanes}
 
     # -- decode ----------------------------------------------------------------
 
     def _page_state(self, slots, tables, lengths) -> dict:
         """Assemble block-table rows + write coordinates for this step."""
-        b, w = self.pcfg.max_batch, self.hot_width
-        page = self.pcfg.page_size
-        phys = np.full((b, w), -1, np.int32)
-        logical = np.full((b, w), -1, np.int32)
-        write_page = np.full((b,), SCRATCH, np.int32)
-        write_off = np.zeros((b,), np.int32)
+        with self.tel.tracer.span("decode.page_state"):
+            b, w = self.pcfg.max_batch, self.hot_width
+            page = self.pcfg.page_size
+            phys = np.full((b, w), -1, np.int32)
+            logical = np.full((b, w), -1, np.int32)
+            write_page = np.full((b,), SCRATCH, np.int32)
+            write_off = np.zeros((b,), np.int32)
 
-        # scores are needed for hot-page selection once any table exceeds
-        # W, and for eviction whenever the free list cannot cover EVERY
-        # sequence growing a page this step (not just when it is empty —
-        # the last grower of the step must still evict lowest-score-first).
-        # Bounded sphere selection and the quantized tier both put the
-        # DLZS prediction on the critical path EVERY step — the LAPA
-        # "prediction is cheap enough to always run" claim.
-        growers = sum(1 for s in slots
-                      if int(lengths[s]) // page == len(tables[s]))
-        need_scores = (self.sparse_decode or self.kv_quant
-                       or any(len(tables[s]) > w for s in slots)
-                       or self.pool.free_pages() < growers)
-        scores = self._pull_scores() if need_scores else None
-        resident: set[int] = set()
-        hot_pids: set[int] = set()
-        pages_total = pages_hot = 0
-        per_slot: dict[int, tuple[int, int]] = {}
-        for slot in slots:
-            table = tables[slot]
-            length = int(lengths[slot])
-            idx = length // page
-            if idx == len(table):          # tail page full: grow
-                try:
-                    table.append(self.alloc.extend(scores))
-                except PoolExhausted:
-                    raise NeedPages(slot) from None
-            cow = self.alloc.ensure_owned(table, idx)
-            if cow is not None:            # COW before the write
-                src, dst = cow
-                self.cache["layers"] = self._copy_page(
-                    self.cache["layers"], jnp.asarray(src, jnp.int32),
-                    jnp.asarray(dst, jnp.int32))
-            if self.sparse_decode:
-                ph, lg = self.alloc.select_hot_sphere(
-                    table, w, scores, radius=self.hot_radius)
-            else:
-                ph, lg = self.alloc.select_hot(table, w, scores)
-            phys[slot] = ph
-            logical[slot] = lg
-            write_page[slot] = table[idx]
-            write_off[slot] = length % page
-            n_res = sum(1 for pid in table if pid >= 0)
-            n_hot = int((lg >= 0).sum())
-            pages_total += n_res
-            pages_hot += n_hot
-            per_slot[slot] = (n_res, n_hot)
+            # scores are needed for hot-page selection once any table exceeds
+            # W, and for eviction whenever the free list cannot cover EVERY
+            # sequence growing a page this step (not just when it is empty —
+            # the last grower of the step must still evict lowest-score-first).
+            # Bounded sphere selection and the quantized tier both put the
+            # DLZS prediction on the critical path EVERY step — the LAPA
+            # "prediction is cheap enough to always run" claim.
+            growers = sum(1 for s in slots
+                          if int(lengths[s]) // page == len(tables[s]))
+            need_scores = (self.sparse_decode or self.kv_quant
+                           or any(len(tables[s]) > w for s in slots)
+                           or self.pool.free_pages() < growers)
+            scores = self._pull_scores() if need_scores else None
+            resident: set[int] = set()
+            hot_pids: set[int] = set()
+            pages_total = pages_hot = 0
+            per_slot: dict[int, tuple[int, int]] = {}
+            for slot in slots:
+                table = tables[slot]
+                length = int(lengths[slot])
+                idx = length // page
+                if idx == len(table):          # tail page full: grow
+                    try:
+                        table.append(self.alloc.extend(scores))
+                    except PoolExhausted:
+                        raise NeedPages(slot) from None
+                cow = self.alloc.ensure_owned(table, idx)
+                if cow is not None:            # COW before the write
+                    src, dst = cow
+                    self.cache["layers"] = self._copy_page(
+                        self.cache["layers"], jnp.asarray(src, jnp.int32),
+                        jnp.asarray(dst, jnp.int32))
+                if self.sparse_decode:
+                    ph, lg = self.alloc.select_hot_sphere(
+                        table, w, scores, radius=self.hot_radius)
+                else:
+                    ph, lg = self.alloc.select_hot(table, w, scores)
+                phys[slot] = ph
+                logical[slot] = lg
+                write_page[slot] = table[idx]
+                write_off[slot] = length % page
+                n_res = sum(1 for pid in table if pid >= 0)
+                n_hot = int((lg >= 0).sum())
+                pages_total += n_res
+                pages_hot += n_hot
+                per_slot[slot] = (n_res, n_hot)
+                if self.kv_quant:
+                    resident.update(pid for pid in table if pid >= 0)
+                    hot_pids.update(int(p) for p in ph if p >= 0)
+            self.decode_sparsity = {"pages_total": pages_total,
+                                    "pages_hot": pages_hot,
+                                    "shard_skips": 0,
+                                    "per_slot": per_slot}
+            out = {"phys": jnp.asarray(phys),
+                   "logical": jnp.asarray(logical),
+                   "write_page": jnp.asarray(write_page),
+                   "write_off": jnp.asarray(write_off)}
             if self.kv_quant:
-                resident.update(pid for pid in table if pid >= 0)
-                hot_pids.update(int(p) for p in ph if p >= 0)
-        self.decode_sparsity = {"pages_total": pages_total,
-                                "pages_hot": pages_hot,
-                                "shard_skips": 0,
-                                "per_slot": per_slot}
-        out = {"phys": jnp.asarray(phys),
-               "logical": jnp.asarray(logical),
-               "write_page": jnp.asarray(write_page),
-               "write_off": jnp.asarray(write_off)}
-        if self.kv_quant:
-            out["qmask"] = jnp.asarray(self._quantize_cold(resident,
-                                                           hot_pids, phys))
-        return out
+                out["qmask"] = jnp.asarray(self._quantize_cold(resident,
+                                                               hot_pids, phys))
+            return out
 
     def _quantize_cold(self, resident: set, hot_pids: set,
                        phys: np.ndarray) -> np.ndarray:
@@ -512,9 +522,10 @@ class PagedBackend:
 
     def decode_step(self, slots, tables, lengths):
         ps = self._page_state(slots, tables, lengths)  # may raise NeedPages
-        self.cache["lengths"] = jnp.asarray(lengths, jnp.int32)
-        logits, self.cache = self._decode(self.params, self.last_token,
-                                          self.cache, ps)
+        with self.tel.tracer.span("decode.dispatch"):
+            self.cache["lengths"] = jnp.asarray(lengths, jnp.int32)
+            logits, self.cache = self._decode(self.params, self.last_token,
+                                              self.cache, ps)
         return logits
 
     def set_last_token(self, slot: int, tok: int) -> None:

@@ -445,10 +445,6 @@ class Scheduler:
     def tick(self, ex: Executor) -> list[Request]:
         self._tick += 1
         self._resumed_tick.clear()
-        if not self.tel.enabled:
-            self._admit_phase(ex)
-            self._prefill_phase(ex)
-            return self._decode_phase(ex)
         tr = self.tel.tracer
         with tr.span("phase.admit"):
             self._admit_phase(ex)

@@ -503,6 +503,157 @@ class TestEngineIntegration:
             f"{best_off:.4f}s vs {best_on:.4f}s"
 
 
+# ------------------------------------------------------ profiler spans
+
+# the spans every served tick of the batched paged path must put in a
+# JAX profiler trace, telemetry on or off
+PROFILED_SPANS = ("engine.tick", "engine.phase.admit",
+                  "engine.phase.prefill", "engine.phase.decode",
+                  "engine.decode.step", "engine.decode.page_state",
+                  "engine.decode.dispatch", "engine.decode.sample",
+                  "engine.decode.sync", "engine.decode.commit",
+                  "engine.prefill.sync")
+
+
+def _profiled_spans(log_dir) -> list[tuple[str, int, int]]:
+    """(name, start ns, end ns) of every ``engine.*`` host event in the
+    ``.xplane.pb`` a profiler session wrote under ``log_dir``."""
+    import jax
+    path, = pathlib.Path(log_dir).rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("engine.")]
+
+
+class TestProfilerSpans:
+    def _serve_profiled(self, smoke_lm, tmp_path, telemetry):
+        import jax
+
+        from repro.serving import SchedulerCfg
+        cfg, params = smoke_lm
+        llm = _paged_llm(cfg, params, pages=24, hot=4,
+                         scfg=SchedulerCfg(chunk_pages=1,
+                                           prefill_tokens=48),
+                         telemetry=telemetry)
+        for i in range(2):
+            llm.submit((np.arange(20, dtype=np.int32) + i) % cfg.vocab,
+                       max_tokens=4, rid=i)
+        with jax.profiler.trace(str(tmp_path)):
+            for _ in range(6):
+                llm.tick()
+        return llm, _profiled_spans(tmp_path)
+
+    def test_spans_reach_the_profiler_with_telemetry_off(self, smoke_lm,
+                                                         tmp_path):
+        llm, spans = self._serve_profiled(smoke_lm, tmp_path, None)
+        assert llm.tel is NULL_TELEMETRY
+        names = {n for n, _, _ in spans}
+        assert set(PROFILED_SPANS) <= names, \
+            sorted(set(PROFILED_SPANS) - names)
+        ticks = [(a, b) for n, a, b in spans if n == "engine.tick"]
+        assert len(ticks) == 6
+        for name, a, b in spans:
+            if name in ("engine.tick", "engine.api.tick"):
+                continue
+            assert any(ta <= a and b <= tb for ta, tb in ticks), \
+                f"{name} lies outside every engine.tick"
+        # the record stamping after the engine step is a span of its own
+        api = [(a, b) for n, a, b in spans if n == "engine.api.tick"]
+        assert len(api) == 6
+        assert all(not (ta <= a and b <= tb)
+                   for a, b in api for ta, tb in ticks)
+
+    def test_profiled_spans_are_kept_with_their_host_times(self, smoke_lm,
+                                                           tmp_path):
+        """Under a session the spans the trace holds are also kept in
+        memory, name for name; without one nothing is kept."""
+        from repro.obs.trace import PROFILED, profiled_spans
+        PROFILED.clear()
+        t0 = time.perf_counter()
+        llm, spans = self._serve_profiled(smoke_lm, tmp_path, None)
+        kept = profiled_spans()
+        assert sorted(n for n, *_ in kept) == sorted(n for n, _, _ in spans)
+        assert all(t0 <= a <= b <= time.perf_counter() for _, a, b, _ in kept)
+        ticks = [(a, b) for n, a, b, _ in kept if n == "engine.tick"]
+        steps = [(a, b) for n, a, b, _ in kept if n == "engine.decode.step"]
+        assert len(ticks) == 6 and steps
+        assert all(any(ta <= a and b <= tb for ta, tb in ticks)
+                   for a, b in steps)
+        PROFILED.clear()
+        llm.submit(np.arange(20, dtype=np.int32) % 7, max_tokens=2, rid=9)
+        llm.tick()
+        assert profiled_spans() == []
+
+    def test_telemetry_keeps_its_names_and_profiles_too(self, smoke_lm,
+                                                         tmp_path):
+        tel = Telemetry()
+        _, spans = self._serve_profiled(smoke_lm, tmp_path, tel)
+        kept = {e["name"] for e in tel.tracer.events if e["ph"] == "X"}
+        assert {"tick", "phase.admit", "phase.prefill", "phase.decode",
+                "prefill.pack", "prefill.dispatch", "prefill.commit",
+                "decode.step"} <= kept, kept
+        prefix = len("engine.")
+        assert kept == {n[prefix:] for n, _, _ in spans}
+        s = phase_summary(tel.tracer.events)
+        assert s["ticks"] == 6 and s["counts"]["decode"] == 6
+
+    def test_null_tracer_span_cost(self):
+        """Without a profiler session a span stays well under 1 us."""
+        import timeit
+        tr = NullTracer()
+
+        def span():
+            with tr.span("decode.step", lanes=4, compile=False):
+                pass
+        n = 20000
+        best = min(timeit.repeat(span, number=n, repeat=5)) / n
+        assert best < 5e-6, f"{best * 1e6:.2f} us per span"
+
+
+def _module_name(lowered) -> str:
+    return lowered.as_text().split("module @", 1)[1].split()[0]
+
+
+def test_paged_programs_are_named(smoke_lm):
+    """Every jitted program of the paged backend but the decode step
+    lowers under its own name (the name the profiler trace shows), and
+    none of those names holds ``decode``: the decode step is the one
+    unnamed program (``jit__unknown``), which is how the chip benchmark
+    tells it apart."""
+    from repro.serving import SchedulerCfg
+    cfg, params = smoke_lm
+    modules = {}
+    # batched varlen prefill, then the sequential chunked path
+    for prefill_tokens in (48, None):
+        llm = _paged_llm(cfg, params, pages=24, hot=4,
+                         scfg=SchedulerCfg(chunk_pages=1,
+                                           prefill_tokens=prefill_tokens),
+                         telemetry=None)
+        be = llm.engine.backend
+        for attr in ("_prefill", "_prefill_chunk", "_prefill_chunk_batch",
+                     "_decode", "_scatter"):
+            def first_call(*a, _jit=getattr(be, attr), _attr=attr):
+                if _attr not in modules:        # lower with real args
+                    modules[_attr] = _module_name(_jit.lower(*a))
+                    if _attr == "_decode":      # the audit takes the same
+                        modules["_audit"] = _module_name(be._audit.lower(*a))
+                return _jit(*a)
+            setattr(be, attr, first_call)
+        llm.submit(np.arange(40, dtype=np.int32) % cfg.vocab, max_tokens=3)
+        llm.run_until_done(max_steps=50)
+    assert modules == {"_prefill": "jit__prefill_fn",
+                       "_prefill_chunk": "jit__prefill_chunk_fn",
+                       "_prefill_chunk_batch":
+                           "jit__prefill_chunk_batch_fn",
+                       "_decode": "jit__unknown",
+                       "_audit": "jit__audit_fn",
+                       "_scatter": "jit__scatter_fn"}, modules
+    assert [a for a, m in modules.items() if "unknown" in m] == ["_decode"]
+    assert not [a for a, m in modules.items() if "decode" in m]
+
+
 # ------------------------------------------------------- spatial + tools
 
 def test_spatial_trace_shard_tags(tmp_path):
