@@ -22,8 +22,9 @@ DLZS prediction stage (§IV-A) decide which pages stay hot:
                    lowest-DLZS-score-first) and hot-page retention
                    (``select_hot``) for sparse decode.
 * ``paged_attention`` — gather-based decode over block tables, as an XLA
-                   ``jnp.take`` fallback and a Pallas scalar-prefetch kernel
-                   (kernels/paged.py); backend auto-dispatch picks pallas on
+                   ``jnp.take`` fallback and a Pallas kernel that DMAs
+                   blocks of each lane's valid pages (kernels/paged.py);
+                   backend auto-dispatch picks pallas on
                    TPU, xla elsewhere (``REPRO_PAGED_BACKEND`` overrides).
 * ``bucketing``  — prompt-length buckets (O(log max_len) prefill
                    compilations, not one per length) and the page-aligned

@@ -65,6 +65,12 @@ def tree_bytes(tree) -> int:
                if hasattr(leaf, "dtype"))
 
 
+def k_pool(cache_layers):
+    """The first K page pool of the stack, [L, n_pages, page, n_kv, dh]
+    (every attention block's pool has the same page, head and dtype)."""
+    return _leaves_by_key(cache_layers, "k")[0]
+
+
 def bytes_per_page(cache_layers) -> int:
     """Bytes one physical page occupies across the whole layer stack."""
     leaves = [l for l in jax.tree.leaves(cache_layers) if hasattr(l, "dtype")]

@@ -7,9 +7,16 @@ Pallas kernel with kernels/ref.py):
   out of the pool slab into a [B, W·page] working set, then one grouped-GQA
   masked softmax. Runs anywhere (the CPU test/serving path) and is the
   numerics oracle for the kernel.
-* ``kernels.paged.paged_decode_attention`` — Pallas kernel whose BlockSpec
-  index maps read the block table via scalar prefetch, DMA-ing pages
-  directly from the pool (no contiguous HBM copy at all).
+* ``kernels.paged.paged_decode_attention`` — Pallas kernel: one grid step
+  per lane, a loop over blocks of that lane's valid pages, each block's
+  pages DMA'd straight from the pool into VMEM (no contiguous HBM copy).
+  Blocks past a lane's last valid page are never fetched.
+
+Block-table contract: row b of ``phys``/``logical`` [B, W] holds the
+lane's valid slots as a prefix, in ascending position order, padded with
+-1. ``select_hot`` and ``select_hot_sphere`` (kvcache.allocator) build
+rows this way; the kernel's loop bound, ``n_valid[b]``, is counted from
+``logical`` on the device. Rows are masked by position < ``kv_len``.
 
 Both only touch the ``W`` hot pages the DLZS retention policy selected
 (kvcache.allocator.select_hot), so decode compute AND memory traffic scale
@@ -223,7 +230,8 @@ def paged_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     int8 cold-tier inputs, see ``_gather_hot``) is served by the XLA
     gather path — the Pallas kernel has no dequant lane yet, so a quant
     request falls back to XLA regardless of ``backend``, with a warning
-    at trace time."""
+    at trace time. The kernel needs each row's valid slots as a prefix
+    (module docstring); it counts them here, on the device."""
     if backend is None:
         backend = default_backend()
     if backend == "pallas" and quant is not None:
@@ -240,7 +248,9 @@ def paged_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     b, nh, d = q.shape
     scale = scale or (1.0 / math.sqrt(d))
     qg = _group(q, n_kv)
+    n_valid = (logical >= 0).sum(axis=1, dtype=jnp.int32)
     o = kpaged.paged_decode_attention(qg, k_pages, v_pages,
                                       jnp.maximum(phys, 0),
-                                      logical, kv_len, scale=scale)
+                                      logical, kv_len, n_valid,
+                                      scale=scale)
     return o.reshape(b, nh, d).astype(q.dtype)

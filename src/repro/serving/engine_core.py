@@ -88,7 +88,11 @@ class Backend(Protocol):
     # DLZS hot-width selection kept, "shard_skips": shards that skipped
     # their psum merge}. None before the first decode; the core turns it
     # into engine_decode_pages_skipped_total /
-    # engine_decode_shard_merges_skipped_total counters.
+    # engine_decode_shard_merges_skipped_total counters. Where its decode
+    # runs the Pallas kernel, the paged backend adds "blocks_visited" (page
+    # blocks the kernel visits, sum of ceil(hot pages / pages per block))
+    # and "blocks_window" (B * ceil(W / pages per block)):
+    # engine_decode_kernel_blocks_total.
 
     # -- admission ------------------------------------------------------
     def check_capacity(self, rid: int, total_tokens: int,
@@ -878,6 +882,15 @@ class EngineCore:
                     "fp K/V bytes the bounded hot-width gather did NOT "
                     "read (measured bytes-not-gathered)").inc(
                     skipped * getattr(self.backend, "page_bytes_gather", 0))
+            if "blocks_window" in sparsity:
+                blocks = self.tel.metrics.counter(
+                    "engine_decode_kernel_blocks_total",
+                    "page blocks of the Pallas decode kernel: visited, and "
+                    "skipped of the fixed window's B*ceil(W/ppb)")
+                visited = sparsity["blocks_visited"]
+                blocks.inc(visited, state="visited")
+                blocks.inc(sparsity["blocks_window"] - visited,
+                           state="skipped")
             if sparsity.get("shard_skips"):
                 self.tel.metrics.counter(
                     "engine_decode_shard_merges_skipped_total",

@@ -45,8 +45,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged import pages_per_block
 from repro.kvcache import (SCRATCH, PagePool, PagedAllocator, PoolExhausted,
                            bucketing, metrics, quant)
+from repro.kvcache.paged_attention import default_backend
 from repro.models import lm
 from repro.obs import NULL_TELEMETRY
 from repro.serving.engine_core import EngineCore
@@ -198,6 +200,14 @@ class PagedBackend:
             self.cache["layers"])
         self.page_bytes_int8 = metrics.quant_bytes_per_page(
             self.cache["layers"])
+        # pages per block of the Pallas decode kernel, from the K slab
+        # itself, where that kernel is the decode path (the XLA fallback
+        # and the int8 tier run none): _page_state counts its blocks
+        self.kernel_ppb = (
+            pages_per_block(metrics.k_pool(self.cache["layers"]),
+                            self.hot_width)
+            if default_backend() == "pallas" and not self.kv_quant
+            else None)
 
     # -- jitted kernels -----------------------------------------------------
 
@@ -448,7 +458,7 @@ class PagedBackend:
             scores = self._pull_scores() if need_scores else None
             resident: set[int] = set()
             hot_pids: set[int] = set()
-            pages_total = pages_hot = 0
+            pages_total = pages_hot = blocks = 0
             per_slot: dict[int, tuple[int, int]] = {}
             for slot in slots:
                 table = tables[slot]
@@ -478,6 +488,8 @@ class PagedBackend:
                 n_hot = int((lg >= 0).sum())
                 pages_total += n_res
                 pages_hot += n_hot
+                if self.kernel_ppb:
+                    blocks += -(-n_hot // self.kernel_ppb)
                 per_slot[slot] = (n_res, n_hot)
                 if self.kv_quant:
                     resident.update(pid for pid in table if pid >= 0)
@@ -486,6 +498,10 @@ class PagedBackend:
                                     "pages_hot": pages_hot,
                                     "shard_skips": 0,
                                     "per_slot": per_slot}
+            if self.kernel_ppb:
+                self.decode_sparsity.update(
+                    blocks_visited=blocks,
+                    blocks_window=b * -(-w // self.kernel_ppb))
             out = {"phys": jnp.asarray(phys),
                    "logical": jnp.asarray(logical),
                    "write_page": jnp.asarray(write_page),

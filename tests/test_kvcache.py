@@ -12,6 +12,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.kernels.paged import (VMEM_BUDGET, block_vmem_bytes,
+                                 pages_per_block)
 from repro.kvcache import (SCRATCH, PagePool, PagedAllocator, PoolExhausted,
                            bucketing, metrics)
 from repro.kvcache import paged_attention as pa
@@ -235,14 +237,99 @@ def test_paged_gather_decode_matches_dense_oracle():
                                        rtol=1e-5, atol=1e-5)
 
 
-def test_paged_pallas_kernel_matches_fallback():
-    q, kp, vp, phys, logical, kv_len, nkv, _ = _paged_inputs(seed=3)
-    o_xla = pa.paged_decode(q, kp, vp, phys, logical, kv_len, n_kv=nkv,
-                            backend="xla")
-    o_pl = pa.paged_decode(q, kp, vp, phys, logical, kv_len, n_kv=nkv,
-                           backend="pallas")
-    np.testing.assert_allclose(np.asarray(o_xla), np.asarray(o_pl),
+def _evicting_rows(page, n_pages, w, rng):
+    """Two lanes' (phys, logical) rows from an allocator that evicts: the
+    pool is first filled with cached prompt pages, then both lanes grow
+    in turn past the window, each page evicting the lowest-scored cached
+    one, and ``select_hot`` keeps ``w`` of them by the scores of what
+    was written since."""
+    pool = PagePool(n_pages, page_size=page)
+    alloc = PagedAllocator(pool, recent_pages=2)
+    scores = rng.random(n_pages)
+    while pool.free_pages():
+        prompt = rng.integers(0, 10_000, page).tolist()
+        pages, fresh, _ = alloc.admit(prompt, scores)
+        alloc.register_prompt_pages(prompt, pages, fresh)
+        alloc.release(pages)
+    tables = [[], []]
+    for _ in range(w + 3):
+        for table in tables:
+            table.append(alloc.extend(scores))
+    written = rng.random(n_pages)
+    return [alloc.select_hot(table, w, written) for table in tables]
+
+
+# lanes' valid page counts per case, in units of the kernel's block
+# (ppb = pages_per_block of the shape: 8 here) and window (W = 12)
+_KERNEL_CASES = {
+    "no_valid_page": lambda ppb, w: [0, 3],
+    "partial_page": lambda ppb, w: [1, 2],
+    "one_block": lambda ppb, w: [ppb, ppb],
+    "block_plus_page": lambda ppb, w: [ppb + 1, 2],
+    "full_window": lambda ppb, w: [w, w - 1],
+    "evicting_allocator": None,
+}
+
+
+@pytest.mark.parametrize("nh,nkv", [(4, 4), (8, 2)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_paged_pallas_kernel_matches_fallback(case, nh, nkv):
+    """The block kernel (interpret mode) against the XLA gather: lanes
+    with no valid page, a partial page, exactly one block, one block and
+    a page, a full window that is no multiple of the block, and rows an
+    evicting allocator scattered over the pool."""
+    B, d, P, page, W = 2, 8, 48, 4, 12
+    ppb = pages_per_block(jax.ShapeDtypeStruct((P, page, nkv, d),
+                                               jnp.float32), W)
+    assert W % ppb and ppb < W                  # the cases need a ragged W
+    rng = np.random.default_rng(sum(map(ord, case)) + nh)
+    phys = np.full((B, W), -1, np.int32)
+    logical = np.full((B, W), -1, np.int32)
+    if _KERNEL_CASES[case] is None:
+        for b, (ph, lg) in enumerate(_evicting_rows(page, P, W, rng)):
+            phys[b], logical[b] = ph, lg
+    else:
+        for b, n in enumerate(_KERNEL_CASES[case](ppb, W)):
+            phys[b, :n] = rng.choice(np.arange(1, P), n, replace=False)
+            logical[b, :n] = np.sort(rng.choice(2 * W, n, replace=False))
+    n_valid = (logical >= 0).sum(1)
+    kv_len = np.where(n_valid > 0, logical.max(1) * page
+                      + rng.integers(1, page, B), 0).astype(np.int32)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    q = jax.random.normal(ks[0], (B, nh, d), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, page, nkv, d), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, page, nkv, d), jnp.float32)
+    args = (q, kp, vp, jnp.asarray(phys), jnp.asarray(logical),
+            jnp.asarray(kv_len))
+    o_xla = pa.paged_decode(*args, n_kv=nkv, backend="xla")
+    o_pl = np.asarray(pa.paged_decode(*args, n_kv=nkv, backend="pallas"))
+    assert np.isfinite(o_pl).all()
+    np.testing.assert_allclose(np.asarray(o_xla), o_pl,
                                rtol=1e-5, atol=1e-5)
+    for b in np.flatnonzero(n_valid == 0):
+        assert not o_pl[b].any()                # no valid page: exactly 0
+
+
+# (page, G, d, dtype, W): the two chip cells' decode shapes, the parity
+# test's, a one-page window, and a page too large for any block of two
+@pytest.mark.parametrize("page,g,d,dtype,w", [
+    (16, 16, 128, jnp.bfloat16, 128), (16, 2, 128, jnp.bfloat16, 512),
+    (4, 2, 8, jnp.float32, 12), (16, 8, 128, jnp.bfloat16, 1),
+    (2048, 16, 128, jnp.float32, 64)],
+    ids=["olmo_1b", "chatglm3_6b", "small", "one_page_window", "huge_page"])
+def test_pages_per_block_from_shape(page, g, d, dtype, w):
+    """The block is 1..W pages, the largest power of two whose padded
+    VMEM footprint fits the budget (one page whatever its size). It reads
+    only the pool's trailing [page, G, d] and dtype: the kernel's pool
+    and the engine's stacked [L, P, page, G, d] slab give the same."""
+    pool = jax.ShapeDtypeStruct((64, page, g, d), dtype)
+    ppb = pages_per_block(pool, w)
+    assert 1 <= ppb <= w and ppb & (ppb - 1) == 0
+    assert ppb == 1 or block_vmem_bytes(ppb, page, g, d, dtype) <= VMEM_BUDGET
+    assert (2 * ppb > w
+            or block_vmem_bytes(2 * ppb, page, g, d, dtype) > VMEM_BUDGET)
+    slab = jax.ShapeDtypeStruct((3, 5, page, g, d), np.dtype(dtype))
+    assert pages_per_block(slab, w) == ppb
 
 
 def test_page_scores_reduce_lz_codes():

@@ -459,6 +459,63 @@ class TestEngineIntegration:
             dir="out", kind="shed") > 0
         assert tel.metrics.get("engine_preemptions_total") is None
 
+    def test_decode_kernel_blocks_counter(self, smoke_lm, monkeypatch):
+        """engine_decode_kernel_blocks_total, with the Pallas kernel (in
+        interpret mode) as the decode path: each decode step adds the
+        fixed window's B * ceil(W / ppb) blocks, of which ``visited`` is
+        the sum over lanes of ceil(hot pages / ppb) (decode_sparsity)."""
+        from repro.serving import SchedulerCfg
+        cfg, params = smoke_lm
+        monkeypatch.setenv("REPRO_PAGED_BACKEND", "pallas")
+        tel = Telemetry()
+        llm = _paged_llm(cfg, params, max_batch=3, pages=64, hot=6,
+                         scfg=SchedulerCfg(), telemetry=tel)
+        backend = llm.engine.backend
+        ppb, b, w = backend.kernel_ppb, 3, backend.hot_width
+        assert 1 <= ppb < w                  # lanes can skip a block
+        for i, n in enumerate((20, 40, 90)):
+            llm.submit((np.arange(n, dtype=np.int32) * 3 + i) % cfg.vocab,
+                       max_tokens=24, rid=i)
+
+        def blocks(state):
+            c = tel.metrics.get("engine_decode_kernel_blocks_total")
+            return 0 if c is None else c.value(state=state)
+
+        steps = skipped = 0
+        while llm.has_work():
+            seen = blocks("visited"), blocks("skipped")
+            llm.tick()
+            visited = blocks("visited") - seen[0]
+            step_skipped = blocks("skipped") - seen[1]
+            if visited + step_skipped == 0:
+                continue                     # a tick with no decode step
+            steps += 1
+            skipped += step_skipped
+            assert visited + step_skipped == b * -(-w // ppb)
+            per_slot = backend.decode_sparsity["per_slot"]
+            assert visited == sum(-(-n_hot // ppb)
+                                  for _, n_hot in per_slot.values())
+        assert steps > 0 and skipped > 0
+
+    @pytest.mark.parametrize("backend,kv_quant", [("xla", None),
+                                                  ("pallas", "int8")])
+    def test_decode_kernel_blocks_only_where_the_kernel_runs(
+            self, smoke_lm, monkeypatch, backend, kv_quant):
+        """The XLA fallback and the int8 tier run no Pallas kernel, so
+        they publish no kernel blocks (the page counters still count)."""
+        from repro.serving import SchedulerCfg
+        cfg, params = smoke_lm
+        monkeypatch.setenv("REPRO_PAGED_BACKEND", backend)
+        tel = Telemetry()
+        llm = _paged_llm(cfg, params, max_batch=2, pages=64, hot=6,
+                         scfg=SchedulerCfg(kv_quant=kv_quant),
+                         telemetry=tel)
+        assert llm.engine.backend.kernel_ppb is None
+        _tick_all(llm, [np.arange(30, dtype=np.int32) % cfg.vocab],
+                  max_tokens=4)
+        assert tel.metrics.get("engine_decode_kernel_blocks_total") is None
+        assert tel.metrics.get("engine_decode_pages_considered_total")
+
     def test_disabled_telemetry_overhead_under_5pct(self, smoke_lm):
         """The acceptance bound: serving with the default NULL telemetry
         must not run measurably slower than... anything. We compare it

@@ -48,18 +48,23 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
-# (batch, kv heads G, q heads per kv head R): OLMo-1B decode (16 MHA heads)
-# and a grouped-query shape
-@pytest.mark.parametrize("b,g,r", [(8, 16, 1), (4, 8, 4)],
-                         ids=["olmo_1b_G16_R1", "gqa_G8_R4"])
-def test_paged_decode_compiles(one_chip, b, g, r):
-    d, n_pages, page, w = 128, 1024, 16, 16
+# (batch, kv heads G, q heads per kv head R, hot window W, pool pages):
+# OLMo-1B decode (16 MHA heads), a grouped-query shape, and the two chip
+# benchmark cells' decode shapes, where a block of pages that overflows
+# VMEM would fail here before a chip run
+@pytest.mark.parametrize("b,g,r,w,n_pages", [
+    (8, 16, 1, 16, 1024), (4, 8, 4, 16, 1024),
+    (16, 16, 1, 128, 2049), (16, 2, 16, 512, 8193)],
+    ids=["olmo_1b_G16_R1", "gqa_G8_R4", "olmo_1b.decode_batch",
+         "chatglm3_6b.decode_batch"])
+def test_paged_decode_compiles(one_chip, b, g, r, w, n_pages):
+    d, page = 128, 16
     bf, i32 = jnp.bfloat16, jnp.int32
-    _compile(lambda q, k, v, ph, lg, kl: paged_decode_attention(
-        q, k, v, ph, lg, kl, scale=d ** -0.5, interpret=False), one_chip,
-        ((b, g, r, d), bf), ((n_pages, page, g, d), bf),
+    _compile(lambda q, k, v, ph, lg, kl, nv: paged_decode_attention(
+        q, k, v, ph, lg, kl, nv, scale=d ** -0.5, interpret=False),
+        one_chip, ((b, g, r, d), bf), ((n_pages, page, g, d), bf),
         ((n_pages, page, g, d), bf), ((b, w), i32), ((b, w), i32),
-        ((b,), i32))
+        ((b,), i32), ((b,), i32))
 
 
 def test_flash_compiles(one_chip):
