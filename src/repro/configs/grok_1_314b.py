@@ -17,7 +17,7 @@ def config() -> ModelCfg:
         vocab=131072,
         pattern=(BlockCfg("attn", "moe"),),
         norm="rmsnorm", mlp_act="gelu", mlp_gated=True,
-        moe=MoECfg(d_model=6144, d_ff=32768, n_experts=8, top_k=2,
+        moe=MoECfg(n_experts=8, top_k=2,
                    act="gelu"),
         star=STARConfig(top_k_ratio=0.2),
         optimizer="adafactor", train_accum=8,
@@ -30,7 +30,7 @@ def smoke_config() -> ModelCfg:
         d_model=64, n_layers=2, n_heads=4, n_kv=2, d_ff=128, vocab=512,
         pattern=(BlockCfg("attn", "moe"),),
         norm="rmsnorm", mlp_act="gelu", mlp_gated=True,
-        moe=MoECfg(d_model=64, d_ff=128, n_experts=8, top_k=2, act="gelu",
+        moe=MoECfg(n_experts=8, top_k=2, act="gelu",
                    token_chunk=64),
         star=STARConfig(top_k_ratio=0.5, block_q=16, block_kv=16),
         q_chunk=64, seq_loss_chunk=64, vocab_pad_to=64,

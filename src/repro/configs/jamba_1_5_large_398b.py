@@ -29,7 +29,7 @@ def config() -> ModelCfg:
         vocab=65536,
         pattern=_pattern(),
         norm="rmsnorm", mlp_act="silu", mlp_gated=True,
-        moe=MoECfg(d_model=8192, d_ff=24576, n_experts=16, top_k=2),
+        moe=MoECfg(n_experts=16, top_k=2),
         mamba=MambaCfg(d_model=8192, expand=2, head_dim=64, d_state=16),
         star=STARConfig(top_k_ratio=0.2),
         optimizer="adafactor", train_accum=8,
@@ -42,7 +42,7 @@ def smoke_config() -> ModelCfg:
         d_model=64, n_layers=8, n_heads=4, n_kv=2, d_ff=128, vocab=512,
         pattern=_pattern(),
         norm="rmsnorm", mlp_act="silu", mlp_gated=True,
-        moe=MoECfg(d_model=64, d_ff=128, n_experts=4, top_k=2,
+        moe=MoECfg(n_experts=4, top_k=2,
                    token_chunk=64),
         mamba=MambaCfg(d_model=64, expand=2, head_dim=16, d_state=8,
                        chunk=32),

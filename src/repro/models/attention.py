@@ -37,6 +37,7 @@ class AttentionCfg:
     rope_fraction: float = 1.0   # 0 = none, 0.5 = ChatGLM 2d-RoPE, 1 = full
     rope_theta: float = 1e4
     qkv_bias: bool = False
+    qk_norm: bool = False        # RMSNorm over all heads of q and of k
     causal: bool = True
     q_chunk: int = 1024          # query tile for chunked dense softmax
     star: Optional[STARConfig] = None   # sparse mode (None = dense)
@@ -63,6 +64,9 @@ def init(key, cfg: AttentionCfg):
         p["bq"] = jnp.zeros((nh, dh), cfg.dtype)
         p["bk"] = jnp.zeros((nkv, dh), cfg.dtype)
         p["bv"] = jnp.zeros((nkv, dh), cfg.dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = common.rmsnorm_init(nh * dh)
+        p["k_norm"] = common.rmsnorm_init(nkv * dh)
     return p
 
 
@@ -77,11 +81,22 @@ def axes(cfg: AttentionCfg):
         a["bq"] = ("heads", "head_dim")
         a["bk"] = ("kv_heads", "head_dim")
         a["bv"] = ("kv_heads", "head_dim")
+    if cfg.qk_norm:
+        a["q_norm"] = {"scale": (None,)}
+        a["k_norm"] = {"scale": (None,)}
     return a
 
 
+def _norm_heads(params, x):
+    """RMSNorm of [..., n, dh] over all n * dh features at once."""
+    return common.rmsnorm_apply(
+        params, x.reshape(*x.shape[:-2], -1)).reshape(x.shape)
+
+
 def _project_qkv(params, cfg: AttentionCfg, x, positions):
-    """x [B,S,H] -> q [B,S,nh,dh], k/v [B,S,nkv,dh] with RoPE applied."""
+    """x [B,S,H] -> q [B,S,nh,dh], k/v [B,S,nkv,dh] with RoPE applied
+    (after the QK-norm, when the model has one). Every attention path
+    projects here, so the K it caches is the normed and rotated one."""
     q = jnp.einsum("bsh,hnd->bsnd", x, params["wq"])
     k = jnp.einsum("bsh,hnd->bsnd", x, params["wk"])
     v = jnp.einsum("bsh,hnd->bsnd", x, params["wv"])
@@ -89,6 +104,9 @@ def _project_qkv(params, cfg: AttentionCfg, x, positions):
         q = q + params["bq"]
         k = k + params["bk"]
         v = v + params["bv"]
+    if cfg.qk_norm:
+        q = _norm_heads(params["q_norm"], q)
+        k = _norm_heads(params["k_norm"], k)
     if cfg.rope_fraction > 0:
         q = common.apply_rope(q, positions, theta=cfg.rope_theta,
                               rotary_fraction=cfg.rope_fraction)

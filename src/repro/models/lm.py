@@ -49,7 +49,14 @@ class ModelCfg:
     rope_theta: float = 1e4
     qkv_bias: bool = False
     head_dim: Optional[int] = None
-    moe: Optional[moe.MoECfg] = None
+    qk_norm: bool = False          # RMSNorm over the whole projected q and
+    #                                k before RoPE (OLMoE); the cache holds
+    #                                the normed, rotated K
+    moe: Optional[moe.MoECfg] = None   # router width, experts per token
+    experts_held: int = 0          # experts of each MoE layer held on this
+    #                                chip (0: all); serving computes their
+    #                                share of the layer's output
+    expert_first: int = 0          # global index of the first held expert
     mamba: Optional[ssm.MambaCfg] = None
     xlstm_heads: int = 0
     enc_layers: int = 0            # > 0 => encoder-decoder
@@ -95,9 +102,21 @@ class ModelCfg:
             d_model=self.d_model, n_heads=self.n_heads, n_kv=self.n_kv,
             head_dim=self.dh, rope_fraction=self.rope_fraction,
             rope_theta=self.rope_theta, qkv_bias=self.qkv_bias,
+            qk_norm=self.qk_norm,
             causal=self.causal if causal is None else causal,
             q_chunk=self.q_chunk, star=use_star,
             chunk_sparse=self.star_chunk_sparse, dtype=self.dtype)
+
+    def moe_cfg(self) -> moe.MoECfg:
+        """The expert layer at this model's widths (``d_model``, and
+        ``d_ff`` as each expert's), so a ``dataclasses.replace`` of the
+        model's fields reaches it."""
+        return dataclasses.replace(self.moe, d_model=self.d_model,
+                                   d_ff=self.d_ff)
+
+    @property
+    def has_moe(self) -> bool:
+        return any(blk.ffn == "moe" for blk in self.pattern)
 
     def mlp_cfg(self) -> mlp.MLPCfg:
         return mlp.MLPCfg(self.d_model, self.d_ff, self.mlp_act,
@@ -131,7 +150,8 @@ def _block_init(key, cfg: ModelCfg, blk: BlockCfg, causal: bool = True):
     if blk.ffn != "none":
         p["norm2"] = common.norm_init(cfg.norm, cfg.d_model)
         if blk.ffn == "moe":
-            p["ffn"] = moe.init(ks[2], cfg.moe)
+            p["ffn"] = moe.init(ks[2], cfg.moe_cfg(),
+                                held=cfg.experts_held)
         else:
             p["ffn"] = mlp.init(ks[2], cfg.mlp_cfg())
     return p
@@ -152,7 +172,7 @@ def _block_axes(cfg: ModelCfg, blk: BlockCfg):
         a["cross"] = attention.cross_axes(cfg.attn_cfg("train"))
     if blk.ffn != "none":
         a["norm2"] = common.norm_axes(cfg.norm)
-        a["ffn"] = moe.axes(cfg.moe) if blk.ffn == "moe" \
+        a["ffn"] = moe.axes(cfg.moe_cfg()) if blk.ffn == "moe" \
             else mlp.axes(cfg.mlp_cfg())
     return a
 
@@ -160,9 +180,13 @@ def _block_axes(cfg: ModelCfg, blk: BlockCfg):
 def _block_apply(params, cfg: ModelCfg, blk: BlockCfg, x, positions, *,
                  mode: str, causal: bool = True, cache=None,
                  enc_cache=None, lengths=None, cache_len=None,
-                 page_state=None, spatial_axis=None):
-    """Returns (y, new_cache, aux_loss)."""
+                 page_state=None, spatial_axis=None, valid=None):
+    """Returns (y, new_cache, aux_loss, moe_hist). An MoE block trains
+    through the capacity path and serves every other mode dropless, with
+    ``moe_hist`` [held] its assignments per held expert from the tokens
+    ``valid`` marks real (None for other blocks)."""
     aux = jnp.zeros((), jnp.float32)
+    hist = None
     h = common.norm_apply(cfg.norm, params["norm1"], x)
     acfg = cfg.attn_cfg(mode, causal)
     new_cache = {}
@@ -259,13 +283,20 @@ def _block_apply(params, cfg: ModelCfg, blk: BlockCfg, x, positions, *,
 
     if blk.ffn != "none":
         h2 = common.norm_apply(cfg.norm, params["norm2"], x)
-        if blk.ffn == "moe":
-            y2, a = moe.apply(params["ffn"], cfg.moe, h2)
+        if blk.ffn == "moe" and mode == "train":
+            if cfg.experts_held:
+                raise ValueError("a held share of the experts serves; it "
+                                 "does not train")
+            y2, a = moe.apply(params["ffn"], cfg.moe_cfg(), h2)
             aux = aux + a * cfg.moe.aux_loss_weight
+        elif blk.ffn == "moe":
+            y2, hist = moe.apply_dropless(
+                params["ffn"], cfg.moe_cfg(), h2, first=cfg.expert_first,
+                held=cfg.experts_held, valid=valid)
         else:
             y2 = mlp.apply(params["ffn"], cfg.mlp_cfg(), h2)
         x = x + y2
-    return x, new_cache, aux
+    return x, new_cache, aux, hist
 
 
 def _superblock_init(key, cfg: ModelCfg, pattern, causal=True):
@@ -281,18 +312,20 @@ def _superblock_axes(cfg: ModelCfg, pattern):
 def _superblock_apply(params, cfg: ModelCfg, pattern, x, positions, *,
                       mode, causal=True, caches=None, enc_cache=None,
                       lengths=None, cache_len=None, page_state=None,
-                      spatial_axis=None):
-    new_caches, aux_total = {}, jnp.zeros((), jnp.float32)
+                      spatial_axis=None, valid=None):
+    new_caches, aux_total, hists = {}, jnp.zeros((), jnp.float32), []
     for i, blk in enumerate(pattern):
-        x, nc, aux = _block_apply(
+        x, nc, aux, hist = _block_apply(
             params[f"b{i}"], cfg, blk, x, positions, mode=mode,
             causal=causal, cache=caches[f"b{i}"] if caches else None,
             enc_cache=enc_cache, lengths=lengths, cache_len=cache_len,
-            page_state=page_state, spatial_axis=spatial_axis)
+            page_state=page_state, spatial_axis=spatial_axis, valid=valid)
         x = shd(x, "batch", "act_seq", "embed")
         new_caches[f"b{i}"] = nc
         aux_total = aux_total + aux
-    return x, new_caches, aux_total
+        if hist is not None:
+            hists.append(hist)
+    return x, new_caches, aux_total, (jnp.stack(hists) if hists else None)
 
 
 # ---------------------------------------------------------------------------
@@ -367,30 +400,42 @@ def _remat(fn, cfg: ModelCfg):
 
 def _run_stack(blocks, cfg: ModelCfg, pattern, x, positions, *, mode,
                causal=True, caches=None, enc_cache=None, lengths=None,
-               cache_len=None, page_state=None, spatial_axis=None):
-    """Scan the super-block over the repeat dim. Returns (x, caches, aux)."""
+               cache_len=None, page_state=None, spatial_axis=None,
+               valid=None):
+    """Scan the super-block over the repeat dim. Returns (x, caches, aux,
+    moe_hist): ``moe_hist`` [MoE layers, held] int32 counts each layer's
+    assignments per held expert (None without MoE blocks)."""
 
     def body(carry, layer_in):
         xc, aux_acc = carry
         xc = shd(xc, "batch", "act_seq", "embed")  # pin the carry sharding
         lp = layer_in["params"]
         lc = layer_in.get("cache")
-        y, nc, aux = _superblock_apply(
+        y, nc, aux, hist = _superblock_apply(
             lp, cfg, pattern, xc, positions, mode=mode, causal=causal,
             caches=lc, enc_cache=enc_cache, lengths=lengths,
             cache_len=cache_len, page_state=page_state,
-            spatial_axis=spatial_axis)
+            spatial_axis=spatial_axis, valid=valid)
         y = shd(y, "batch", "act_seq", "embed")
-        return (y, aux_acc + aux), nc
+        return (y, aux_acc + aux), (nc, hist)
 
     body_fn = _remat(body, cfg) if mode == "train" else body
     xs = {"params": blocks}
     if caches is not None:
         xs["cache"] = caches
-    (x, aux), new_caches = jax.lax.scan(body_fn, (x, jnp.zeros((),
-                                                               jnp.float32)),
-                                        xs)
-    return x, new_caches, aux
+    (x, aux), (new_caches, hist) = jax.lax.scan(
+        body_fn, (x, jnp.zeros((), jnp.float32)), xs)
+    if hist is not None:                        # [R, blocks, held]
+        hist = hist.reshape(-1, hist.shape[-1])
+    return x, new_caches, aux, hist
+
+
+def _with_hist(out: dict, hist) -> dict:
+    """A serving path's cache dict, with the MoE routing counts beside it
+    (``moe_hist``) when the model has MoE layers."""
+    if hist is not None:
+        out["moe_hist"] = hist
+    return out
 
 
 def _logits(params, cfg: ModelCfg, x):
@@ -406,7 +451,7 @@ def _encode(params, cfg: ModelCfg, batch):
     x = shd(x, "batch", "seq", "embed")
     s = x.shape[1]
     positions = jnp.arange(s)
-    x, _, _ = _run_stack(params["enc_blocks"], cfg,
+    x, _, _, _ = _run_stack(params["enc_blocks"], cfg,
                          (BlockCfg("attn", "dense"),), x, positions,
                          mode="encode", causal=False)
     return common.norm_apply(cfg.norm, params["enc_norm"], x)
@@ -422,9 +467,9 @@ def loss_fn(params, cfg: ModelCfg, batch):
     s = x.shape[1]
     positions = jnp.arange(s)
     enc_cache = _encode(params, cfg, batch) if cfg.enc_layers else None
-    x, _, aux = _run_stack(params["blocks"], cfg, cfg.pattern, x, positions,
-                           mode="train", causal=cfg.causal,
-                           enc_cache=enc_cache)
+    x, _, aux, _ = _run_stack(params["blocks"], cfg, cfg.pattern, x,
+                              positions, mode="train", causal=cfg.causal,
+                              enc_cache=enc_cache)
     x = common.norm_apply(cfg.norm, params["final_norm"], x)
 
     labels = batch["labels"]
@@ -471,9 +516,13 @@ def prefill(params, cfg: ModelCfg, batch, *, cache_len: Optional[int] = None,
     b, s, _ = x.shape
     positions = jnp.arange(s)
     enc_cache = _encode(params, cfg, batch) if cfg.enc_layers else None
-    x, caches, _ = _run_stack(params["blocks"], cfg, cfg.pattern, x,
-                              positions, mode="prefill", causal=cfg.causal,
-                              enc_cache=enc_cache, cache_len=cache_len)
+    valid = None
+    if cfg.has_moe and last_index is not None:
+        valid = jnp.arange(s)[None, :] <= last_index[:, None]
+    x, caches, _, hist = _run_stack(
+        params["blocks"], cfg, cfg.pattern, x, positions, mode="prefill",
+        causal=cfg.causal, enc_cache=enc_cache, cache_len=cache_len,
+        valid=valid)
     if last_index is None:
         x_last = x[:, -1:, :]
         lengths = jnp.full((b,), s, jnp.int32)
@@ -482,7 +531,8 @@ def prefill(params, cfg: ModelCfg, batch, *, cache_len: Optional[int] = None,
             x, last_index[:, None, None].astype(jnp.int32), axis=1)
         lengths = last_index.astype(jnp.int32) + 1
     logits = _logits(params, cfg, x_last)
-    return logits[:, 0], {"layers": caches, "lengths": lengths}
+    return logits[:, 0], _with_hist({"layers": caches, "lengths": lengths},
+                                    hist)
 
 
 def prefill_chunk_paged(params, cfg: ModelCfg, batch, cache, chunk_state):
@@ -506,15 +556,17 @@ def prefill_chunk_paged(params, cfg: ModelCfg, batch, cache, chunk_state):
     x = _embed_inputs(params, cfg, batch)
     b, c, _ = x.shape
     positions = chunk_state["past_len"][:, None] + jnp.arange(c)[None, :]
-    x, chunk_caches, _ = _run_stack(
+    valid = (jnp.arange(c)[None, :] <= chunk_state["last_index"][:, None]
+             if cfg.has_moe else None)
+    x, chunk_caches, _, hist = _run_stack(
         params["blocks"], cfg, cfg.pattern, x, positions,
         mode="prefill_chunk", causal=cfg.causal, caches=cache["layers"],
-        page_state=chunk_state)
+        page_state=chunk_state, valid=valid)
     x_last = jnp.take_along_axis(
         x, chunk_state["last_index"][:, None, None].astype(jnp.int32),
         axis=1)
     logits = _logits(params, cfg, x_last)
-    return logits[:, 0], {"layers": chunk_caches}
+    return logits[:, 0], _with_hist({"layers": chunk_caches}, hist)
 
 
 def prefill_chunk_batch_paged(params, cfg: ModelCfg, batch, cache,
@@ -544,14 +596,15 @@ def prefill_chunk_batch_paged(params, cfg: ModelCfg, batch, cache,
     """
     x = _embed_inputs(params, cfg, batch)
     positions = pack_state["positions"][None, :]
-    x, chunk_caches, _ = _run_stack(
+    valid = (pack_state["seg_ids"] >= 0)[None, :] if cfg.has_moe else None
+    x, chunk_caches, _, hist = _run_stack(
         params["blocks"], cfg, cfg.pattern, x, positions,
         mode="prefill_chunk_batch", causal=cfg.causal,
-        caches=cache["layers"], page_state=pack_state)
+        caches=cache["layers"], page_state=pack_state, valid=valid)
     x_last = jnp.take(x[0], pack_state["last_index"].astype(jnp.int32),
                       axis=0)[None]
     logits = _logits(params, cfg, x_last)
-    return logits[0], {"layers": chunk_caches}
+    return logits[0], _with_hist({"layers": chunk_caches}, hist)
 
 
 def prefill_chunk_batch_spatial(params, cfg: ModelCfg, batch, cache,
@@ -581,7 +634,7 @@ def prefill_chunk_batch_spatial(params, cfg: ModelCfg, batch, cache,
         ps = {k: (v[0] if k in sharded else v) for k, v in ps.items()}
         x = _embed_inputs(p, cfg, {"tokens": toks})
         positions = ps["positions"][None, :]
-        x, new_layers, _ = _run_stack(
+        x, new_layers, _, _ = _run_stack(
             p["blocks"], cfg, cfg.pattern, x, positions,
             mode="prefill_chunk_batch", causal=cfg.causal, caches=layers,
             page_state=ps, spatial_axis=axis)
@@ -607,12 +660,13 @@ def decode_step(params, cfg: ModelCfg, tokens, cache):
     x = jnp.take(params["embed"], tokens, axis=0)
     x = shd(x, "batch", "seq", "embed")
     lengths = cache["lengths"]
-    x, new_caches, _ = _run_stack(params["blocks"], cfg, cfg.pattern, x,
-                                  lengths[:, None], mode="decode",
-                                  causal=cfg.causal,
-                                  caches=cache["layers"], lengths=lengths)
+    x, new_caches, _, hist = _run_stack(
+        params["blocks"], cfg, cfg.pattern, x, lengths[:, None],
+        mode="decode", causal=cfg.causal, caches=cache["layers"],
+        lengths=lengths)
     logits = _logits(params, cfg, x)
-    return logits[:, 0], {"layers": new_caches, "lengths": lengths + 1}
+    return logits[:, 0], _with_hist({"layers": new_caches,
+                                     "lengths": lengths + 1}, hist)
 
 
 def _spatial_specs(mesh, axis: str):
@@ -651,7 +705,7 @@ def prefill_chunk_spatial(params, cfg: ModelCfg, batch, cache, chunk_state,
         x = _embed_inputs(p, cfg, {"tokens": toks})
         b, c, _ = x.shape
         positions = cs["past_len"][:, None] + jnp.arange(c)[None, :]
-        x, new_layers, _ = _run_stack(
+        x, new_layers, _, _ = _run_stack(
             p["blocks"], cfg, cfg.pattern, x, positions,
             mode="prefill_chunk", causal=cfg.causal, caches=layers,
             page_state=cs, spatial_axis=axis)
@@ -700,7 +754,7 @@ def decode_step_spatial(params, cfg: ModelCfg, tokens, cache, page_state,
         layers = jax.tree.map(lambda leaf: leaf[0], layers)
         ps = jax.tree.map(lambda leaf: leaf[0], ps)
         x = jnp.take(p["embed"], toks, axis=0)
-        x, new_layers, _ = _run_stack(
+        x, new_layers, _, _ = _run_stack(
             p["blocks"], cfg, cfg.pattern, x, lengths[:, None],
             mode="decode", causal=cfg.causal, caches=layers,
             lengths=lengths, page_state=ps, spatial_axis=axis)
@@ -738,7 +792,7 @@ def audit_decode_spatial(params, cfg: ModelCfg, tokens, cache, page_state,
         layers = jax.tree.map(lambda leaf: leaf[0], layers)
         ps = jax.tree.map(lambda leaf: leaf[0], ps)
         x = jnp.take(p["embed"], toks, axis=0)
-        _, new_layers, _ = _run_stack(
+        _, new_layers, _, _ = _run_stack(
             p["blocks"], cfg, cfg.pattern, x, lengths[:, None],
             mode="decode", causal=cfg.causal, caches=layers,
             lengths=lengths, page_state=ps, spatial_axis=axis)
@@ -774,10 +828,13 @@ def decode_step_paged(params, cfg: ModelCfg, tokens, cache, page_state):
     x = jnp.take(params["embed"], tokens, axis=0)
     x = shd(x, "batch", "seq", "embed")
     lengths = cache["lengths"]
-    x, new_caches, _ = _run_stack(params["blocks"], cfg, cfg.pattern, x,
-                                  lengths[:, None], mode="decode",
-                                  causal=cfg.causal,
-                                  caches=cache["layers"], lengths=lengths,
-                                  page_state=page_state)
+    # a lane is real when it has a page to attend (idle lanes have none)
+    valid = ((page_state["logical"] >= 0).any(axis=-1)[:, None]
+             if cfg.has_moe else None)
+    x, new_caches, _, hist = _run_stack(
+        params["blocks"], cfg, cfg.pattern, x, lengths[:, None],
+        mode="decode", causal=cfg.causal, caches=cache["layers"],
+        lengths=lengths, page_state=page_state, valid=valid)
     logits = _logits(params, cfg, x)
-    return logits[:, 0], {"layers": new_caches, "lengths": lengths + 1}
+    return logits[:, 0], _with_hist({"layers": new_caches,
+                                     "lengths": lengths + 1}, hist)

@@ -20,6 +20,14 @@ Capacity-based token dropping (capacity_factor, default 1.25) bounds the
 buffers; a load-balancing auxiliary loss (Switch-style) keeps routing usable
 for training. Long token streams are processed in fixed-size chunks via
 lax.scan so dispatch buffers stay O(chunk), not O(batch·seq).
+
+Serving never drops: ``apply_dropless`` routes every token over all
+experts and sends each of its top-k choices to its expert, through grouped
+matmuls (``jax.lax.ragged_dot``) over the experts this chip holds, so a
+token's output never depends on the tokens it shares a batch with. A chip
+of an expert-parallel deployment holds a contiguous share of the experts
+(``init(..., held=n)``) and computes only their part of the layer's output;
+a token none of whose choices is held gets no expert term here.
 """
 
 from __future__ import annotations
@@ -38,15 +46,17 @@ from repro.shardlib import shd
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
-    d_model: int
-    d_ff: int                   # per-expert hidden dim
     n_experts: int
     top_k: int
+    d_model: int = 0            # the model's widths: lm.ModelCfg.moe_cfg()
+    d_ff: int = 0               # sets both (d_ff is the per-expert width)
     act: str = "silu"
     gated: bool = True
     capacity_factor: float = 1.25
     token_chunk: int = 2048     # per-shard tokens per dispatch round
     aux_loss_weight: float = 0.01
+    renorm: bool = True         # renormalise the top-k probabilities
+    #                             (OLMoE's norm_topk_prob: false)
     dtype: jnp.dtype = jnp.bfloat16
 
     def virtual(self, ep: int) -> tuple[int, int]:
@@ -61,14 +71,16 @@ class MoECfg:
         return ep, ep // self.n_experts
 
 
-def init(key, cfg: MoECfg, ep_hint: int = 16):
+def init(key, cfg: MoECfg, ep_hint: int = 16, held: int = 0):
     """Parameters are stored pre-split into virtual-expert layout [V, ...].
 
     ``ep_hint`` is the maximum EP degree the layout must divide (the
     production data-axis size); running on a smaller mesh still works because
-    V stays divisible by any EP' | EP.
+    V stays divisible by any EP' | EP. With ``held`` > 0 only that many whole
+    experts are drawn ([held, ...]: one chip's share); the router keeps all
+    ``n_experts`` outputs.
     """
-    v, tpw = cfg.virtual(ep_hint)   # V = max(E, EP), tpw = V/E
+    v, tpw = (held, 1) if held else cfg.virtual(ep_hint)
     ks = jax.random.split(key, 4)
     ff = cfg.d_ff // tpw
     p = {
@@ -103,7 +115,8 @@ def _gate(x, wg, cfg: MoECfg):
                         wg.astype(jnp.float32))
     probs_full = jax.nn.softmax(logits, axis=-1)
     top_p, eidx = jax.lax.top_k(probs_full, cfg.top_k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if cfg.renorm:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
     # Switch-style load-balance loss: E * sum_e f_e * P_e.
     f = jnp.zeros((cfg.n_experts,), jnp.float32).at[eidx.reshape(-1)].add(
         1.0) / (eidx.size)
@@ -243,3 +256,60 @@ def apply(params, cfg: MoECfg, x):
                        in_specs=(bspec, pspecs),
                        out_specs=(bspec, P()))
     return fn(x, params)
+
+
+def apply_dropless(params, cfg: MoECfg, x, *, first: int = 0, held: int = 0,
+                   valid=None):
+    """Dropless expert layer over the experts held here (serving).
+
+    x [..., H] -> (y [..., H], hist [held] int32). The router scores every
+    token over all ``n_experts``; each of its top-k choices that falls in
+    the held range [first, first + held) (``held`` 0: all experts) goes to
+    that expert with no capacity limit, and the outputs are summed weighted
+    by the router probabilities (renormalised over the top-k only with
+    ``cfg.renorm``). Assignments are sorted by held (virtual) expert and run
+    as three grouped matmuls (``ragged_dot``); choices of experts held
+    elsewhere sort past the last group, which computes nothing for them.
+    ``hist`` counts, per held expert, the assignments of the tokens that
+    ``valid`` (x's leading shape, default all) marks real.
+    """
+    shape = x.shape
+    t = x.reshape(-1, shape[-1])
+    n = t.shape[0]
+    held = held or cfg.n_experts
+    hv = params["w1"].shape[0]              # held virtual experts
+    tpw = hv // held
+    kc = cfg.top_k * tpw
+
+    logits = jnp.einsum("th,he->te", t.astype(jnp.float32),
+                        params["wg"].astype(jnp.float32))
+    top_p, eidx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    if cfg.renorm:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    local = eidx - first
+    here = (local >= 0) & (local < held)                # [n, k]
+    vloc = (local[..., None] * tpw + jnp.arange(tpw)).reshape(n, kc)
+    here_v = jnp.repeat(here, tpw, axis=-1)
+    flat = jnp.where(here_v, vloc, hv).reshape(-1)      # [n * kc]
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.zeros((hv + 1,), jnp.int32).at[flat].add(1)[:hv]
+    rows = jnp.take(t, order // kc, axis=0)
+
+    act = common.activation(cfg.act)
+    f32 = jnp.float32
+    with jax.named_scope("moe_gmm"):
+        h = act(jax.lax.ragged_dot(rows, params["w1"], sizes,
+                                   preferred_element_type=f32))
+        if cfg.gated:
+            h = h * jax.lax.ragged_dot(rows, params["w3"], sizes,
+                                       preferred_element_type=f32)
+        y = jax.lax.ragged_dot(h.astype(x.dtype), params["w2"], sizes,
+                               preferred_element_type=f32)
+    y = jnp.zeros_like(y).at[order].set(y).reshape(n, kc, shape[-1])
+    w = jnp.where(here_v, jnp.repeat(top_p, tpw, axis=-1), 0.0)
+    out = (y * w[..., None]).sum(axis=1).astype(x.dtype).reshape(shape)
+
+    ok = here if valid is None else here & valid.reshape(n, 1)
+    hist = jnp.zeros((held + 1,), jnp.int32).at[
+        jnp.where(ok, local, held).reshape(-1)].add(1)[:held]
+    return out, hist

@@ -37,7 +37,8 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
 from repro.obs.recorder import NULL_RECORDER, FlightRecorder
 from repro.obs.timeline import RequestTimeline, aggregate, percentile
 from repro.obs.trace import (NULL_TRACER, NullTracer, Tracer, format_table,
-                             load_trace, phase_summary, profiled_spans)
+                             keep_counts, load_trace, phase_summary,
+                             profiled_counts, profiled_spans, profiling)
 
 
 class Telemetry:
@@ -102,7 +103,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "DEFAULT_BUCKETS",
     "RequestTimeline", "aggregate", "percentile",
     "Tracer", "NullTracer", "NULL_TRACER", "load_trace", "phase_summary",
-    "format_table", "profiled_spans",
+    "format_table", "profiled_spans", "profiled_counts", "keep_counts",
+    "profiling",
     "FlightRecorder", "NULL_RECORDER",
     "AuditCfg", "DlzsAuditor", "score_histogram",
     "WatchdogReport", "conservation_error", "fold_snapshot",

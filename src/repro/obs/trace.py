@@ -6,7 +6,9 @@ profiler session is active the engine's spans land in its trace on the
 device events' clock, with telemetry on or off. Span args ride into the
 annotation only while a session is active; without one a span costs
 well under a microsecond. While a session is active the spans are also
-kept in memory, with their host times (``profiled_spans``).
+kept in memory, with their host times (``profiled_spans``), and so are the
+per-dispatch counts the engine keeps (``keep_counts``,
+``profiled_counts``).
 
 A ``Tracer`` (telemetry on) also collects structured span ("X" complete)
 and instant ("i") events in memory with microsecond timestamps relative
@@ -48,6 +50,28 @@ PROFILED: collections.deque = collections.deque(maxlen=1 << 17)
 def profiled_spans() -> list[tuple[str, float, float, int]]:
     """The spans kept in ``PROFILED``, oldest first."""
     return list(PROFILED)
+
+
+# Counts the program tallies per device dispatch while a profiler session
+# is active, as (profiler-style name, host time s, {count: value}) on the
+# same clock as ``PROFILED``, for readers that sum them over a window.
+PROFILED_COUNTS: collections.deque = collections.deque(maxlen=1 << 17)
+
+
+def profiling() -> bool:
+    """Is a JAX profiler session active?"""
+    return TraceAnnotation.is_enabled()
+
+
+def keep_counts(name: str, **values) -> None:
+    """Keep one dispatch's counts as ``engine.<name>`` while profiling."""
+    if TraceAnnotation.is_enabled():
+        PROFILED_COUNTS.append((PREFIX + name, time.perf_counter(), values))
+
+
+def profiled_counts() -> list[tuple[str, float, dict]]:
+    """The counts kept in ``PROFILED_COUNTS``, oldest first."""
+    return list(PROFILED_COUNTS)
 
 
 class _Annotation(TraceAnnotation):

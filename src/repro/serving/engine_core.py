@@ -47,7 +47,7 @@ import numpy as np
 
 from repro.kvcache import PoolExhausted, SwapArea, bucketing
 from repro.obs import (NULL_TELEMETRY, DlzsAuditor, fold_snapshot,
-                       fold_traffic, reconcile_refs)
+                       fold_traffic, keep_counts, profiling, reconcile_refs)
 from repro.serving import swap_policy
 from repro.serving.engine import Request
 from repro.serving.scheduler import (SLA_DEADLINES_MS, ExecFault,
@@ -601,6 +601,7 @@ class EngineCore:
                 self._note_fault([slot], err, "prefill")
                 raise ExecFault([slot], err, "prefill") from err
             self._compiled.add(kind)
+            self._note_moe("prefill")
             if self.backend.share and pf.toks is not None:
                 self.backend.register_prompt_pages(pf.toks, table,
                                                    fresh_globals,
@@ -763,6 +764,7 @@ class EngineCore:
                 self._note_fault(wave, err, "prefill")
                 raise ExecFault(wave, err, "prefill") from err
             self._compiled.add("wave")
+            self._note_moe("prefill")
 
         done: list[int] = []
         with self.tel.tracer.span("prefill.commit", slots=len(slots)):
@@ -864,6 +866,7 @@ class EngineCore:
                 self.backend.commit_tokens(nxt)
             with tr.span("decode.sync"):
                 nxt_host = np.asarray(nxt)
+            self._note_moe("decode")
         self._compiled.add("decode")
         sparsity = getattr(self.backend, "decode_sparsity", None)
         if self.tel.enabled and sparsity:
@@ -930,6 +933,33 @@ class EngineCore:
                     if tel_on:
                         self._stamp_done(req, "done")
         return finished
+
+    def _note_moe(self, phase: str) -> None:
+        """Fold the MoE routing counts of the dispatch that just ran
+        (``backend.moe_hist``, [MoE layers, held experts]) into
+        ``engine_moe_assignments_total`` (token-to-expert assignments
+        routed to the experts held here) and ``engine_moe_experts_hit_total``
+        ((layer, held expert) pairs given at least one token), and keep
+        them for a profiler session's readers (``obs.profiled_counts``).
+        The pull to the host (span ``moe.sync``) happens only while
+        telemetry or a profiler session is on."""
+        hist = getattr(self.backend, "moe_hist", None)
+        if hist is None or not (self.tel.enabled or profiling()):
+            return
+        self.backend.moe_hist = None
+        with self.tel.tracer.span("moe.sync"):
+            h = np.asarray(hist)
+        assigned, hit = int(h.sum()), int((h > 0).sum())
+        keep_counts("moe." + phase, assignments=assigned, experts_hit=hit)
+        if self.tel.enabled:
+            self.tel.metrics.counter(
+                "engine_moe_assignments_total",
+                "token-to-expert assignments routed to the experts held "
+                "here").inc(assigned, phase=phase)
+            self.tel.metrics.counter(
+                "engine_moe_experts_hit_total",
+                "(layer, held expert) pairs that received at least one "
+                "token").inc(hit, phase=phase)
 
     # -- executor protocol: lazy shed / preemption / swap -------------------
 

@@ -113,6 +113,10 @@ class PagedBackend:
                 f"kv_quant={scfg.kv_quant!r}: choose None or 'int8'")
         self.kv_quant = scfg.kv_quant == "int8"
         self.decode_sparsity = None  # telemetry dict, set per decode step
+        # MoE models: [MoE layers, held experts] assignment counts of the
+        # newest prefill or decode dispatch, left on the device
+        # (EngineCore._note_moe pulls them only when something reads them)
+        self.moe_hist = None
 
         # Prefix sharing is exact only if a full page never splits a STAR
         # prefill q-tile (tile selection mixes rows within a tile).
@@ -354,6 +358,7 @@ class PagedBackend:
         if start == 0:
             logits, cache_one = self._prefill(
                 self.params, batch, jnp.asarray([last_idx], jnp.int32))
+            self.moe_hist = cache_one.get("moe_hist")
         else:
             wp = bucketing.bucket_count(start_page,
                                         pow2=self.pcfg.bucket_pow2)
@@ -369,6 +374,7 @@ class PagedBackend:
             logits, cache_one = self._prefill_chunk(
                 self.params, batch, {"layers": self.cache["layers"]},
                 chunk_state)
+            self.moe_hist = cache_one.get("moe_hist")
         # chunk page j -> its fresh pool page; shared pages (content
         # identical by construction) and bucket padding -> scratch
         fresh_set = set(fresh_globals)
@@ -424,6 +430,7 @@ class PagedBackend:
         logits, cache_flat = self._prefill_chunk_batch(
             self.params, {"tokens": jnp.asarray(flat)[None, :]},
             {"layers": self.cache["layers"]}, pack_state)
+        self.moe_hist = cache_flat.get("moe_hist")
         self.cache["layers"] = self._scatter(
             self.cache["layers"], cache_flat["layers"],
             jnp.asarray(phys_sc))
@@ -540,8 +547,10 @@ class PagedBackend:
         ps = self._page_state(slots, tables, lengths)  # may raise NeedPages
         with self.tel.tracer.span("decode.dispatch"):
             self.cache["lengths"] = jnp.asarray(lengths, jnp.int32)
-            logits, self.cache = self._decode(self.params, self.last_token,
-                                              self.cache, ps)
+            logits, cache = self._decode(self.params, self.last_token,
+                                         self.cache, ps)
+            self.moe_hist = cache.pop("moe_hist", None)
+            self.cache = cache
         return logits
 
     def set_last_token(self, slot: int, tok: int) -> None:

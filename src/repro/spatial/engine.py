@@ -91,6 +91,10 @@ class SpatialBackend:
             raise ValueError(
                 "spatial engine serves dense-attention configs; sparsity "
                 "comes from per-shard DLZS hot-page retention at decode")
+        if model_cfg.has_moe or model_cfg.qk_norm:
+            raise ValueError(
+                "spatial engine serves dense-FFN models without QK-norm; "
+                "MoE and QK-norm models serve on the paged backend")
         self.cfg = model_cfg
         self.pcfg = pcfg
         self.topo = ShardTopology(pcfg.n_shards)

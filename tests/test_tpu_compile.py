@@ -87,3 +87,70 @@ def test_sufa_compiles(one_chip):
                                                  interpret=False),
              one_chip, ((bh, t, d), jnp.bfloat16), tiles, tiles,
              ((bh, n_qt, keep, blk, blk), jnp.bool_))
+
+
+# OLMoE's dropless expert layer at the chip cell's shapes: one decode step
+# of 24 lanes and one 384-token prefill wave over 16 held experts of the
+# published widths. Its grouped matmuls must lower to the ragged-dot
+# kernels whose op names the benchmark's expert roofline reads.
+@pytest.mark.parametrize("tokens", [24, 384], ids=["decode", "wave"])
+def test_moe_dropless_compiles_to_ragged_dot(one_chip, tokens):
+    from repro.models import moe
+    cfg = moe.MoECfg(n_experts=64, top_k=8, d_model=2048, d_ff=1024)
+    bf = jnp.bfloat16
+    compiled = jax.jit(
+        lambda x, wg, w1, w2, w3: moe.apply_dropless(
+            {"wg": wg, "w1": w1, "w2": w2, "w3": w3}, cfg, x, held=16)
+    ).lower(*[jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((tokens, 2048), bf), ((2048, 64), jnp.float32),
+        ((16, 2048, 1024), bf), ((16, 1024, 2048), bf),
+        ((16, 2048, 1024), bf))]).compile()
+    assert compiled.as_text().count("%ragged-dot-none") >= 3
+
+
+# OLMoE-1B-7B's whole decode step, as the paged engine compiles it, at the
+# chip cell's engine (24 lanes, 1,921 pages of 16, an 80-page window, 16
+# held experts): it fits one v5e's HBM. At the 32 lanes and 2,561 pages
+# first planned it does not, for the decode program's whole-pool
+# temporaries (ROADMAP 1.3), which is why the cell serves 24 lanes; once
+# that case compiles, the cell can go back to 32.
+@pytest.mark.parametrize("lanes,n_pages,fits", [
+    (24, 1921, True), (32, 2561, False)],
+    ids=["olmoe_1b_7b.decode_batch", "olmoe_1b_7b_32_lanes"])
+def test_olmoe_decode_step_fits_one_chip(one_chip, monkeypatch, lanes,
+                                         n_pages, fits):
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.kernels import paged as kpaged
+    from repro.models import lm
+    monkeypatch.setenv("REPRO_PAGED_BACKEND", "pallas")
+    monkeypatch.setattr(kpaged, "resolve_interpret", lambda i: False)
+    cfg = dataclasses.replace(get_config("olmoe_1b_7b"), star=None,
+                              experts_held=16)
+    page, window = 16, 80
+    sds = lambda t: jax.tree.map(                          # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = jax.eval_shape(lambda k: lm.init(k, cfg), jax.random.PRNGKey(0))
+    probe = jax.eval_shape(lambda p: lm.prefill(
+        p, cfg, {"tokens": jnp.zeros((1, page), jnp.int32)},
+        last_index=jnp.zeros((1,), jnp.int32)), params)[1]["layers"]
+    cache = {"layers": jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((a.shape[0], n_pages) + a.shape[2:],
+                                       a.dtype), probe),
+             "lengths": jax.ShapeDtypeStruct((lanes,), jnp.int32)}
+    state = {k: jax.ShapeDtypeStruct((lanes, window), jnp.int32)
+             for k in ("phys", "logical")}
+    state.update({k: jax.ShapeDtypeStruct((lanes,), jnp.int32)
+                  for k in ("write_page", "write_off")})
+    lowered = jax.jit(lambda p, t, c, s: lm.decode_step_paged(p, cfg, t, c, s),
+                      donate_argnums=(2,)).lower(
+        sds(params), sds(jax.ShapeDtypeStruct((lanes, 1), jnp.int32)),
+        sds(cache), sds(state))
+    if fits:
+        assert "%paged_decode" in lowered.compile().as_text()
+    else:
+        with pytest.raises(Exception, match="Ran out of memory in memory "
+                                            "space hbm"):
+            lowered.compile()
