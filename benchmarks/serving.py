@@ -8,29 +8,16 @@ Scenarios (CSV rows to stdout, optionally merged into a
   system prefix, dense slot engine vs paged engine at the SAME device
   allocation. Reports TTFT / tok/s / KV working-set bytes and asserts the
   paged/dense footprint ratio stays <= 0.60 with token parity.
-* ``mixed_ttft`` — the chunked-prefill acceptance: long prompts arrive
-  first, short ones behind them. The non-chunked engine prefills each long
-  prompt in one monolithic shot, so every short request's first token
-  hides behind it; the chunked engine slices prefill into page chunks that
-  interleave with decode. Reports p50 short-request TTFT for both and
-  asserts the chunked engine improves it.
 * ``overload`` — queued demand ~4x pool capacity. The scheduler must
   preempt (swap/page-in) rather than reject: asserts zero rejected
   requests, every request finishes, and preemption counters are reported.
-* ``batched_prefill`` — the dispatch-granularity study on the mixed
-  workload: monolithic vs per-sequence chunked vs BATCHED varlen chunked
-  prefill (one token-budget dispatch per tick,
-  ``SchedulerCfg.prefill_tokens``). Chunking buys short-request TTFT but
-  used to pay ~2x aggregate throughput in per-sequence dispatch
-  overhead; the batched path must close that gap to <= 1.3x of
-  monolithic while keeping the short-prompt TTFT win and one
-  prefill/decode compilation each.
-* ``engine_core`` — the unified-API no-regression scenario: the same
-  mixed workload driven ONLY through the ``repro.serving.api.LLM``
-  front door over the shared EngineCore executor. Asserts front-door
-  throughput stays within 5% of the directly-driven engine and that the
-  ``prefill_tokens="auto"`` EMA budget controller matches or beats the
-  fixed budget's short-request TTFT p50.
+* ``engine_core`` — the unified-API no-regression scenario: a mixed
+  workload (long prompts first, short ones behind them) driven through
+  the ``repro.serving.api.LLM`` front door over the shared EngineCore
+  executor. Asserts front-door throughput stays within 5% of the
+  directly-driven engine and that the ``prefill_tokens="auto"`` EMA
+  budget controller matches or beats the fixed budget's short-request
+  TTFT p50.
 * ``decode_sparse`` (also standalone via ``--decode-sparse``) — the
   decode-time DLZS sparsity sweep on a decode-heavy mixed-length
   workload: hot width vs greedy top-1 agreement vs decode tok/s against
@@ -43,7 +30,7 @@ Scenarios (CSV rows to stdout, optionally merged into a
   long-prompt sub-run pins a structurally nonzero measured skip
   fraction at the widest bounded width.
 * ``--spatial`` — the spatial-runtime acceptance (runs INSTEAD of the
-  three above): a batch of ultra-long prompts against the sequence-
+  scenarios above): a batch of ultra-long prompts against the sequence-
   sharded engine at 1/2/4 shards with a FIXED per-shard pool. At 1 shard
   the workload barely fits one sequence at a time and serves through
   preempt/swap churn; at 4 shards the striped context fits concurrently,
@@ -82,10 +69,8 @@ MAX_LEN = 128          # dense engine-wide cap; must cover the longest request
 GEN = 8
 TAILS = (0, 8, 24, 40, 64, 4, 16, 48, 32, 56)   # + 32-token system prefix
 
-# mixed_ttft workload: two LONG prompts first, six short ones behind them.
-# The long prompts are long enough (384/448 tokens -> a 512-wide monolithic
-# prefill) that one-shot prefill genuinely stalls the engine loop — the
-# regime chunked prefill exists for.
+# mixed workload: two LONG prompts (384/448 tokens) first, six short ones
+# behind them — the regime chunked prefill exists for.
 LONG_TAILS = (368, 432)
 SHORT_TAILS = (4, 8, 12, 6, 10, 14)
 MIXED_CHUNK_PAGES = 2          # 32-token chunks; shorts fit one chunk
@@ -149,11 +134,10 @@ def _footprint(cfg, params, results):
     # Pool sized to the workload: 32 pages x 16 rows = 512 KV rows, the
     # same device allocation as the dense 4 x 128 slot slab — so the
     # working-set ratio below compares equal-allocation engines, not a
-    # hypothetical. chunk_pages=None: the monolithic baseline.
+    # hypothetical.
     paged = PagedServingEngine(cfg, params, PagedEngineCfg(
         max_batch=4, page_size=16, n_pages=32,
-        hot_pages=MAX_LEN // 16, recent_pages=2, eos_id=-1),
-        SchedulerCfg(chunk_pages=None))
+        hot_pages=MAX_LEN // 16, recent_pages=2, eos_id=-1))
     p_done, p_wall, p_tok, p_ttft = _drive(paged, _requests(cfg))
     st = paged.stats()
     # +1: the scratch page is part of the paged working set
@@ -181,77 +165,6 @@ def _footprint(cfg, params, results):
     }
 
 
-def _paged_mixed_engine(cfg, params, chunk_pages):
-    # pool holds the whole workload (no preemption noise here) and
-    # hot_pages covers the longest request, so both engines are exact and
-    # the only variable is HOW prefill is scheduled. Prefix sharing is off
-    # so the warmup pass cannot seed the measured pass with free pages.
-    return PagedServingEngine(cfg, params, PagedEngineCfg(
-        max_batch=4, page_size=16, n_pages=80,
-        hot_pages=32, recent_pages=2, eos_id=-1, share_prefixes=False),
-        SchedulerCfg(chunk_pages=chunk_pages))
-
-
-def _mixed_ttft(cfg, params, results):
-    short_rids = {len(LONG_TAILS) + j for j in range(len(SHORT_TAILS))}
-    variants = (("monolithic", None), ("chunked", MIXED_CHUNK_PAGES))
-    engines = {}
-    for name, chunk_pages in variants:
-        eng = _paged_mixed_engine(cfg, params, chunk_pages)
-        # warmup the SAME engine (jit caches are per instance) on
-        # shape-identical, content-different traffic: compiles everything,
-        # shares nothing with the measured pass
-        _drive(eng, _mixed_requests(cfg, seed=7))
-        engines[name] = eng
-
-    # p50 over six short requests is a small sample on a shared CPU host;
-    # a single OS stall can flip the comparison, so re-measure (engines
-    # stay warm) before declaring the structural claim false
-    for attempt in range(3):
-        out = {}
-        outputs = {}
-        for name, chunk_pages in variants:
-            done, wall, n_tok, ttft = _drive(engines[name],
-                                             _mixed_requests(cfg))
-            p50 = 1e3 * obs.percentile([ttft[r] for r in short_rids], 50)
-            p50_long = 1e3 * obs.percentile(
-                [ttft[r] for r in range(len(LONG_TAILS))], 50)
-            out[name] = {"tok_s": round(n_tok / wall, 1),
-                         "ttft_p50_short_ms": round(p50, 1),
-                         "ttft_p50_long_ms": round(p50_long, 1),
-                         "us_per_tok": wall * 1e6 / max(n_tok, 1),
-                         "chunk_pages": chunk_pages}
-            outputs[name] = done
-        if out["chunked"]["ttft_p50_short_ms"] \
-                < out["monolithic"]["ttft_p50_short_ms"]:
-            break
-    for name, _ in variants:
-        m = out[name]                  # keep every key: the dict is also
-        emit(f"serving_mixed_{name}",  # the stored trajectory entry
-             m["us_per_tok"],
-             f"tok_s={m['tok_s']};"
-             f"ttft_p50_short_ms={m['ttft_p50_short_ms']};"
-             f"ttft_p50_long_ms={m['ttft_p50_long_ms']};"
-             f"chunk_pages={m['chunk_pages']}")
-    # Exactness scope: short requests must match token-for-token (their
-    # prefill takes the identical single-chunk path). Long prompts may
-    # drift a late greedy argmax — the chunk path's gather+concat softmax
-    # reduces in a different order, a 1-ulp bf16 effect the parity tests
-    # bound at moderate lengths — but their FIRST token must agree.
-    for rid in short_rids:
-        assert outputs["chunked"][rid] == outputs["monolithic"][rid], \
-            f"short request {rid} diverged under chunked prefill"
-    for rid in range(len(LONG_TAILS)):
-        assert outputs["chunked"][rid][0] == outputs["monolithic"][rid][0], \
-            f"long request {rid} first token diverged"
-    assert out["chunked"]["ttft_p50_short_ms"] \
-        < out["monolithic"]["ttft_p50_short_ms"], (
-        "chunked prefill did not improve short-prompt TTFT: "
-        f"{out['chunked']['ttft_p50_short_ms']} vs "
-        f"{out['monolithic']['ttft_p50_short_ms']} ms")
-    results["mixed_ttft"] = out
-
-
 BATCH_PREFILL_TOKENS = 192     # 6 x 2-page (32-token) chunks per tick
 
 
@@ -264,100 +177,6 @@ def _batched_engine_cfg():
         max_batch=8, page_size=16, n_pages=96, hot_pages=32,
         recent_pages=2, eos_id=-1, share_prefixes=False,
         batch_past_pages=32)
-
-
-def batched_prefill(cfg, params) -> dict:
-    """Monolithic vs per-sequence chunked vs batched varlen chunked
-    prefill on the mixed long/short workload. Shared with
-    tools/smoke_serve.py, which refreshes the ``batched_prefill`` entry
-    of BENCH_serving.json each CI run and asserts batched chunked
-    throughput never falls below the per-sequence chunked path.
-
-    All three engines run at max_batch=8 so the whole workload is
-    concurrently resident — the continuous-batching regime the batched
-    path exists for. The per-sequence chunked engine can only advance
-    ONE sequence's chunk per dispatch regardless; the batched engine
-    packs every prefilling sequence's next chunk(s) under the token
-    budget into one varlen dispatch per tick."""
-    short_rids = {len(LONG_TAILS) + j for j in range(len(SHORT_TAILS))}
-    variants = (("monolithic", None, None),
-                ("sequential", MIXED_CHUNK_PAGES, None),
-                ("batched", MIXED_CHUNK_PAGES, BATCH_PREFILL_TOKENS))
-    engines = {}
-    for name, chunk_pages, prefill_tokens in variants:
-        eng = PagedServingEngine(cfg, params, _batched_engine_cfg(),
-                                 SchedulerCfg(
-                                     chunk_pages=chunk_pages,
-                                     prefill_tokens=prefill_tokens))
-        _drive(eng, _mixed_requests(cfg, seed=7))        # warmup pass
-        engines[name] = eng
-
-    # timing comparisons on a shared CPU host are noisy at this scale —
-    # re-measure (engines stay warm) before declaring a structural miss
-    for attempt in range(3):
-        out, outputs = {}, {}
-        for name, chunk_pages, prefill_tokens in variants:
-            done, wall, n_tok, ttft = _drive(engines[name],
-                                             _mixed_requests(cfg))
-            p50 = 1e3 * obs.percentile([ttft[r] for r in short_rids], 50)
-            p50_long = 1e3 * obs.percentile(
-                [ttft[r] for r in range(len(LONG_TAILS))], 50)
-            out[name] = {"tok_s": round(n_tok / wall, 1),
-                         "ttft_p50_short_ms": round(p50, 1),
-                         "ttft_p50_long_ms": round(p50_long, 1),
-                         "us_per_tok": wall * 1e6 / max(n_tok, 1),
-                         "chunk_pages": chunk_pages,
-                         "prefill_tokens": prefill_tokens}
-            outputs[name] = done
-        if (out["batched"]["tok_s"] * 1.3 >= out["monolithic"]["tok_s"]
-                and out["batched"]["ttft_p50_short_ms"]
-                < out["monolithic"]["ttft_p50_short_ms"]):
-            break
-
-    # exactness scope mirrors mixed_ttft: short requests token-exact,
-    # long prompts first-token exact (late greedy flips are a 1-ulp bf16
-    # reduction-order effect the parity tests bound at moderate lengths)
-    for rid in short_rids:
-        assert outputs["batched"][rid] == outputs["monolithic"][rid], \
-            f"short request {rid} diverged under batched chunk prefill"
-        assert outputs["batched"][rid] == outputs["sequential"][rid], \
-            f"short request {rid}: batched != per-sequence chunked"
-    for rid in range(len(LONG_TAILS)):
-        assert outputs["batched"][rid][0] == outputs["monolithic"][rid][0], \
-            f"long request {rid} first token diverged"
-
-    st = engines["batched"].stats()
-    assert st["prefill_batch_compiles"] == 1, st["prefill_batch_compiles"]
-    assert st["decode_compiles"] == 1, st["decode_compiles"]
-    gap = out["monolithic"]["tok_s"] / out["batched"]["tok_s"]
-    seq_gap = out["monolithic"]["tok_s"] / out["sequential"]["tok_s"]
-    assert gap <= 1.3, (
-        f"batched chunked prefill still {gap:.2f}x off monolithic "
-        f"throughput (budget {BATCH_PREFILL_TOKENS} tokens)")
-    assert out["batched"]["ttft_p50_short_ms"] \
-        < out["monolithic"]["ttft_p50_short_ms"], (
-        "batching chunks lost the short-prompt TTFT win: "
-        f"{out['batched']['ttft_p50_short_ms']} vs monolithic "
-        f"{out['monolithic']['ttft_p50_short_ms']} ms")
-    out["batched_vs_monolithic_gap"] = round(gap, 2)
-    out["sequential_vs_monolithic_gap"] = round(seq_gap, 2)
-    return out
-
-
-def _batched_prefill(cfg, params, results):
-    m = batched_prefill(cfg, params)
-    for name in ("monolithic", "sequential", "batched"):
-        v = m[name]
-        emit(f"serving_batchpf_{name}", v["us_per_tok"],
-             f"tok_s={v['tok_s']};"
-             f"ttft_p50_short_ms={v['ttft_p50_short_ms']};"
-             f"ttft_p50_long_ms={v['ttft_p50_long_ms']};"
-             f"chunk_pages={v['chunk_pages']};"
-             f"prefill_tokens={v['prefill_tokens']}")
-    emit("serving_batchpf_gap", 0.0,
-         f"batched_vs_monolithic={m['batched_vs_monolithic_gap']};"
-         f"sequential_vs_monolithic={m['sequential_vs_monolithic_gap']}")
-    results["batched_prefill"] = m
 
 
 def _drive_llm(llm, reqs):
@@ -373,19 +192,25 @@ def _drive_llm(llm, reqs):
     return done, wall, n_tok, ttft
 
 
-def engine_core(cfg, params, baseline: dict | None = None) -> dict:
-    """Refactor no-regression scenario: the ``batched_prefill`` mixed
-    workload driven ONLY through the unified ``LLM`` front door over the
-    shared EngineCore executor.
+def engine_core(cfg, params) -> dict:
+    """Front-door no-regression scenario: the mixed long/short workload
+    driven through the unified ``LLM`` front door over the shared
+    EngineCore executor.
 
-    Asserts (a) front-door batched-prefill + decode throughput stays
-    within 5% of the directly-driven engine measured in the same run
-    (``baseline`` = the just-refreshed ``batched_prefill`` entry), and
+    Asserts (a) front-door prefill + decode throughput stays within 5%
+    of the same engine driven directly, measured in the same run, and
     (b) the ``prefill_tokens="auto"`` EMA budget controller matches or
     beats the fixed-budget short-request TTFT p50. Shared with
     tools/smoke_serve.py, which refreshes the ``engine_core`` entry of
     BENCH_serving.json each CI run."""
     short_rids = {len(LONG_TAILS) + j for j in range(len(SHORT_TAILS))}
+    direct = PagedServingEngine(cfg, params, _batched_engine_cfg(),
+                                SchedulerCfg(
+                                    chunk_pages=MIXED_CHUNK_PAGES,
+                                    prefill_tokens=BATCH_PREFILL_TOKENS))
+    _drive(direct, _mixed_requests(cfg, seed=7))         # warmup pass
+    _, wall, n_tok, _ = _drive(direct, _mixed_requests(cfg))
+    base_tok_s = round(n_tok / wall, 1)
     llms = {}
     for name, prefill_tokens in (("fixed", BATCH_PREFILL_TOKENS),
                                  ("auto", "auto")):
@@ -396,7 +221,6 @@ def engine_core(cfg, params, baseline: dict | None = None) -> dict:
         _drive_llm(llm, _mixed_requests(cfg, seed=7))    # warmup pass
         llms[name] = llm
 
-    base_tok_s = baseline["batched"]["tok_s"] if baseline else None
     # shared-CPU timing noise: both variants run identical compute here
     # (the controller converges to the same page-quantized budget on an
     # unloaded host), so single-shot medians of 6 short TTFTs can flip
@@ -420,8 +244,7 @@ def engine_core(cfg, params, baseline: dict | None = None) -> dict:
                 out[name]["ttft_p50_short_ms"] = min(
                     out[name]["ttft_p50_short_ms"],
                     m["ttft_p50_short_ms"])
-        ok_tok = (base_tok_s is None
-                  or out["fixed"]["tok_s"] >= 0.95 * base_tok_s)
+        ok_tok = out["fixed"]["tok_s"] >= 0.95 * base_tok_s
         ok_auto = out["auto"]["ttft_p50_short_ms"] \
             <= 1.05 * out["fixed"]["ttft_p50_short_ms"]
         if ok_tok and ok_auto:
@@ -431,12 +254,10 @@ def engine_core(cfg, params, baseline: dict | None = None) -> dict:
         st = llm.stats()
         assert st["prefill_batch_compiles"] == 1, (name, st)
         assert st["decode_compiles"] == 1, (name, st)
-    if base_tok_s is not None:
-        assert out["fixed"]["tok_s"] >= 0.95 * base_tok_s, (
-            f"LLM front door lost throughput: {out['fixed']['tok_s']} "
-            f"vs direct-engine baseline {base_tok_s} tok/s")
-        out["vs_batched_gap"] = round(base_tok_s
-                                      / out["fixed"]["tok_s"], 3)
+    assert out["fixed"]["tok_s"] >= 0.95 * base_tok_s, (
+        f"LLM front door lost throughput: {out['fixed']['tok_s']} "
+        f"vs direct-engine baseline {base_tok_s} tok/s")
+    out["vs_direct_gap"] = round(base_tok_s / out["fixed"]["tok_s"], 3)
     assert out["auto"]["ttft_p50_short_ms"] \
         <= 1.05 * out["fixed"]["ttft_p50_short_ms"], (
         "auto prefill budget lost short-TTFT vs the fixed budget: "
@@ -448,7 +269,7 @@ def engine_core(cfg, params, baseline: dict | None = None) -> dict:
 
 
 def _engine_core(cfg, params, results):
-    m = engine_core(cfg, params, results.get("batched_prefill"))
+    m = engine_core(cfg, params)
     for name in ("fixed", "auto"):
         emit(f"serving_enginecore_{name}", 0.0,
              f"tok_s={m[name]['tok_s']};"
@@ -1183,8 +1004,6 @@ def run(json_path: str | None = None) -> dict:
     params = lm.init(jax.random.PRNGKey(0), cfg)
     results: dict = {}
     _footprint(cfg, params, results)
-    _mixed_ttft(cfg, params, results)
-    _batched_prefill(cfg, params, results)
     _engine_core(cfg, params, results)
     _overload(cfg, params, results)
     _overload_deadlines(cfg, params, results)

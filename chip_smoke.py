@@ -160,7 +160,7 @@ def serve_check(cfg, seed: int, meter: CompileMeter, *, lens=PROMPT_LENS,
         engine_cfg=PagedEngineCfg(max_batch=len(lens), page_size=PAGE,
                                   n_pages=n_pages, hot_pages=16, eos_id=-1,
                                   batch_past_pages=max_pages),
-        sched_cfg=SchedulerCfg(chunk_pages=chunk, prefill_tokens="auto"))
+        sched_cfg=SchedulerCfg(chunk_pages=chunk))
     rng = np.random.default_rng(seed)
     c0 = meter.snapshot()
     cold, cold_s = serve_burst(llm, cfg.vocab, lens, new_tokens, rng)
@@ -228,14 +228,14 @@ def spatial_check(cfg, seed: int, shards: int, *,
             n_shards=shards, max_batch=len(lens), page_size=PAGE,
             n_pages_local=2 * local * len(lens), hot_pages_local=local,
             eos_id=-1, batch_past_pages=local),
-        sched_cfg=SchedulerCfg(chunk_pages=4, prefill_tokens="auto"))
+        sched_cfg=SchedulerCfg(chunk_pages=4))
     paged = LLM.from_config(
         cfg, backend="paged", params=params,
         engine_cfg=PagedEngineCfg(
             max_batch=len(lens), page_size=PAGE,
             n_pages=2 * max_pages * len(lens), hot_pages=max_pages,
             eos_id=-1, batch_past_pages=max_pages),
-        sched_cfg=SchedulerCfg(chunk_pages=4, prefill_tokens="auto"))
+        sched_cfg=SchedulerCfg(chunk_pages=4))
 
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(0, cfg.vocab, size=n, dtype=np.int32)

@@ -30,7 +30,7 @@ def main():
             max_batch=4, page_size=cfg.star.block_q, n_pages=32,
             hot_pages=4, recent_pages=2, eos_id=-1),
         # chunk boundaries must stay STAR q-tile aligned
-        sched_cfg=SchedulerCfg(chunk_pages=1, prefill_tokens="auto"))
+        sched_cfg=SchedulerCfg(chunk_pages=1))
 
     rng = np.random.default_rng(0)
     system = rng.integers(0, cfg.vocab, size=cfg.star.block_q,

@@ -58,7 +58,7 @@ def main():
         engine_cfg=SpatialEngineCfg(
             n_shards=N_SHARDS, max_batch=4, page_size=16,
             n_pages_local=pages_local, hot_pages_local=10, eos_id=-1),
-        sched_cfg=SchedulerCfg(chunk_pages=2))
+        sched_cfg=SchedulerCfg(chunk_pages=2, prefill_tokens=32))
     handles = [llm.submit(**r) for r in mix]
     done = llm.run_until_done()
     rep = llm.metrics()

@@ -26,9 +26,10 @@ DLZS prediction stage (§IV-A) decide which pages stay hot:
                    blocks of each lane's valid pages (kernels/paged.py);
                    backend auto-dispatch picks pallas on
                    TPU, xla elsewhere (``REPRO_PAGED_BACKEND`` overrides).
-* ``bucketing``  — prompt-length buckets (O(log max_len) prefill
-                   compilations, not one per length) and the page-aligned
-                   chunk math (``chunk_spans``) behind chunked prefill.
+* ``bucketing``  — page buckets, the page-aligned chunk math
+                   (``chunk_spans``) and the per-tick packing
+                   (``pack_budget``) behind the one-compile chunked
+                   prefill.
 * ``metrics``    — device-side page scoring + cache-bytes accounting.
 * ``quant``      — int8 cold-page KV tier: per-page-scaled quantized
                    mirrors of the pool slabs; pages leaving the DLZS hot

@@ -1,30 +1,24 @@
-"""Prompt-length bucketing + chunk math for recompile-free admission.
+"""Page bucketing + chunk math for recompile-free admission.
 
-Prefill compiles per input shape. Admitting raw prompt lengths would compile
-once per distinct length; padding every prompt to one engine-wide maximum
-wastes prefill FLOPs quadratically. The middle ground: round the prompt up
-to a whole number of KV pages, then (optionally) to a power-of-two page
-count, so the number of distinct prefill shapes is O(log max_len) and every
+Prefill compiles per input shape, so every shape the engine dispatches is
+fixed at construction: the batched varlen chunk prefill packs each tick's
+chunks into one flat buffer of ``budget_tokens`` width against a fixed
+past-page arena, and compiles exactly once. Widths inside it round up to
+whole KV pages (then, optionally, to a power-of-two page count), so every
 K/V row that matters lands page-aligned for the pool scatter.
 
 Padding is safe for causal models: K/V rows at positions < T depend only on
 tokens <= their position, so the junk tail changes nothing that is kept.
-(For tile-granular STAR prefill the selection of a boundary q-tile can see
-junk rows — a selection-noise effect the engine documents; exactness holds
-whenever T is already bucket-aligned.)
 
 Chunked prefill (``chunk_spans``) slices a prompt into page-aligned chunks
 of at most ``chunk_pages`` pages so long prompts prefill incrementally,
 interleaved with decode steps. Every non-final chunk is exactly
-``chunk_pages`` pages wide (one compiled shape); the final remainder is
-bucketed like a monolithic prompt, so the set of compiled chunk widths
-stays O(log chunk_pages) and the set of past-page gather widths
-(``bucket_count``) stays O(log max_pages).
+``chunk_pages`` pages wide; the final remainder is bucketed, so it never
+exceeds the widest single chunk ``budget_tokens`` reserves room for.
+``pack_budget`` decides how many chunks of which prompts fill one tick.
 """
 
 from __future__ import annotations
-
-from typing import Optional
 
 import numpy as np
 
@@ -122,25 +116,20 @@ def pack_budget(widths: list, budget: int) -> list[tuple]:
     return [(key, counts[key]) for key, _ in packed]
 
 
-def chunk_spans(n_tokens: int, page_size: int,
-                chunk_pages: Optional[int], *, pow2: bool = True
-                ) -> list[tuple[int, int, int]]:
+def chunk_spans(n_tokens: int, page_size: int, chunk_pages: int, *,
+                pow2: bool = True) -> list[tuple[int, int, int]]:
     """Split a prompt into page-aligned prefill chunks.
 
     Returns ``[(start, end, width), ...]`` in token units: the chunk covers
     prompt tokens ``[start, end)`` and is computed at padded width
-    ``width`` (a whole number of pages). ``chunk_pages=None`` disables
-    chunking — one span covering the whole prompt at its bucketed width,
-    which is exactly the monolithic prefill the engine always did.
-    Every ``start`` is a page multiple, so chunk K/V rows scatter onto
-    whole pool pages.
+    ``width`` (a whole number of pages). Every ``start`` is a page
+    multiple, so chunk K/V rows scatter onto whole pool pages.
     """
     if n_tokens <= 0:
         raise ValueError(f"empty prompt (n_tokens={n_tokens})")
-    if chunk_pages is not None and chunk_pages < 1:
-        raise ValueError(f"chunk_pages must be >= 1 or None, "
-                         f"got {chunk_pages}")
-    if chunk_pages is None or n_tokens <= chunk_pages * page_size:
+    if chunk_pages < 1:
+        raise ValueError(f"chunk_pages must be >= 1, got {chunk_pages}")
+    if n_tokens <= chunk_pages * page_size:
         return [(0, n_tokens, bucket_len(n_tokens, page_size, pow2=pow2))]
     c_tok = chunk_pages * page_size
     spans = []

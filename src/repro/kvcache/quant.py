@@ -85,8 +85,8 @@ def add_quant_slabs(layers):
 def split_quant(layers):
     """(base, quant) with identical nesting: ``base`` holds the fp leaves,
     ``quant`` only the tier leaves. Lets two-tree kernels written against
-    the fp structure (e.g. the prefill scatter, whose per-sequence cache
-    has no quant leaves) run untouched, with the tier merged back after."""
+    the fp structure (e.g. the prefill scatter, whose flat wave cache has
+    no quant leaves) run untouched, with the tier merged back after."""
     def walk(d):
         if _is_attn(d):
             return ({k: v for k, v in d.items() if k not in QUANT_KEYS},

@@ -165,8 +165,7 @@ def main(argv=None):
                 admission = AdmissionCfg(
                     high_watermark=high, low_watermark=low,
                     shed_below_priority=args.shed_below_priority)
-            sched_cfg = SchedulerCfg(prefill_tokens="auto",
-                                     sla_deadlines=args.sla_deadlines,
+            sched_cfg = SchedulerCfg(sla_deadlines=args.sla_deadlines,
                                      admission=admission)
     tel = None if args.no_telemetry else obs.Telemetry(
         {"launcher": "repro.launch.serve", "engine": args.engine,

@@ -280,102 +280,6 @@ def apply_decode(params, cfg: AttentionCfg, x, cache, lengths):
     return shd(y, "batch", "seq", "embed"), new_cache
 
 
-def apply_prefill_chunk(params, cfg: AttentionCfg, x, positions, cache,
-                        past_phys, past_logical, past_len):
-    """Prefill one page-aligned chunk from a nonzero cache offset.
-
-    x [B,C,H] — the chunk's hidden states; positions [B,C] — ABSOLUTE token
-    positions (RoPE is position-exact, so past K rows already in the pool
-    match); cache k/v [P,page,nkv,dh] — this layer's pool slabs, read-only
-    here; past_phys/past_logical [B,Wp] — block-table rows of every page
-    written by earlier chunks (-1 = pad); past_len [B] — tokens already in
-    the cache.
-
-    Attention is exact: each chunk query attends to all past rows plus the
-    causal prefix of its own chunk (no STAR tile selection — chunked
-    prefill trades first-chunk sparsity for admission latency; see
-    docs/serving.md). Returns (y, chunk_cache) where chunk_cache holds the
-    chunk's K/V (+ int8 LZ codes) in prefill layout [B,C,nkv,dh] — the
-    caller scatters it into pool pages.
-    """
-    b, c, _ = x.shape
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    q, k, v = _project_qkv(params, cfg, x, positions)
-    page = cache["k"].shape[1]
-
-    safe = jnp.maximum(past_phys, 0)
-    kg = jnp.take(cache["k"], safe, axis=0)        # [B, Wp, page, nkv, d]
-    vg = jnp.take(cache["v"], safe, axis=0)
-    wp = past_phys.shape[1]
-    sp = wp * page
-    kg = kg.reshape(b, sp, cfg.n_kv, cfg.head_dim).astype(q.dtype)
-    vg = vg.reshape(b, sp, cfg.n_kv, cfg.head_dim).astype(q.dtype)
-
-    past_pos = (past_logical[:, :, None] * page
-                + jnp.arange(page)[None, None, :]).reshape(b, sp)
-    past_ok = (past_logical[:, :, None] >= 0).repeat(page, axis=2)
-    past_ok = past_ok.reshape(b, sp) & (past_pos < past_len[:, None])
-
-    k_all = jnp.concatenate([kg, k], axis=1)        # [B, Sp+C, nkv, d]
-    v_all = jnp.concatenate([vg, v], axis=1)
-    kv_pos = jnp.concatenate([past_pos, positions], axis=1)
-    kv_ok = jnp.concatenate(
-        [past_ok, jnp.ones((b, c), bool)], axis=1)
-
-    # Grouped-GQA masked softmax in one tile: C is a handful of pages, so
-    # the [B,g,r,C,Sp+C] score block stays small; junk rows (chunk padding,
-    # page tails past past_len) are masked and can only feed junk queries.
-    n_rep = cfg.n_heads // cfg.n_kv
-    qg = q.reshape(b, c, cfg.n_kv, n_rep, cfg.head_dim)
-    sc = jnp.einsum("btgrd,bsgd->bgrts", qg, k_all).astype(jnp.float32)
-    sc = sc * scale
-    mask = kv_ok[:, None, None, None, :] & \
-        (kv_pos[:, None, None, None, :] <= positions[:, None, None, :, None])
-
-    if cfg.star is not None and cfg.chunk_sparse and wp > 0:
-        # STAR inside later chunks: DLZS-predict the chunk's scores against
-        # the gathered PAST pages (streaming the int8 LZ slab when present)
-        # and drop whole pages outside the SADS sphere — a page whose best
-        # predicted score sits more than ``radius`` below the per-sequence
-        # max contributes < e^-radius relative softmax mass. The chunk's
-        # own causal block always stays dense, so the approximation touches
-        # only the long-context tail.
-        if "k_lz" in cache:
-            khat = dlzs.lz_unpack(jnp.take(cache["k_lz"], safe, axis=0),
-                                  q.dtype)
-            khat = khat.reshape(b, sp, cfg.n_kv, cfg.head_dim)
-        else:
-            khat = dlzs.pow2_quantize(kg)
-        s_hat = jnp.einsum("btgrd,bsgd->bgrts", qg, khat
-                           ).astype(jnp.float32) * scale
-        s_hat = jnp.where(mask[..., :sp], s_hat, NEG_INF)
-        page_max = s_hat.reshape(b, cfg.n_kv, n_rep, c, wp, page
-                                 ).max(axis=(1, 2, 3, 5))        # [B, Wp]
-        row_max = page_max.max(axis=-1, keepdims=True)
-        keep = page_max >= row_max - cfg.star.radius             # sphere
-        keep_rows = keep[:, :, None].repeat(page, axis=2).reshape(b, sp)
-        keep_all = jnp.concatenate(
-            [keep_rows, jnp.ones((b, c), bool)], axis=1)
-        mask = mask & keep_all[:, None, None, None, :]
-
-    sc = jnp.where(mask, sc, NEG_INF)
-    m = sc.max(axis=-1, keepdims=True)
-    p = jnp.exp(sc - m)
-    p = jnp.where(sc <= NEG_INF / 2, 0.0, p)
-    l = jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
-    o = jnp.einsum("bgrts,bsgd->btgrd", (p / l).astype(q.dtype), v_all)
-    y = o.reshape(b, c, cfg.n_heads, cfg.head_dim)
-    out = jnp.einsum("bsnd,ndh->bsh", y, params["wo"])
-    out = shd(out, "batch", "act_seq", "embed")
-
-    chunk_cache = {"k": shd(k, "batch", "kv_seq", "kv_heads", "head_dim"),
-                   "v": shd(v, "batch", "kv_seq", "kv_heads", "head_dim")}
-    if cfg.lz_cache:
-        chunk_cache["k_lz"] = shd(dlzs.lz_pack(k),
-                                  "batch", "kv_seq", "kv_heads", "head_dim")
-    return out, chunk_cache
-
-
 def _batch_past_rows(cfg: AttentionCfg, cache, past_phys, past_lane,
                      past_logical, past_len, dtype):
     """Flatten the shared past-page ARENA into one row buffer.
@@ -421,10 +325,10 @@ def apply_prefill_chunk_batch(params, cfg: AttentionCfg, x, positions,
 
     The mask composes three terms: lane match (a query only sees rows of
     its own sequence), validity (padding rows/tokens see nothing), and
-    causality over absolute positions. Per-lane math is identical to
-    ``apply_prefill_chunk`` — the batched form just runs every lane's
-    gather+softmax inside one compiled program, which is what removes
-    the per-sequence dispatch overhead chunked prefill used to pay.
+    causality over absolute positions: each chunk query attends to all
+    of its lane's past rows plus the causal prefix of its own chunk,
+    exactly (no STAR tile selection unless ``chunk_sparse``). Every
+    lane's gather+softmax runs inside one compiled program.
     Returns (y [1, B_tok, H], chunk_cache [1, B_tok, nkv, dh] + LZ) —
     the caller scatters the flat rows onto each lane's pool pages.
     """
@@ -459,11 +363,17 @@ def apply_prefill_chunk_batch(params, cfg: AttentionCfg, x, positions,
            <= positions[:, None, None, :, None])
 
     if cfg.star is not None and cfg.chunk_sparse and wp > 0:
-        # Same DLZS sphere as apply_prefill_chunk, per lane: predicted
-        # scores of OTHER lanes' queries against an arena slot are
-        # already NEG_INF under the lane mask, so the per-slot max over
-        # all flat queries is exactly the owner lane's max; the sphere
-        # radius is then applied against a segmented per-lane row max.
+        # STAR inside chunked prefill: DLZS-predict each lane's chunk
+        # scores against its gathered PAST pages (streaming the int8 LZ
+        # slab when present) and drop whole pages outside the SADS
+        # sphere — a page whose best predicted score sits more than
+        # ``radius`` below its lane's max contributes < e^-radius
+        # relative softmax mass. The chunks' own causal blocks stay
+        # dense, so the approximation touches only the long-context
+        # tail. Predicted scores of OTHER lanes' queries against an
+        # arena slot are already NEG_INF under the lane mask, so the
+        # per-slot max over all flat queries is exactly the owner lane's
+        # max; the radius is applied against a segmented per-lane max.
         if "k_lz" in cache:
             khat = dlzs.lz_unpack(
                 jnp.take(cache["k_lz"], jnp.maximum(past_phys, 0),
@@ -674,84 +584,6 @@ def apply_decode_spatial(params, cfg: AttentionCfg, x, cache, lengths,
                    o.reshape(b, cfg.n_heads, cfg.head_dim).astype(x.dtype),
                    params["wo"])[:, None, :]
     return shd(y, "batch", "seq", "embed"), new_cache
-
-
-def apply_prefill_chunk_spatial(params, cfg: AttentionCfg, x, positions,
-                                cache, page_state, axis: str):
-    """Prefill one page-aligned chunk of a sequence-sharded prompt (one
-    shard's view; call inside shard_map over mesh axis ``axis``).
-
-    The chunk's hidden states are replicated; each shard computes a
-    partial (m, l, o) of the chunk queries against ITS local past pages,
-    the partials merge across the axis (pmax/psum — the T>1 form of the
-    decode merge), and the chunk's causal self-attention block is added
-    locally (identical on every shard, merged exactly once). The chunk's
-    fresh K/V rows scatter into the pages this shard owns
-    (``page_state["chunk_phys"]`` — SCRATCH for pages owned elsewhere), so
-    the whole chunk update stays inside one SPMD dispatch.
-    """
-    b, c, _ = x.shape
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    q, k, v = _project_qkv(params, cfg, x, positions)
-    page = cache["k"].shape[1]
-    n_rep = cfg.n_heads // cfg.n_kv
-    qg = q.reshape(b, c, cfg.n_kv, n_rep, cfg.head_dim)
-
-    # partial stats vs this shard's past pages
-    past_phys, past_logical = page_state["past_phys"], \
-        page_state["past_logical"]
-    safe = jnp.maximum(past_phys, 0)
-    kg = jnp.take(cache["k"], safe, axis=0)        # [B, Wp, page, nkv, d]
-    vg = jnp.take(cache["v"], safe, axis=0)
-    wp = past_phys.shape[1]
-    sp = wp * page
-    kg = kg.reshape(b, sp, cfg.n_kv, cfg.head_dim).astype(q.dtype)
-    vg = vg.reshape(b, sp, cfg.n_kv, cfg.head_dim).astype(q.dtype)
-    past_pos = (past_logical[:, :, None] * page
-                + jnp.arange(page)[None, None, :]).reshape(b, sp)
-    past_ok = (past_logical[:, :, None] >= 0).repeat(page, axis=2)
-    past_ok = past_ok.reshape(b, sp) \
-        & (past_pos < page_state["past_len"][:, None])
-    sc_p = jnp.einsum("btgrd,bsgd->bgrts", qg, kg).astype(jnp.float32)
-    sc_p = sc_p * scale
-    mask_p = past_ok[:, None, None, None, :] & \
-        (past_pos[:, None, None, None, :]
-         <= positions[:, None, None, :, None])
-    sc_p = jnp.where(mask_p, sc_p, NEG_INF)
-    m1 = sc_p.max(axis=-1)                          # [B, G, R, C]
-    p1 = jnp.exp(sc_p - m1[..., None])
-    p1 = jnp.where(sc_p <= NEG_INF / 2, 0.0, p1)
-    l1 = p1.sum(axis=-1)
-    o1 = jnp.einsum("bgrts,bsgd->bgrtd", p1, vg.astype(jnp.float32))
-    m1, l1, o1 = _psum_merge_stats(m1, l1, o1, axis)
-
-    # chunk's causal self-attention block (replicated compute)
-    sc_c = jnp.einsum("btgrd,bsgd->bgrts", qg, k).astype(jnp.float32)
-    sc_c = sc_c * scale
-    mask_c = positions[:, None, None, None, :] \
-        <= positions[:, None, None, :, None]
-    sc_c = jnp.where(mask_c, sc_c, NEG_INF)
-    m2 = sc_c.max(axis=-1)
-    p2 = jnp.exp(sc_c - m2[..., None])
-    p2 = jnp.where(sc_c <= NEG_INF / 2, 0.0, p2)
-    l2 = p2.sum(axis=-1)
-    o2 = jnp.einsum("bgrts,bsgd->bgrtd", p2, v.astype(jnp.float32))
-
-    m, l, o = _merge_two_stats(m1, l1, o1, m2, l2, o2)
-    o = o / jnp.maximum(l, 1e-30)[..., None]        # [B, G, R, C, d]
-    y = jnp.moveaxis(o, 3, 1).reshape(b, c, cfg.n_heads, cfg.head_dim)
-    out = jnp.einsum("bsnd,ndh->bsh", y.astype(x.dtype), params["wo"])
-    out = shd(out, "batch", "act_seq", "embed")
-
-    # scatter the chunk's K/V rows into the pages this shard owns
-    chunk_phys = page_state["chunk_phys"]           # [B, C // page]
-    def put(pool, rows):
-        rows = rows.reshape(b, c // page, page, *rows.shape[2:])
-        return pool.at[chunk_phys].set(rows.astype(pool.dtype))
-    new_cache = dict(cache, k=put(cache["k"], k), v=put(cache["v"], v))
-    if cfg.lz_cache and "k_lz" in cache:
-        new_cache["k_lz"] = put(cache["k_lz"], dlzs.lz_pack(k))
-    return out, new_cache
 
 
 def apply_prefill_chunk_batch_spatial(params, cfg: AttentionCfg, x,

@@ -204,17 +204,6 @@ def _block_apply(params, cfg: ModelCfg, blk: BlockCfg, x, positions, *,
                 params["core"], acfg, h, positions, cache["attn"],
                 page_state)
             new_cache["attn"] = c
-        elif mode == "prefill_chunk" and spatial_axis is not None:
-            y, c = attention.apply_prefill_chunk_spatial(
-                params["core"], acfg, h, positions, cache["attn"],
-                page_state, spatial_axis)
-            new_cache["attn"] = c
-        elif mode == "prefill_chunk":
-            y, c = attention.apply_prefill_chunk(
-                params["core"], acfg, h, positions, cache["attn"],
-                page_state["past_phys"], page_state["past_logical"],
-                page_state["past_len"])
-            new_cache["attn"] = c
         elif mode == "decode" and spatial_axis is not None:
             y, new_attn = attention.apply_decode_spatial(
                 params["core"], acfg, h, cache["attn"], lengths,
@@ -540,38 +529,14 @@ def prefill(params, cfg: ModelCfg, batch, *, cache_len: Optional[int] = None,
                                     hist)
 
 
-def prefill_chunk_paged(params, cfg: ModelCfg, batch, cache, chunk_state):
-    """Prefill one page-aligned chunk of a prompt from a NONZERO cache
-    offset, attending to pool pages written by earlier chunks.
-
-    batch["tokens"] [B,C] — the chunk (right-padded to a page multiple);
-    ``cache["layers"]`` — pool slabs [L, n_pages, page, nkv, dh], read-only;
-    ``chunk_state``:
-      past_phys/past_logical [B,Wp] — block-table rows of the pages earlier
-        chunks wrote (-1 = pad; Wp is bucketed so compiles stay O(log)),
-      past_len [B] — tokens already cached (the chunk's absolute offset),
-      last_index [B] — within-chunk index whose logits to return (only
-        meaningful on a prompt's final chunk).
-
-    Returns (logits [B, vocab_padded], chunk_caches) where chunk_caches
-    have prefill layout [L, B, C, nkv, dh] — the engine scatters them into
-    this chunk's pool pages, exactly like a monolithic prefill's cache.
-    Shapes depend only on (C, Wp) buckets, never on the raw prompt length.
-    """
-    x = _embed_inputs(params, cfg, batch)
-    b, c, _ = x.shape
-    positions = chunk_state["past_len"][:, None] + jnp.arange(c)[None, :]
-    valid = (jnp.arange(c)[None, :] <= chunk_state["last_index"][:, None]
-             if cfg.has_moe else None)
-    x, chunk_caches, _, hist = _run_stack(
-        params["blocks"], cfg, cfg.pattern, x, positions,
-        mode="prefill_chunk", causal=cfg.causal, caches=cache["layers"],
-        page_state=chunk_state, valid=valid)
-    x_last = jnp.take_along_axis(
-        x, chunk_state["last_index"][:, None, None].astype(jnp.int32),
-        axis=1)
-    logits = _logits(params, cfg, x_last)
-    return logits[:, 0], _with_hist({"layers": chunk_caches}, hist)
+def prefill_cache_shapes(params, cfg: ModelCfg, n_tokens: int):
+    """Shapes and dtypes of ``prefill``'s per-layer caches for one
+    ``n_tokens`` prompt, traced and never compiled — what the paged
+    backends size their pool slabs from."""
+    probe = {"tokens": jax.ShapeDtypeStruct((1, n_tokens), jnp.int32)}
+    _, cache = jax.eval_shape(lambda p, b: prefill(p, cfg, b), params,
+                              probe)
+    return cache["layers"]
 
 
 def prefill_chunk_batch_paged(params, cfg: ModelCfg, batch, cache,
@@ -594,10 +559,10 @@ def prefill_chunk_batch_paged(params, cfg: ModelCfg, batch, cache,
         logits row; only meaningful on a lane's final chunk).
 
     Returns (logits [S, vocab_padded], chunk_caches [L, 1, B_tok, ...])
-    — the engine scatters the flat rows onto each lane's pool pages,
-    exactly like the per-sequence chunk path but for the whole batch at
-    once. All shapes depend only on (B_tok, S, Wp), never on the mix of
-    chunks packed, so there is exactly one prefill compilation.
+    — the engine scatters the flat rows onto each lane's pool pages. A
+    one-lane wave is one sequence's chunk. All shapes depend only on
+    (B_tok, S, Wp), never on the mix of chunks packed, so there is
+    exactly one prefill compilation.
     """
     x = _embed_inputs(params, cfg, batch)
     positions = pack_state["positions"][None, :]
@@ -626,8 +591,8 @@ def prefill_chunk_batch_spatial(params, cfg: ModelCfg, batch, cache,
         for the flat buffer's pages (SCRATCH off the owner shard);
     seg_ids/positions/past_len/last_index are replicated. Every shard
     computes partial (m, l, o) states of ALL lanes' chunk queries
-    against its local past pages; the merge is the same pmax/psum tree
-    as the per-sequence spatial path (see attention).
+    against its local past pages, merged through a pmax/psum tree (see
+    ``attention.apply_prefill_chunk_batch_spatial``).
     """
     shard_spec, rep_spec = _spatial_specs(mesh, axis)
     sharded = {"past_phys", "past_lane", "past_logical", "chunk_phys"}
@@ -677,58 +642,6 @@ def decode_step(params, cfg: ModelCfg, tokens, cache):
 def _spatial_specs(mesh, axis: str):
     from jax.sharding import PartitionSpec as P
     return P(axis), P()
-
-
-def prefill_chunk_spatial(params, cfg: ModelCfg, batch, cache, chunk_state,
-                          *, mesh, axis: str = "shards"):
-    """Prefill one chunk of a sequence-sharded prompt across a device mesh.
-
-    One SPMD dispatch (shard_map over mesh axis ``axis``): every shard runs
-    the replicated block stack, computes a partial (m, l, o) of the chunk
-    queries against ITS local past pages, merges the partials with
-    pmax/psum (exact — DRAttention's combination executed as a tree), and
-    scatters the chunk's fresh K/V rows into the pages it owns.
-
-    ``cache["layers"]`` leaves are stacked per-shard slabs
-    [n_shards, L, P_local, page, nkv, dh], sharded on axis 0; chunk_state:
-      past_phys/past_logical [n_shards, B, Wp] — shard-LOCAL physical ids /
-        GLOBAL logical page indices of pages earlier chunks wrote,
-      chunk_phys [n_shards, B, C // page] — local scatter targets for this
-        chunk's pages (SCRATCH where another shard owns the page),
-      past_len / last_index [B] — replicated, as in prefill_chunk_paged.
-
-    Returns (logits [B, vocab_padded], {"layers": updated stacked slabs}).
-    """
-    shard_spec, rep_spec = _spatial_specs(mesh, axis)
-    sharded = {"past_phys", "past_logical", "chunk_phys"}
-    cs_specs = {k: shard_spec if k in sharded else rep_spec
-                for k in chunk_state}
-
-    def local_fn(p, toks, layers, cs):
-        layers = jax.tree.map(lambda leaf: leaf[0], layers)
-        cs = {k: (v[0] if k in sharded else v) for k, v in cs.items()}
-        x = _embed_inputs(p, cfg, {"tokens": toks})
-        b, c, _ = x.shape
-        positions = cs["past_len"][:, None] + jnp.arange(c)[None, :]
-        x, new_layers, _, _ = _run_stack(
-            p["blocks"], cfg, cfg.pattern, x, positions,
-            mode="prefill_chunk", causal=cfg.causal, caches=layers,
-            page_state=cs, spatial_axis=axis)
-        x_last = jnp.take_along_axis(
-            x, cs["last_index"][:, None, None].astype(jnp.int32), axis=1)
-        logits = _logits(p, cfg, x_last)[:, 0]
-        return logits, jax.tree.map(lambda leaf: leaf[None], new_layers)
-
-    fn = jax.shard_map(
-        local_fn, mesh=mesh,
-        in_specs=(jax.tree.map(lambda _: rep_spec, params), rep_spec,
-                  jax.tree.map(lambda _: shard_spec, cache["layers"]),
-                  cs_specs),
-        out_specs=(rep_spec,
-                   jax.tree.map(lambda _: shard_spec, cache["layers"])))
-    logits, new_layers = fn(params, batch["tokens"], cache["layers"],
-                            chunk_state)
-    return logits, {"layers": new_layers}
 
 
 def decode_step_spatial(params, cfg: ModelCfg, tokens, cache, page_state,

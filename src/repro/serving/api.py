@@ -164,7 +164,7 @@ class LLM:
         already have that many jax devices, see
         ``repro.spatial.ensure_host_devices``). ``engine_cfg`` overrides
         the backend's default config; ``sched_cfg`` the scheduler's
-        (default: batched prefill with the ``prefill_tokens="auto"``
+        (default ``SchedulerCfg()``: the ``prefill_tokens="auto"``
         budget controller). ``rng`` seeds both param init and sampling.
         ``telemetry`` (an ``obs.Telemetry``) enables tracing + metrics.
         ``audit_cfg`` (an ``obs.AuditCfg``) tunes the sampled DLZS
@@ -189,7 +189,7 @@ class LLM:
             eng = ServingEngine(model_cfg, params,
                                 engine_cfg or EngineCfg(), rng=rng)
             return cls(eng, telemetry=telemetry)
-        scfg = sched_cfg or SchedulerCfg(prefill_tokens="auto")
+        scfg = sched_cfg or SchedulerCfg()
         if backend == "paged":
             eng = PagedServingEngine(model_cfg, params,
                                      engine_cfg or PagedEngineCfg(),

@@ -128,8 +128,7 @@ class DisaggRouter(LLM):
                              "choose from ('paged', 'spatial')")
 
         pre = build(prefill_backend or backend, prefill_engine_cfg,
-                    prefill_sched_cfg
-                    or SchedulerCfg(prefill_tokens="auto"))
+                    prefill_sched_cfg or SchedulerCfg())
         dec = build(backend, decode_engine_cfg,
                     decode_sched_cfg or SchedulerCfg())
         return cls(pre, dec, telemetry=telemetry, fault_plan=fault_plan,

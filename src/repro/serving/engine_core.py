@@ -3,9 +3,10 @@ serving backend.
 
 ``PagedServingEngine`` and ``SpatialServingEngine`` used to carry two
 drifting copies of the identical serving scaffold: admission binding,
-chunked prefill, the batched varlen prefill's phase A (pending-cursor
-allocation) / phase A2 (same-tick prefix dedup) / wave split / commit,
-the fused decode loop, lazy cold-page shedding, and preempt/swap-in.
+the batched varlen chunk prefill's phase A (pending-cursor allocation) /
+phase A2 (same-tick prefix dedup) / wave split / commit, the fused
+decode loop, lazy cold-page shedding, and preempt/swap-in. Prefill has
+one path: every prompt advances through batched varlen chunk waves.
 Every scheduler-visible behavior now lives HERE, once, driven through a
 small formal ``Backend`` protocol that covers only what genuinely
 differs between a single page pool and a sharded mesh deployment:
@@ -13,9 +14,8 @@ differs between a single page pool and a sharded mesh deployment:
 * pool primitives — allocate a chunk's pages, look up / register prefix
   keys, drop references (``alloc_chunk`` / ``lookup_prefix`` /
   ``register_prefix`` / ``decref_page`` / ``release_table``);
-* dispatch primitives — run one chunk, one batched wave, or one fused
-  decode step on the device(s) (``dispatch_chunk`` / ``dispatch_wave``
-  / ``decode_step``);
+* dispatch primitives — run one batched prefill wave or one fused
+  decode step on the device(s) (``dispatch_wave`` / ``decode_step``);
 * swap hooks — gather page rows to the host and write them back
   (``gather_park`` / ``upload_park`` / ``page_in_extend``), with ONE
   payload layout (flat page axis) so the host ``SwapArea`` format is
@@ -79,9 +79,8 @@ class Backend(Protocol):
     bucket_pow2: bool
     share: bool                  # effective prefix sharing
     keep_recent: int             # newest pages a lazy shed must keep
-    batched: bool                # batched varlen prefill configured
-    budget_tokens: Optional[int]  # flat-buffer width (one compile)
-    batch_wp: Optional[int]      # past-arena width (per pool shard)
+    budget_tokens: int           # prefill flat-buffer width (one compile)
+    batch_wp: int                # past-arena width (per pool shard)
     decode_sparsity: Optional[dict]
     # Last decode step's sparsity telemetry: {"pages_total": resident
     # pages a dense gather would touch, "pages_hot": pages the bounded
@@ -126,9 +125,6 @@ class Backend(Protocol):
 
     def decref_page(self, g: int, pid: int) -> None: ...
 
-    def register_prompt_pages(self, toks, table, fresh_globals,
-                              start_page: int) -> None: ...
-
     def ref_of(self, table, j: int) -> int: ...
 
     def held_pages(self, table, shard: Optional[int]) -> int: ...
@@ -138,13 +134,6 @@ class Backend(Protocol):
         Single-pool backends always say True."""
 
     # -- prefill dispatch ------------------------------------------------
-    def dispatch_chunk(self, pf, table, start: int, end: int, width: int,
-                       last_idx: int, pages: list[int],
-                       fresh_globals: list[int]):
-        """Compute + scatter ONE chunk; returns the logits row of
-        ``last_idx`` (legacy per-sequence path). May stay a device
-        array — the core only materializes the FINAL chunk's row."""
-
     def arena_cost(self, past_pages: int) -> list[int]:
         """Per-pool-shard past-arena slots a lane with ``past_pages``
         past pages occupies in a batched wave."""
@@ -229,10 +218,9 @@ class EngineCore:
     Single-step flow (``step()`` = one scheduler tick):
       admit   — swap preempted sequences back in, bind waiting requests
                 to free slots (no page allocation yet)
-      prefill — with a ``SchedulerCfg.prefill_tokens`` budget: pack
-                chunks of EVERY prefilling prompt (consecutive chunks
-                merge) into ONE batched varlen dispatch; legacy path: up
-                to ``prefill_per_step`` one-sequence chunk dispatches
+      prefill — pack chunks of every prefilling prompt that fit the
+                ``SchedulerCfg.prefill_tokens`` budget (consecutive
+                chunks merge) into ONE batched varlen dispatch
       decode  — one fused decode step over every decode-phase slot;
                 finished sequences are reaped and their pages released
     """
@@ -244,11 +232,9 @@ class EngineCore:
         self.cfg = backend.cfg
         self.rng = rng if rng is not None else jax.random.PRNGKey(0)
         self.sched = Scheduler(scfg or SchedulerCfg())
-        if backend.batched and self.sched.cfg.prefill_tokens == "auto":
-            chunk_tok = self.sched.cfg.chunk_pages * backend.page_size
-            self.sched.attach_budget(lo=chunk_tok,
-                                     hi=backend.budget_tokens,
-                                     quantum=backend.page_size)
+        chunk_tok = self.sched.cfg.chunk_pages * backend.page_size
+        self.sched.attach_budget(lo=chunk_tok, hi=backend.budget_tokens,
+                                 quantum=backend.page_size)
 
         self.swap_area = SwapArea()
         self.active: dict[int, Request] = {}       # slot -> request
@@ -366,7 +352,7 @@ class EngineCore:
     def held_pages(self, slot: int, shard: Optional[int] = None) -> int:
         return self.backend.held_pages(self.tables.get(slot, ()), shard)
 
-    # -- executor protocol: chunked prefill ---------------------------------
+    # -- executor protocol: batched varlen chunk prefill --------------------
 
     def _alloc_chunk(self, slot: int, pf, start_page: int, n_need: int):
         """Backend allocation with pool pressure translated into the
@@ -559,61 +545,6 @@ class EngineCore:
             self.backend.release_pages(pages, start_page)
             pf.pending = None
 
-    def exec_prefill_chunk(self, slot: int) -> bool:
-        """Share/allocate + compute + scatter ONE chunk of ``slot``'s
-        prompt. Returns True once the prompt is complete (slot enters
-        decode). Raises NeedPages when the pool cannot supply the chunk."""
-        pf = self._pf[slot]
-        page = self.backend.page_size
-        start, end, width = pf.spans[pf.chunk]
-        start_page = start // page
-        n_need = -(-end // page) - start_page
-        pages, fresh_globals, sharing = self._alloc_chunk(
-            slot, pf, start_page, n_need)
-        pf.sharing = sharing
-        table = self.tables[slot]
-        table.extend(pages)
-        t = len(pf.prompt)
-        last = pf.chunk == len(pf.spans) - 1
-        if self.tel.enabled and pf.chunk == 0:
-            tl = self.tel.timeline(self.active[slot].rid)
-            if tl.first_chunk_t is None:
-                tl.first_chunk_t = time.perf_counter()
-
-        logits = None
-        if fresh_globals or last:  # fully-shared middle chunks skip compute
-            last_idx = (t - 1 if last else end - 1) - start
-            kind = ("chunk", width)
-            try:
-                with self.tel.tracer.span(
-                        "prefill.chunk", slot=slot, width=width,
-                        compile=kind not in self._compiled):
-                    logits = self.backend.dispatch_chunk(
-                        pf, table, start, end, width, last_idx, pages,
-                        fresh_globals)
-            except NeedPages:
-                raise
-            except Exception as err:
-                # isolate to this request: its pages (all in the table
-                # by now, none prefix-registered yet — the sequential
-                # path registers after compute) fall with it in the
-                # recompute preemption the scheduler now issues
-                self._note_fault([slot], err, "prefill")
-                raise ExecFault([slot], err, "prefill") from err
-            self._compiled.add(kind)
-            self._note_moe("prefill")
-            if self.backend.share and pf.toks is not None:
-                self.backend.register_prompt_pages(pf.toks, table,
-                                                   fresh_globals,
-                                                   start_page)
-        pf.chunk += 1
-        if not last:
-            return False
-        self._finish_prefill(slot, pf, logits)
-        return True
-
-    # -- executor protocol: batched varlen chunk prefill --------------------
-
     def pending_chunk_widths(self, slot: int) -> list[int]:
         pf = self._pf[slot]
         return [w for _, _, w in pf.spans[pf.chunk:]]
@@ -750,7 +681,7 @@ class EngineCore:
             try:
                 with self.tel.tracer.span("prefill.dispatch", wave=i,
                                           lanes=len(wave), compile=first):
-                    self._dispatch_chunk_wave(wave, logits_by_slot)
+                    self._run_wave(wave, logits_by_slot)
             except NeedPages:
                 raise
             except Exception as err:
@@ -783,8 +714,7 @@ class EngineCore:
                                      done_out=done)
         return done
 
-    def _dispatch_chunk_wave(self, wave: list[int],
-                             logits_by_slot: dict) -> None:
+    def _run_wave(self, wave: list[int], logits_by_slot: dict) -> None:
         """Pack one wave of merged spans into the shared flat buffer
         (tokens, segment ids, absolute positions, per-lane past lengths
         and last indices) and hand it to the backend dispatch, which

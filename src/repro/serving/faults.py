@@ -28,7 +28,7 @@ seam            raises                 engine path exercised
 ``swap_corrupt``  ``FaultInjected``    swap-in teardown + bounded
                 (at ``upload_park``)   retry-with-recompute
 ``dispatch``    ``FaultInjected``      per-request quarantine of a
-                                       prefill chunk/wave
+                                       prefill wave
 ``decode``      ``FaultInjected``      decode-batch recompute retry
 ``stall``       (sleeps ``stall_s``)   slow-tick tolerance — budget
                                        autotuner and deadline sweeps
@@ -151,11 +151,6 @@ class FaultyBackend:
         if self.plan.fire("alloc"):
             raise PoolExhausted("injected: pool exhausted")
         return self.inner.alloc_chunk(pf, start_page, n_need)
-
-    def dispatch_chunk(self, *args, **kwargs):
-        if self.plan.fire("dispatch"):
-            raise FaultInjected("injected: chunk dispatch failed")
-        return self.inner.dispatch_chunk(*args, **kwargs)
 
     def dispatch_wave(self, *args, **kwargs):
         if self.plan.fire("dispatch"):

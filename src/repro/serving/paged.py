@@ -10,12 +10,12 @@ Layering (see docs/serving.md):
 * ``repro.serving.scheduler.Scheduler`` — policy: who admits, which
   prompt prefills next, who is preempted under pool pressure.
 * ``repro.serving.engine_core.EngineCore`` — the executor state machine
-  the scheduler drives: admission binding, chunked + batched varlen
-  prefill (the allocate/dedup/wave-split/commit scaffold lives THERE,
-  once), the fused decode loop, lazy cold-page shedding,
-  preempt/swap-in. Shared with the spatial engine.
+  the scheduler drives: admission binding, batched varlen chunk prefill
+  (the allocate/dedup/wave-split/commit scaffold lives THERE, once), the
+  fused decode loop, lazy cold-page shedding, preempt/swap-in. Shared
+  with the spatial engine.
 * ``PagedBackend`` (this module) — the device driver EngineCore calls:
-  pool slabs, jitted prefill/chunk/decode/scatter kernels, single-pool
+  pool slabs, jitted prefill-wave/decode/scatter kernels, single-pool
   allocation and prefix indexing.
 
 ``PagedServingEngine`` is the thin composition of the three — construct
@@ -24,12 +24,13 @@ it directly, or (preferred) through ``repro.serving.api.LLM``.
 Properties carried by this backend:
 
 * Chunked prefill — prompts prefill in page-aligned chunks that
-  interleave with decode steps. Chunk 0 reuses the bucketed monolithic
-  prefill; later chunks run ``lm.prefill_chunk_paged`` against the pages
-  earlier chunks wrote. Pages are allocated chunk-by-chunk.
-* ``max_len`` is a per-request property; admission is length-bucketed so
-  prefill compiles O(log max_len) shapes; decode compiles ONCE — its
-  shapes depend only on (max_batch, hot_pages, pool size).
+  interleave with decode steps. Each tick packs the chunks of every
+  prefilling prompt into one fixed-width flat buffer and runs
+  ``lm.prefill_chunk_batch_paged`` once over it, each lane attending to
+  the pages its earlier chunks wrote. Pages are allocated chunk-by-chunk.
+* ``max_len`` is a per-request property; prefill compiles ONCE (fixed
+  flat-buffer and past-arena widths) and so does decode — its shapes
+  depend only on (max_batch, hot_pages, pool size).
 * Decode gathers at most ``hot_pages`` pages per sequence, DLZS page
   scores ranking the cold pages (exact, token-parity with the dense
   engine, when ``hot_pages`` covers the longest request).
@@ -124,7 +125,6 @@ class PagedBackend:
             model_cfg.star is None
             or pcfg.page_size % model_cfg.star.block_q == 0)
         if (model_cfg.star is not None
-                and scfg.chunk_pages is not None
                 and (scfg.chunk_pages * pcfg.page_size)
                 % model_cfg.star.block_q != 0):
             raise ValueError(
@@ -138,21 +138,15 @@ class PagedBackend:
 
         # batched varlen chunk prefill: fixed flat-buffer width + fixed
         # past-gather window => exactly one prefill compilation
-        max_tokens = resolve_prefill_tokens(scfg, pcfg.page_size)
-        self.batched = max_tokens is not None
-        self.budget_tokens = self.batch_wp = None
-        if self.batched:
-            self.budget_tokens = bucketing.budget_tokens(
-                max_tokens, pcfg.page_size, scfg.chunk_pages,
-                pow2=pcfg.bucket_pow2)
-            self.batch_wp = bucketing.bucket_count(
-                pcfg.batch_past_pages or pcfg.n_pages - 1,
-                pow2=pcfg.bucket_pow2)
+        self.budget_tokens = bucketing.budget_tokens(
+            resolve_prefill_tokens(scfg, pcfg.page_size), pcfg.page_size,
+            scfg.chunk_pages, pow2=pcfg.bucket_pow2)
+        self.batch_wp = bucketing.bucket_count(
+            pcfg.batch_past_pages or pcfg.n_pages - 1,
+            pow2=pcfg.bucket_pow2)
 
         # each program compiles under its method's name, which the
-        # profiler trace shows (jit__prefill_fn, jit__scatter_fn, ...)
-        self._prefill = jax.jit(self._prefill_fn)
-        self._prefill_chunk = jax.jit(self._prefill_chunk_fn)
+        # profiler trace shows (jit__prefill_chunk_batch_fn, ...)
         self._prefill_chunk_batch = jax.jit(self._prefill_chunk_batch_fn)
         # donate the cache/pool slabs: these updates would otherwise keep
         # two full copies of the page pool live per step (no-op on CPU,
@@ -173,16 +167,15 @@ class PagedBackend:
         self._scores = jax.jit(metrics.page_scores)
         self._scores_by_layer = jax.jit(metrics.page_scores_per_layer)
 
-        # Build the page pool slabs from a one-page probe prefill: every
-        # prefill cache leaf [L, 1, page, nkv, dh] becomes a pool slab
-        # [L, n_pages, page, nkv, dh].
-        probe = {"tokens": jnp.zeros((1, pcfg.page_size), jnp.int32)}
-        _, cache_one = self._prefill(params, probe,
-                                     jnp.zeros((1,), jnp.int32))
+        # Build the page pool slabs from the shapes of a one-page prefill
+        # (traced, never compiled): every prefill cache leaf [L, 1, page,
+        # nkv, dh] becomes a pool slab [L, n_pages, page, nkv, dh].
+        cache_one = lm.prefill_cache_shapes(params, model_cfg,
+                                            pcfg.page_size)
         def slab(leaf):
             shape = (leaf.shape[0], pcfg.n_pages) + leaf.shape[2:]
             return jnp.zeros(shape, leaf.dtype)
-        layers = jax.tree.map(slab, cache_one["layers"])
+        layers = jax.tree.map(slab, cache_one)
         if self.kv_quant:
             # int8 cold tier rides IN the cache tree: every attention
             # update uses dict(cache, k=..., v=...), so the tier leaves
@@ -215,13 +208,6 @@ class PagedBackend:
 
     # -- jitted kernels -----------------------------------------------------
 
-    def _prefill_fn(self, params, batch, last_index):
-        return lm.prefill(params, self.cfg, batch, last_index=last_index)
-
-    def _prefill_chunk_fn(self, params, batch, cache, chunk_state):
-        return lm.prefill_chunk_paged(params, self.cfg, batch, cache,
-                                      chunk_state)
-
     def _prefill_chunk_batch_fn(self, params, batch, cache, pack_state):
         return lm.prefill_chunk_batch_paged(params, self.cfg, batch, cache,
                                             pack_state)
@@ -238,9 +224,9 @@ class PagedBackend:
                                page_state)[1]["audit_mass"]
 
     def _scatter_fn(self, pool_layers, one_layers, phys):
-        """Write a prefilled sequence's rows into pool pages ``phys``.
+        """Write a prefill wave's flat rows into pool pages ``phys``.
 
-        Two-tree map over (pool slab, per-sequence cache): the prefill
+        Two-tree map over (pool slab, the wave's flat cache): the prefill
         cache has no int8-tier leaves, so with the quantized tier on the
         tier is split out first and merged back untouched — freshly
         prefilled pages are fp until they leave the DLZS hot set."""
@@ -291,7 +277,7 @@ class PagedBackend:
             raise ValueError(
                 f"request {rid}: {total} tokens needs {need} pages; "
                 f"pool holds {self.pool.n_pages - 1}")
-        if self.batched and need - 1 > self.batch_wp:
+        if need - 1 > self.batch_wp:
             raise ValueError(
                 f"request {rid}: {need} pages exceeds the batched "
                 f"chunk-prefill past window ({self.batch_wp} pages); "
@@ -329,14 +315,6 @@ class PagedBackend:
     def forget_prefix(self, g: int, pid: int) -> None:
         self.pool.forget(pid)
 
-    def register_prompt_pages(self, toks, table, fresh_globals,
-                              start_page: int) -> None:
-        page = self.page_size
-        for g in fresh_globals:
-            end = (g + 1) * page
-            if end <= len(toks):
-                self.pool.register(toks[:end], table[g])
-
     def ref_of(self, table, j: int) -> int:
         return self.pool.ref(table[j])
 
@@ -352,45 +330,6 @@ class PagedBackend:
         return True
 
     # -- prefill dispatch ------------------------------------------------------
-
-    def dispatch_chunk(self, pf, table, start, end, width, last_idx,
-                       pages, fresh_globals) -> np.ndarray:
-        page = self.page_size
-        start_page = start // page
-        toks = bucketing.pad_tokens(pf.prompt[start:end], width)
-        batch = {"tokens": jnp.asarray(toks)[None, :]}
-        if start == 0:
-            logits, cache_one = self._prefill(
-                self.params, batch, jnp.asarray([last_idx], jnp.int32))
-            self.moe_hist = cache_one.get("moe_hist")
-        else:
-            wp = bucketing.bucket_count(start_page,
-                                        pow2=self.pcfg.bucket_pow2)
-            past_phys = np.full((1, wp), -1, np.int32)
-            past_phys[0, :start_page] = table[:start_page]
-            past_logical = np.full((1, wp), -1, np.int32)
-            past_logical[0, :start_page] = np.arange(start_page)
-            chunk_state = {
-                "past_phys": jnp.asarray(past_phys),
-                "past_logical": jnp.asarray(past_logical),
-                "past_len": jnp.asarray([start], jnp.int32),
-                "last_index": jnp.asarray([last_idx], jnp.int32)}
-            logits, cache_one = self._prefill_chunk(
-                self.params, batch, {"layers": self.cache["layers"]},
-                chunk_state)
-            self.moe_hist = cache_one.get("moe_hist")
-        # chunk page j -> its fresh pool page; shared pages (content
-        # identical by construction) and bucket padding -> scratch
-        fresh_set = set(fresh_globals)
-        phys = np.full((width // page,), SCRATCH, np.int32)
-        for j, pid in enumerate(pages):
-            if start_page + j in fresh_set:
-                phys[j] = pid
-        self.cache["layers"] = self._scatter(
-            self.cache["layers"], cache_one["layers"], jnp.asarray(phys))
-        # stays on device: middle chunks' logits are never read, and the
-        # final chunk's row is materialized once by _finish_prefill
-        return logits[0]
 
     def arena_cost(self, past_pages: int) -> list[int]:
         return [past_pages]

@@ -9,14 +9,16 @@ phases against an executor (``PagedServingEngine`` implements the protocol):
      starve), then bind waiting requests to free slots. Admission binds a
      SLOT only — pages are allocated chunk-by-chunk during prefill, so a
      long prompt no longer reserves its worst case up front.
-  2. prefill — advance at most ``prefill_per_step`` prefilling sequences by
-     ONE page-aligned chunk each, shortest-remaining-first within a
-     priority level, with aging: a prefill passed over ``starvation_ticks``
-     times jumps the SJF queue, so a long prompt keeps progressing under a
-     sustained short-prompt stream. Decode never waits for a whole prompt:
-     a long prefill is sliced across many ticks and short requests
-     admitted mid-way reach their first token early (chunked prefill is
-     what bounds TTFT).
+  2. prefill — pack the next page-aligned chunk of as many prefilling
+     sequences as fit the per-tick ``prefill_tokens`` budget (consecutive
+     chunks of one sequence merge) and advance them all in ONE batched
+     varlen dispatch, shortest-remaining-first within a priority level,
+     with aging: a prefill passed over ``starvation_ticks`` times jumps
+     the SJF queue, so a long prompt keeps progressing under a sustained
+     short-prompt stream. Decode never waits for a whole prompt: a long
+     prefill is sliced across many ticks and short requests admitted
+     mid-way reach their first token early (chunked prefill is what
+     bounds TTFT).
   3. decode — one fused decode step over every decode-phase slot.
 
 Pool pressure: when a chunk allocation or decode-time page growth hits
@@ -134,30 +136,22 @@ class AdmissionCfg:
 
 @dataclasses.dataclass(frozen=True)
 class SchedulerCfg:
-    chunk_pages: Optional[int] = 4   # prefill chunk size in pages
-    #                                  (None = monolithic, the pre-chunking
-    #                                  behavior: one prefill per prompt)
-    prefill_tokens: Optional[Union[int, str]] = None
+    chunk_pages: int = 4             # prefill chunk size in pages
+    prefill_tokens: Union[int, str] = "auto"
     # Per-tick prefill TOKEN budget: each tick packs the next chunk of as
     # many prefilling sequences as fit (padded widths, SJF+aging order)
     # and advances them all in ONE batched varlen dispatch
-    # (``exec_prefill_chunk_batch``). This replaces the per-SEQUENCE
-    # ``prefill_per_step`` counter as the throughput knob — one dispatch
-    # per tick regardless of how many prompts are mid-prefill, which is
-    # what closes the chunked-vs-monolithic gap. None (or monolithic
-    # chunk_pages=None) keeps the legacy one-dispatch-per-sequence path.
-    # "auto" (the ``api.LLM`` default) sizes the dispatch buffer to
+    # (``exec_prefill_chunk_batch``). An int fixes the budget (and the
+    # compiled flat-buffer width); "auto" sizes the dispatch buffer to
     # AUTO_PREFILL_CHUNKS chunks and lets a ``BudgetController`` grow/
     # shrink the per-tick PACKING budget inside that fixed buffer from
     # observed tick wall-times (compile-safe: the compiled width never
-    # changes, only how much of it a tick fills).
+    # changes, only how much of it a tick fills). ``chunk_pages *
+    # page_size`` advances one chunk per tick.
     autotune_target_s: float = 0.5   # "auto" only: EMA controller keeps
     #                                  one prefill phase near this wall
     #                                  time — bounds how long co-resident
     #                                  decodes stall behind prefill
-    prefill_per_step: int = 1        # LEGACY path only: prefill chunks
-    #                                  advanced per tick when no token
-    #                                  budget is set
     swap: bool = True                # preempt via host swap (False: drop
     #                                  pages, recompute from prompt+output)
     lazy_swap: bool = False          # under pressure, first try shedding a
@@ -223,19 +217,14 @@ AUTO_PREFILL_CHUNKS = 6   # "auto": the compiled dispatch buffer holds up
 #                           to this many chunks; the controller moves the
 #                           packing budget inside it. A wider buffer buys
 #                           deeper packing but pays its padding compute
-#                           every dispatch — 6 chunks is the measured
-#                           knee on the mixed workload
-#                           (BENCH_serving.json batched_prefill)
+#                           every dispatch
 
 
-def resolve_prefill_tokens(cfg: SchedulerCfg, page_size: int
-                           ) -> Optional[int]:
+def resolve_prefill_tokens(cfg: SchedulerCfg, page_size: int) -> int:
     """The numeric flat-buffer width a ``prefill_tokens`` setting implies
     (what the engine compiles once). ``"auto"`` sizes the buffer to
     ``AUTO_PREFILL_CHUNKS`` chunks — the controller's upper bound."""
     pt = cfg.prefill_tokens
-    if pt is None or cfg.chunk_pages is None:
-        return None
     if pt == "auto":
         return AUTO_PREFILL_CHUNKS * cfg.chunk_pages * page_size
     return int(pt)
@@ -284,10 +273,6 @@ class Executor(Protocol):
     def exec_admit(self, req: Request) -> int:
         """Bind a request (fresh, or recompute-resume carrying prior
         output) to a free slot. Allocates NO pages."""
-
-    def exec_prefill_chunk(self, slot: int) -> bool:
-        """Advance one chunk; True when the prompt is fully prefilled and
-        the slot entered decode. May raise NeedPages."""
 
     def exec_prefill_chunk_batch(self, batch: list[tuple[int, int]]
                                  ) -> list[int]:
@@ -395,7 +380,7 @@ class Scheduler:
                 lo=lo, hi=hi, quantum=quantum,
                 target_s=self.cfg.autotune_target_s)
 
-    def prefill_budget(self) -> Optional[int]:
+    def prefill_budget(self) -> int:
         """Tokens the next batched prefill phase may pack."""
         if self.budget_ctl is not None:
             return self.budget_ctl.budget
@@ -575,10 +560,8 @@ class Scheduler:
     # stream of short ones, so a prefill passed over ``starvation_ticks``
     # times is aged to the front of its priority level (oldest first).
     #
-    # Two dispatch modes: with a ``prefill_tokens`` budget, ONE batched
-    # varlen dispatch advances every sequence that packs under the budget
-    # (the continuous-batching form); otherwise the legacy loop issues up
-    # to ``prefill_per_step`` one-sequence dispatches.
+    # ONE batched varlen dispatch advances every sequence that packs
+    # under the ``prefill_tokens`` budget (the continuous-batching form).
     def _prefill_order_key(self, ex: Executor):
         def order(slot):
             st = self.running[slot]
@@ -590,11 +573,7 @@ class Scheduler:
         return order
 
     def _prefill_phase(self, ex: Executor) -> None:
-        if self.cfg.prefill_tokens is not None \
-                and self.cfg.chunk_pages is not None:
-            advanced = self._prefill_batched(ex)
-        else:
-            advanced = self._prefill_sequential(ex)
+        advanced = self._prefill_batched(ex)
         # aging bookkeeping: slots passed over this tick accumulate wait
         for s, st in list(self.running.items()):
             if st.phase == "prefill":
@@ -602,36 +581,6 @@ class Scheduler:
                     else self._pf_wait.get(s, 0) + 1
             else:
                 self._pf_wait.pop(s, None)
-
-    def _prefill_sequential(self, ex: Executor) -> set[int]:
-        order = self._prefill_order_key(ex)
-        budget = self.cfg.prefill_per_step
-        advanced: set[int] = set()
-        while budget > 0:
-            cands = sorted((s for s, st in self.running.items()
-                            if st.phase == "prefill"), key=order)
-            if not cands:
-                break
-            slot = cands[0]
-            advanced.add(slot)
-            budget -= 1
-            try:
-                if ex.exec_prefill_chunk(slot):
-                    self.running[slot].phase = "decode"
-            except ExecFault as e:
-                self._fault_slots(ex, e)
-                continue
-            except NeedPages as e:
-                if self._try_shed(ex, needy=slot, shard=e.shard):
-                    budget += 1                    # retry the same slot
-                    continue
-                victim = self._pick_victim(ex, needy=slot, shard=e.shard)
-                if victim is None or victim == slot:
-                    self._preempt(ex, slot)        # self-preempt: requeue
-                else:
-                    self._preempt(ex, victim)
-                    budget += 1                    # retry the same slot
-        return advanced
 
     def _prefill_batched(self, ex: Executor) -> set[int]:
         """Pack next-chunks under the token budget (SJF + aging order)

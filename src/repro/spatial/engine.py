@@ -10,21 +10,22 @@ softmax ``(m, l, o)`` states merged across shards (DRAttention's
 combination) for every cross-shard attention.
 
 Dataflow per phase (each a single SPMD shard_map dispatch — see
-``lm.prefill_chunk_spatial`` / ``lm.decode_step_spatial``):
+``lm.prefill_chunk_batch_spatial`` / ``lm.decode_step_spatial``):
 
-* chunked prefill — the chunk's activations are replicated; every shard
-  computes a partial state of the chunk queries against ITS resident
-  past pages (the causal cross-shard part), the partials merge with
-  pmax/psum, and each shard scatters the chunk's K/V rows into the pages
-  it owns. Exact — same math as the paged engine's gather+softmax, in a
-  different reduction order.
+* chunked prefill — one wave packs the chunks of every prefilling
+  prompt into a flat buffer whose activations are replicated; every
+  shard computes a partial state of each lane's chunk queries against
+  ITS resident slice of that lane's past pages (the causal cross-shard
+  part), the partials merge with pmax/psum, and each shard scatters the
+  chunks' K/V rows into the pages it owns. Exact — same math as the
+  paged engine's gather+softmax, in a different reduction order.
 * decode — the query token is broadcast, each shard attends over its
   local hot pages via the paged gather (DLZS page scores pick them,
   per shard), and the partial states merge to the final output. Decode
   compiles ONCE: shapes depend only on (max_batch, hot_pages_local,
   n_pages_local).
 
-The entire executor state machine — admission, chunked + batched varlen
+The entire executor state machine — admission, batched varlen chunk
 prefill (the allocate/dedup/wave-split/commit scaffold), decode loop,
 lazy cold-page shedding, preempt/swap — is the SHARED
 ``serving.engine_core.EngineCore``; this module only implements the
@@ -139,21 +140,16 @@ class SpatialBackend:
 
         # batched varlen chunk prefill (one shard_map dispatch per tick):
         # fixed flat width + fixed per-shard past window => one compile
-        max_tokens = resolve_prefill_tokens(scfg, pcfg.page_size)
-        self.batched = max_tokens is not None
-        self.budget_tokens = self.batch_wp = None
-        if self.batched:
-            self.budget_tokens = bucketing.budget_tokens(
-                max_tokens, pcfg.page_size, scfg.chunk_pages,
-                pow2=pcfg.bucket_pow2)
-            self.batch_wp = bucketing.bucket_count(
-                pcfg.batch_past_pages or pcfg.n_pages_local - 1,
-                pow2=pcfg.bucket_pow2)
+        self.budget_tokens = bucketing.budget_tokens(
+            resolve_prefill_tokens(scfg, pcfg.page_size), pcfg.page_size,
+            scfg.chunk_pages, pow2=pcfg.bucket_pow2)
+        self.batch_wp = bucketing.bucket_count(
+            pcfg.batch_past_pages or pcfg.n_pages_local - 1,
+            pow2=pcfg.bucket_pow2)
 
         # each program compiles under its method's name, which the
-        # profiler trace shows (jit__decode_fn, jit__prefill_chunk_fn, ...)
-        self._prefill_chunk = jax.jit(self._prefill_chunk_fn,
-                                      donate_argnums=(2,))
+        # profiler trace shows (jit__decode_fn, jit__prefill_chunk_batch_fn,
+        # ...)
         self._prefill_chunk_batch = jax.jit(self._prefill_chunk_batch_fn,
                                             donate_argnums=(2,))
         self._decode = jax.jit(self._decode_fn, donate_argnums=(2,))
@@ -167,21 +163,18 @@ class SpatialBackend:
         self._scores_by_layer = jax.jit(
             jax.vmap(metrics.page_scores_per_layer))
 
-        # Per-shard pool slabs from a one-page probe prefill: each leaf
-        # [L, 1, page, nkv, dh] becomes [n_shards, L, P_local, page, nkv,
-        # dh], sharded over the mesh axis (one slab stack per device).
-        probe = {"tokens": jnp.zeros((1, pcfg.page_size), jnp.int32)}
-
-        def prefill_probe(p, b):
-            return lm.prefill(p, model_cfg, b,
-                              last_index=jnp.zeros((1,), jnp.int32))
-        _, cache_one = jax.jit(prefill_probe)(params, probe)
+        # Per-shard pool slabs from the shapes of a one-page prefill
+        # (traced, never compiled): each leaf [L, 1, page, nkv, dh]
+        # becomes [n_shards, L, P_local, page, nkv, dh], sharded over the
+        # mesh axis (one slab stack per device).
+        cache_one = lm.prefill_cache_shapes(params, model_cfg,
+                                            pcfg.page_size)
         spec = NamedSharding(self.mesh, P(self.topo.axis))
         def slab(leaf):
             shape = (self.topo.n_shards, leaf.shape[0],
                      pcfg.n_pages_local) + leaf.shape[2:]
             return jax.device_put(jnp.zeros(shape, leaf.dtype), spec)
-        layers = jax.tree.map(slab, cache_one["layers"])
+        layers = jax.tree.map(slab, cache_one)
         if self.kv_quant:
             # int8 tier slabs ride in the same sharded tree ([S, L, P,
             # ...] / scales [S, L, P]); re-place so every leaf carries
@@ -209,11 +202,6 @@ class SpatialBackend:
         self.page_bytes_int8 = metrics.quant_bytes_per_page(one)
 
     # -- jitted kernels -----------------------------------------------------
-
-    def _prefill_chunk_fn(self, params, batch, cache, chunk_state):
-        return lm.prefill_chunk_spatial(params, self.cfg, batch, cache,
-                                        chunk_state, mesh=self.mesh,
-                                        axis=self.topo.axis)
 
     def _prefill_chunk_batch_fn(self, params, batch, cache, pack_state):
         return lm.prefill_chunk_batch_spatial(params, self.cfg, batch,
@@ -269,7 +257,7 @@ class SpatialBackend:
                 f"request {rid}: {total} tokens needs {need} striped "
                 f"pages; {self.topo.n_shards} shards x "
                 f"{self.pcfg.n_pages_local - 1} pages cannot hold them")
-        if self.batched and self.topo.max_local_count(need) > self.batch_wp:
+        if self.topo.max_local_count(need) > self.batch_wp:
             raise ValueError(
                 f"request {rid}: {need} striped pages exceeds the "
                 f"batched chunk-prefill past window ({self.batch_wp} "
@@ -306,10 +294,6 @@ class SpatialBackend:
     def forget_prefix(self, g: int, pid: int) -> None:
         self.pools.pools[self.topo.owner(g)].forget(pid)
 
-    def register_prompt_pages(self, toks, table, fresh_globals,
-                              start_page: int) -> None:
-        self.pools.register_prompt_pages(toks, table, fresh_globals)
-
     def ref_of(self, table, j: int) -> int:
         return self.pools.pools[self.topo.owner(j)].ref(table[j])
 
@@ -320,53 +304,6 @@ class SpatialBackend:
         return shard is None or self.topo.owner(j) == shard
 
     # -- prefill dispatch -----------------------------------------------------
-
-    def _past_state(self, table: list[int], start_page: int
-                    ) -> tuple[np.ndarray, np.ndarray]:
-        """Per-shard (past_phys, past_logical) [n_shards, 1, Wp] of the
-        pages earlier chunks wrote. Wp is pow2-bucketed on the largest
-        per-shard count so chunk compiles stay O(log^2)."""
-        n = self.topo.n_shards
-        wp = bucketing.bucket_count(
-            max(1, self.topo.max_local_count(start_page)),
-            pow2=self.pcfg.bucket_pow2)
-        phys = np.full((n, 1, wp), -1, np.int32)
-        logical = np.full((n, 1, wp), -1, np.int32)
-        for s in range(n):
-            globals_ = list(range(s, start_page, n))
-            phys[s, 0, :len(globals_)] = [table[j] for j in globals_]
-            logical[s, 0, :len(globals_)] = globals_
-        return phys, logical
-
-    def dispatch_chunk(self, pf, table, start, end, width, last_idx,
-                       pages, fresh_globals) -> np.ndarray:
-        page = self.page_size
-        start_page = start // page
-        toks = bucketing.pad_tokens(pf.prompt[start:end], width)
-        batch = {"tokens": jnp.asarray(toks)[None, :]}
-        # chunk page targets: the owner shard scatters fresh pages,
-        # everything else (shared content, bucket padding) -> scratch
-        n = self.topo.n_shards
-        fresh_set = set(fresh_globals)
-        chunk_phys = np.full((n, 1, width // page), SCRATCH, np.int32)
-        for cj in range(len(pages)):
-            g = start_page + cj
-            if g in fresh_set:
-                chunk_phys[self.topo.owner(g), 0, cj] = table[g]
-        past_phys, past_logical = self._past_state(table, start_page)
-        chunk_state = {
-            "past_phys": jnp.asarray(past_phys),
-            "past_logical": jnp.asarray(past_logical),
-            "chunk_phys": jnp.asarray(chunk_phys),
-            "past_len": jnp.asarray([start], jnp.int32),
-            "last_index": jnp.asarray([last_idx], jnp.int32)}
-        logits, new_cache = self._prefill_chunk(
-            self.params, batch, {"layers": self.cache["layers"]},
-            chunk_state)
-        self.cache["layers"] = new_cache["layers"]
-        # stays on device: middle chunks' logits are never read, and the
-        # final chunk's row is materialized once by _finish_prefill
-        return logits[0]
 
     def arena_cost(self, past_pages: int) -> list[int]:
         # striping puts ~past_pages/n past slots on each shard's arena

@@ -3,9 +3,9 @@
 One scenario set, driven ONLY through the ``repro.serving.api.LLM``
 front door, that every pool-backed serving backend must pass:
 
-* admission + token parity with the dense oracle (sequential chunked,
-  batched varlen, and ``prefill_tokens="auto"`` budget-controller paths)
-  with the one-compile invariants;
+* admission + token parity with the dense oracle (one chunk per tick,
+  a multi-chunk token budget, and the ``prefill_tokens="auto"``
+  budget controller) with the one-compile invariants;
 * pool pressure: preempt/swap/page-in keeps token parity with an
   unpressured run of the same backend (batched path);
 * recompute-mode preemption parity;
@@ -63,6 +63,12 @@ BACKEND_PARAMS = {
 }
 
 
+def _one_chunk(**kw) -> SchedulerCfg:
+    """One 1-page chunk of one prompt per tick: a prefill token budget of
+    exactly one chunk at page size 16."""
+    return SchedulerCfg(chunk_pages=1, prefill_tokens=16, **kw)
+
+
 def _prompts(cfg, lengths):
     return [(np.arange(l, dtype=np.int32) * 7 + i) % cfg.vocab
             for i, l in enumerate(lengths)]
@@ -84,15 +90,17 @@ def _dense_oracle(cfg, params, prompts, max_tokens=5):
 
 
 def scenario_parity_sequential(make_llm, cfg, params, bp) -> str:
-    """Mixed-length chunked prefill through LLM == dense oracle,
-    token-for-token, with exactly one decode compilation."""
+    """Mixed-length chunked prefill through LLM, one chunk of one prompt
+    per tick, == dense oracle, token-for-token, with one prefill and one
+    decode compilation."""
     prompts = _prompts(cfg, MIXED_LENGTHS)
     want = _dense_oracle(cfg, params, prompts)
-    llm = make_llm(max_batch=2, pages=32, hot=4,
-                   scfg=SchedulerCfg(chunk_pages=1))
+    llm = make_llm(max_batch=2, pages=32, hot=4, scfg=_one_chunk())
     got = _run_llm(llm, prompts)
-    assert got == want, f"sequential parity broke:\n{got}\n{want}"
-    assert llm.stats()["decode_compiles"] == 1
+    assert got == want, f"one-chunk parity broke:\n{got}\n{want}"
+    st = llm.stats()
+    assert st["prefill_batch_compiles"] == 1, st["prefill_batch_compiles"]
+    assert st["decode_compiles"] == 1, st["decode_compiles"]
     return "parity-sequential"
 
 
@@ -160,10 +168,10 @@ def scenario_recompute(make_llm, cfg, params, bp) -> str:
     bf16 argmax ties where ``bp["tie_ulps"]`` admits them)."""
     prompts = _prompts(cfg, PRESSURE_LENGTHS)
     big = make_llm(max_batch=4, pages=64, hot=4,
-                   scfg=SchedulerCfg(chunk_pages=1, swap=False))
+                   scfg=_one_chunk(swap=False))
     want = _run_llm(big, prompts, max_tokens=20)
     tiny = make_llm(max_batch=4, pages=bp["pressure_pages"], hot=4,
-                    scfg=SchedulerCfg(chunk_pages=1, swap=False))
+                    scfg=_one_chunk(swap=False))
     got = _run_llm(tiny, prompts, max_tokens=20)
     st = tiny.stats()
     ties = _assert_parity_or_tie(cfg, params, prompts, got, want,
@@ -183,8 +191,7 @@ def scenario_shed(make_llm, cfg, params, bp) -> str:
     are dropped at finish."""
     sp = bp["shed"]
     llm = make_llm(max_batch=2, pages=sp["pages"], hot=sp["hot"],
-                   scfg=SchedulerCfg(chunk_pages=1, swap=True,
-                                     lazy_swap=True))
+                   scfg=_one_chunk(swap=True, lazy_swap=True))
     prompts = [(np.arange(sp["prompt_len"], dtype=np.int32) + i)
                % cfg.vocab for i in range(2)]
     done = _run_llm(llm, prompts, max_tokens=sp["gen"])
@@ -242,9 +249,8 @@ def scenario_decode_sparse_pressure(make_llm, cfg, params, bp) -> str:
     # backends; a 1-page local window restores shed candidates.
     llm = make_llm(max_batch=2, pages=sp["pages"], hot=sp["hot"],
                    recent=1,
-                   scfg=SchedulerCfg(chunk_pages=1, swap=True,
-                                     lazy_swap=True, decode_hot_width=w,
-                                     kv_quant="int8"))
+                   scfg=_one_chunk(swap=True, lazy_swap=True,
+                                   decode_hot_width=w, kv_quant="int8"))
     long_prompts = [(np.arange(sp["prompt_len"], dtype=np.int32) + i)
                     % cfg.vocab for i in range(2)]
     done = _run_llm(llm, long_prompts, max_tokens=sp["gen"])
@@ -267,7 +273,7 @@ def scenario_admission(make_llm, cfg, params, bp) -> str:
     released); an impossible request is rejected at submit; max_len <=
     prompt is rejected."""
     llm = make_llm(max_batch=2, pages=32, hot=4,
-                   scfg=SchedulerCfg(chunk_pages=1))
+                   scfg=_one_chunk())
     want = _dense_oracle(cfg, params,
                          [np.arange(5, dtype=np.int32)], max_tokens=1)
     done = _run_llm(llm, [np.arange(5, dtype=np.int32)], max_tokens=1)
@@ -295,7 +301,7 @@ def scenario_streaming(make_llm, cfg, params, bp) -> str:
     request's tokens while co-resident requests keep being served, and
     metrics() reports the run."""
     llm = make_llm(max_batch=2, pages=32, hot=4,
-                   scfg=SchedulerCfg(chunk_pages=1))
+                   scfg=_one_chunk())
     h0 = llm.submit(np.arange(20, dtype=np.int32), max_tokens=6,
                     sla="interactive")
     h1 = llm.submit(np.arange(9, dtype=np.int32), max_tokens=4,
@@ -499,7 +505,7 @@ def chaos_scenario_lifecycle(make_llm, cfg, params, bp) -> str:
     expires before admission, and terminal states land in the recorder,
     timelines and per-SLA metrics."""
     llm = make_llm(max_batch=4, pages=32, hot=4,
-                   scfg=SchedulerCfg(chunk_pages=1))
+                   scfg=_one_chunk())
     tel = _attach_tel(llm)
     shared = (np.arange(40, dtype=np.int32) * 3) % cfg.vocab
     want = _dense_oracle(cfg, params, [shared], max_tokens=12)

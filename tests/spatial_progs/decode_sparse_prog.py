@@ -54,8 +54,8 @@ def run(llm):
 
 
 def spatial(width=None, kv_quant=None):
-    scfg = SchedulerCfg(chunk_pages=1, decode_hot_width=width,
-                        kv_quant=kv_quant)
+    scfg = SchedulerCfg(chunk_pages=1, prefill_tokens=16,
+                        decode_hot_width=width, kv_quant=kv_quant)
     return LLM(SpatialServingEngine(cfg, params, SpatialEngineCfg(
         n_shards=N_SHARDS, max_batch=2, page_size=16, n_pages_local=24,
         hot_pages_local=8, recent_pages=2, eos_id=-1), scfg))

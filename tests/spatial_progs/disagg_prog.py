@@ -40,7 +40,7 @@ def _decode(scfg=None):
         cfg, params,
         PagedEngineCfg(max_batch=4, page_size=16, n_pages=64,
                        hot_pages=4, eos_id=-1),
-        scfg or SchedulerCfg(chunk_pages=1))
+        scfg or SchedulerCfg(chunk_pages=1, prefill_tokens=16))
 
 
 def make_router(*, fault_plan=None, staging="device",

@@ -49,12 +49,12 @@ def reqs(lengths, max_tokens=5):
 mixed = (5, 8, 17, 33, 40)
 paged = PagedServingEngine(cfg, params, PagedEngineCfg(
     max_batch=2, page_size=16, n_pages=32, hot_pages=4, recent_pages=2,
-    eos_id=-1), SchedulerCfg(chunk_pages=1))
+    eos_id=-1), SchedulerCfg(chunk_pages=1, prefill_tokens=16))
 want = paged.run(reqs(mixed))
 sp = SpatialServingEngine(cfg, params, SpatialEngineCfg(
     n_shards=N_SHARDS, max_batch=2, page_size=16, n_pages_local=32,
     hot_pages_local=4, recent_pages=2, eos_id=-1),
-    SchedulerCfg(chunk_pages=1))
+    SchedulerCfg(chunk_pages=1, prefill_tokens=16))
 got = sp.run(reqs(mixed))
 assert got == want, f"mixed-length parity broke:\n{got}\n{want}"
 assert sp.stats()["decode_compiles"] == 1, sp.stats()["decode_compiles"]
@@ -65,7 +65,7 @@ small = 8                                     # 7 usable pages per shard
 long_prompt = (np.arange(150, dtype=np.int32) * 3 + 11) % cfg.vocab
 pg_small = PagedServingEngine(cfg, params, PagedEngineCfg(
     max_batch=2, page_size=16, n_pages=small, hot_pages=12, eos_id=-1),
-    SchedulerCfg(chunk_pages=2))
+    SchedulerCfg(chunk_pages=2, prefill_tokens=32))
 try:
     pg_small.submit(Request(rid=0, prompt=long_prompt, max_tokens=4))
     raise SystemExit("paged engine admitted an over-capacity prompt")
@@ -73,7 +73,8 @@ except ValueError:
     pass
 sp_small = SpatialServingEngine(cfg, params, SpatialEngineCfg(
     n_shards=N_SHARDS, max_batch=2, page_size=16, n_pages_local=small,
-    hot_pages_local=12, eos_id=-1), SchedulerCfg(chunk_pages=2))
+    hot_pages_local=12, eos_id=-1),
+    SchedulerCfg(chunk_pages=2, prefill_tokens=32))
 done = sp_small.run([Request(rid=0, prompt=long_prompt, max_tokens=4)])
 assert len(done[0]) == 4 and all(0 <= t < cfg.vocab for t in done[0]), done
 print(f"long-context[{N_SHARDS} shards]: OK {done[0]}")

@@ -85,8 +85,8 @@ def _dense(cfg, params, prompts):
 
 
 def _paged(cfg, params, *, width=None, kv_quant=None):
-    scfg = SchedulerCfg(chunk_pages=1, decode_hot_width=width,
-                        kv_quant=kv_quant)
+    scfg = SchedulerCfg(chunk_pages=1, prefill_tokens=16,
+                        decode_hot_width=width, kv_quant=kv_quant)
     return LLM(PagedServingEngine(cfg, params, PagedEngineCfg(
         max_batch=2, page_size=16, n_pages=48, hot_pages=8,
         recent_pages=2, eos_id=-1), scfg))

@@ -47,7 +47,7 @@ def _paged(cfg, params, *, max_batch=2, pages=32, hot=4, scfg=None):
         cfg, params,
         PagedEngineCfg(max_batch=max_batch, page_size=16, n_pages=pages,
                        hot_pages=hot, eos_id=-1),
-        scfg or SchedulerCfg(chunk_pages=1))
+        scfg or SchedulerCfg(chunk_pages=1, prefill_tokens=16))
 
 
 def _router_factory(cfg, params):
@@ -56,7 +56,8 @@ def _router_factory(cfg, params):
         pre = _paged(cfg, params, max_batch=2, pages=32,
                      scfg=SchedulerCfg(chunk_pages=1, prefill_tokens=48))
         dec = _paged(cfg, params, max_batch=4, pages=64,
-                     scfg=decode_scfg or SchedulerCfg(chunk_pages=1))
+                     scfg=decode_scfg or SchedulerCfg(chunk_pages=1,
+                                                      prefill_tokens=16))
         return DisaggRouter(pre, dec, telemetry=tel,
                             fault_plan=fault_plan, staging=staging,
                             transfer_retries=transfer_retries)
@@ -138,7 +139,7 @@ def test_wire_roundtrip(smoke_lm, tier):
     the quant flags on the peer."""
     cfg, params = smoke_lm
     scfg = lambda: SchedulerCfg(
-        chunk_pages=1,
+        chunk_pages=1, prefill_tokens=16,
         decode_hot_width=2 if tier == "int8" else None,
         kv_quant="int8" if tier == "int8" else None)
     prompt = (np.arange(40, dtype=np.int32) * 3) % cfg.vocab

@@ -143,10 +143,10 @@ def test_bucketing():
 
 
 def test_chunk_spans():
-    # monolithic: one span at the bucketed width
-    assert bucketing.chunk_spans(33, 16, None) == [(0, 33, 64)]
-    assert bucketing.chunk_spans(33, 16, None, pow2=False) == [(0, 33, 48)]
-    # short prompt: chunking never pads beyond the monolithic bucket
+    # a prompt within one chunk: one span at the bucketed width
+    assert bucketing.chunk_spans(33, 16, 4) == [(0, 33, 64)]
+    assert bucketing.chunk_spans(33, 16, 4, pow2=False) == [(0, 33, 48)]
+    # short prompt: one span at its bucketed width, not a whole chunk
     assert bucketing.chunk_spans(8, 16, 4) == [(0, 8, 16)]
     # long prompt: full chunks then a bucketed remainder, page-aligned
     spans = bucketing.chunk_spans(100, 16, 2)
@@ -485,7 +485,8 @@ def _reqs(cfg, lengths, max_tokens=5):
 def test_paged_engine_prefix_sharing_not_duplicated(smoke_lm):
     cfg, params = smoke_lm
     eng = PagedServingEngine(cfg, params, PagedEngineCfg(
-        max_batch=2, page_size=16, n_pages=32, hot_pages=4, eos_id=-1))
+        max_batch=2, page_size=16, n_pages=32, hot_pages=4, eos_id=-1),
+        SchedulerCfg(prefill_tokens=64))
     shared = np.arange(32, dtype=np.int32)           # 2 full pages
     reqs = [Request(rid=i, prompt=np.concatenate(
                 [shared, np.full((4 + i,), 100 + i, np.int32)]),
@@ -494,7 +495,7 @@ def test_paged_engine_prefix_sharing_not_duplicated(smoke_lm):
     for r in reqs:
         eng.submit(r)
     # two ticks: admission binds both slots; prefill advances one prompt
-    # per tick (prefill_per_step=1)
+    # per tick (a budget of one 4-page chunk)
     eng.step()
     eng.step()
     t0, t1 = eng.tables[0], eng.tables[1]
@@ -536,7 +537,8 @@ def test_paged_engine_per_request_max_len(smoke_lm):
 def test_paged_engine_batched_prefill_shares_same_tick_prefixes(smoke_lm):
     """Same-prefix prompts packed into the SAME batched dispatch still
     share their prefix pages (the phase-A2 dedup registers fresh full
-    pages before the dispatch), and outputs match the sequential path."""
+    pages before the dispatch), and outputs match a run that prefills
+    one chunk per tick."""
     cfg, params = smoke_lm
     shared = np.arange(32, dtype=np.int32)            # 2 full pages
     mk = lambda: [Request(rid=i, prompt=np.concatenate(
@@ -544,7 +546,7 @@ def test_paged_engine_batched_prefill_shares_same_tick_prefixes(smoke_lm):
                   max_tokens=4) for i in range(4)]
     seq = PagedServingEngine(cfg, params, PagedEngineCfg(
         max_batch=4, page_size=16, n_pages=32, hot_pages=8, eos_id=-1),
-        SchedulerCfg(chunk_pages=1))
+        SchedulerCfg(chunk_pages=1, prefill_tokens=16))
     want = seq.run(mk())
     bat = PagedServingEngine(cfg, params, PagedEngineCfg(
         max_batch=4, page_size=16, n_pages=32, hot_pages=8, eos_id=-1),
@@ -634,13 +636,15 @@ def test_star_chunk_sparse_prefill_within_tolerance():
     cache = {"k": kp, "v": vp, "k_lz": dlzs.lz_pack(kp)}
     x = jax.random.normal(ks[4], (1, c, 64), jnp.float32)
     positions = (wp * page + jnp.arange(c))[None, :]
-    past_phys = jnp.array([[1, 2, 4, 3]], jnp.int32)
-    past_logical = jnp.array([[0, 1, 2, 3]], jnp.int32)
-    past_len = jnp.array([wp * page], jnp.int32)
+    # one lane (0) in the flat buffer; its past arena holds all 4 pages
+    pack_state = {"seg_ids": jnp.zeros((c,), jnp.int32),
+                  "past_phys": jnp.array([1, 2, 4, 3], jnp.int32),
+                  "past_lane": jnp.zeros((wp,), jnp.int32),
+                  "past_logical": jnp.arange(wp, dtype=jnp.int32),
+                  "past_len": jnp.array([wp * page], jnp.int32)}
 
-    run = lambda a: attention.apply_prefill_chunk(
-        params, a, x, positions, cache, past_phys, past_logical,
-        past_len)[0]
+    run = lambda a: attention.apply_prefill_chunk_batch(
+        params, a, x, positions, cache, pack_state)[0]
     dense = run(dc.replace(acfg, star=None, chunk_sparse=False))
     sparse = run(acfg)
     keep_all = run(dc.replace(
@@ -669,7 +673,7 @@ def test_paged_engine_priority_preempts_low_first(smoke_lm):
                     % cfg.vocab, max_tokens=20, priority=0)]
     eng = PagedServingEngine(cfg, params, PagedEngineCfg(
         max_batch=4, page_size=16, n_pages=9, hot_pages=4, eos_id=-1),
-        SchedulerCfg(chunk_pages=1, swap=True))
+        SchedulerCfg(chunk_pages=1, prefill_tokens=16, swap=True))
     victims = []
     orig = eng.exec_preempt
     def spy(slot, swap):

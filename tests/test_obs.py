@@ -444,7 +444,8 @@ class TestEngineIntegration:
         p = scen.BACKEND_PARAMS["paged"]["shed"]
         tel = Telemetry()
         llm = _paged_llm(cfg, params, pages=p["pages"], hot=p["hot"],
-                         scfg=SchedulerCfg(chunk_pages=1, swap=True,
+                         scfg=SchedulerCfg(chunk_pages=1,
+                                           prefill_tokens=16, swap=True,
                                            lazy_swap=True),
                          telemetry=tel)
         for i in range(2):
@@ -682,28 +683,21 @@ def test_paged_programs_are_named(smoke_lm):
     from repro.serving import SchedulerCfg
     cfg, params = smoke_lm
     modules = {}
-    # batched varlen prefill, then the sequential chunked path
-    for prefill_tokens in (48, None):
-        llm = _paged_llm(cfg, params, pages=24, hot=4,
-                         scfg=SchedulerCfg(chunk_pages=1,
-                                           prefill_tokens=prefill_tokens),
-                         telemetry=None)
-        be = llm.engine.backend
-        for attr in ("_prefill", "_prefill_chunk", "_prefill_chunk_batch",
-                     "_decode", "_scatter"):
-            def first_call(*a, _jit=getattr(be, attr), _attr=attr):
-                if _attr not in modules:        # lower with real args
-                    modules[_attr] = _module_name(_jit.lower(*a))
-                    if _attr == "_decode":      # the audit takes the same
-                        modules["_audit"] = _module_name(be._audit.lower(*a))
-                return _jit(*a)
-            setattr(be, attr, first_call)
-        llm.submit(np.arange(40, dtype=np.int32) % cfg.vocab, max_tokens=3)
-        llm.run_until_done(max_steps=50)
-    assert modules == {"_prefill": "jit__prefill_fn",
-                       "_prefill_chunk": "jit__prefill_chunk_fn",
-                       "_prefill_chunk_batch":
-                           "jit__prefill_chunk_batch_fn",
+    llm = _paged_llm(cfg, params, pages=24, hot=4,
+                     scfg=SchedulerCfg(chunk_pages=1, prefill_tokens=48),
+                     telemetry=None)
+    be = llm.engine.backend
+    for attr in ("_prefill_chunk_batch", "_decode", "_scatter"):
+        def first_call(*a, _jit=getattr(be, attr), _attr=attr):
+            if _attr not in modules:            # lower with real args
+                modules[_attr] = _module_name(_jit.lower(*a))
+                if _attr == "_decode":          # the audit takes the same
+                    modules["_audit"] = _module_name(be._audit.lower(*a))
+            return _jit(*a)
+        setattr(be, attr, first_call)
+    llm.submit(np.arange(40, dtype=np.int32) % cfg.vocab, max_tokens=3)
+    llm.run_until_done(max_steps=50)
+    assert modules == {"_prefill_chunk_batch": "jit__prefill_chunk_batch_fn",
                        "_decode": "jit__unknown",
                        "_audit": "jit__audit_fn",
                        "_scatter": "jit__scatter_fn"}, modules

@@ -124,7 +124,7 @@ def test_audit_recall_exact_when_unbounded(smoke_lm):
     from repro.serving import SchedulerCfg
     cfg, params = smoke_lm
     llm = _paged_llm(cfg, params, pages=24, hot=4,
-                     scfg=SchedulerCfg(chunk_pages=1),
+                     scfg=SchedulerCfg(chunk_pages=1, prefill_tokens=16),
                      telemetry=Telemetry())
     eng = llm.engine
     eng.auditor = DlzsAuditor(AuditCfg(every_ticks=2))
@@ -147,7 +147,7 @@ def test_audit_disabled_is_inert(smoke_lm):
     from repro.serving import SchedulerCfg
     cfg, params = smoke_lm
     llm = _paged_llm(cfg, params, pages=24, hot=4,
-                     scfg=SchedulerCfg(chunk_pages=1),
+                     scfg=SchedulerCfg(chunk_pages=1, prefill_tokens=16),
                      telemetry=Telemetry())
     llm.engine.auditor = DlzsAuditor(AuditCfg(every_ticks=0))
     llm.submit(np.arange(20, dtype=np.int32) % cfg.vocab,
@@ -166,7 +166,7 @@ def test_watchdog_catches_injected_refcount_leak(smoke_lm):
     from repro.serving import SchedulerCfg
     cfg, params = smoke_lm
     llm = _paged_llm(cfg, params, pages=24, hot=4,
-                     scfg=SchedulerCfg(chunk_pages=1),
+                     scfg=SchedulerCfg(chunk_pages=1, prefill_tokens=16),
                      telemetry=Telemetry())
     eng = llm.engine
     for i in range(2):

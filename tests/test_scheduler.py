@@ -14,18 +14,27 @@ from repro.serving.scheduler import (AUTO_PREFILL_CHUNKS, AdmissionCfg,
 from repro.serving.swap_policy import RetryGovernor
 
 
+# one fake chunk's budget width: a ``prefill_tokens`` of ONE_CHUNK packs
+# exactly one chunk per tick
+ONE_CHUNK = 16
+
+
 class FakeEngine:
     """Host-only executor: a page budget instead of a device pool.
 
     Each sequence costs pages only while running; prefill takes
-    ``chunks`` steps of one page each, decode grows one page per step
+    ``chunks`` chunks of one page each, decode grows one page per step
     until ``decode_steps`` run out. NeedPages fires exactly like the real
-    engine: when the next page would exceed capacity.
+    engine: when the next page would exceed capacity. Prefill speaks the
+    batched varlen protocol: every chunk costs ONE_CHUNK budget tokens
+    (overridable per rid via ``widths``) and one capacity page, and a
+    batch allocates all-or-nothing like the real engine's phase A.
     """
 
     def __init__(self, capacity: int, slots: int, chunks: dict,
-                 decode_steps: dict):
+                 decode_steps: dict, widths=None):
         self.capacity = capacity
+        self.widths = widths or {}
         self.free = list(range(slots))
         self.chunks = chunks                  # rid -> prefill chunk count
         self.decode_steps = decode_steps      # rid -> decode steps to run
@@ -56,14 +65,26 @@ class FakeEngine:
     def held_pages(self, slot, shard=None):
         return self.pages.get(slot, 0)
 
-    def exec_prefill_chunk(self, slot):
-        if self._used() + 1 > self.capacity:
-            raise NeedPages(slot)
-        st = self.state[slot]
-        self.pages[slot] += 1
-        st["chunk"] += 1
-        self.log.append(("chunk", st["req"].rid))
-        return self.prefill_chunks_left(slot) == 0
+    def pending_chunk_widths(self, slot):
+        w = self.widths.get(self.state[slot]["req"].rid, ONE_CHUNK)
+        return [w] * self.prefill_chunks_left(slot)
+
+    def exec_prefill_chunk_batch(self, batch):
+        if self._used() + sum(n for _, n in batch) > self.capacity:
+            raise NeedPages(batch[0][0])
+        self.log.append(("batch", sorted(
+            self.state[s]["req"].rid for s, _ in batch)))
+        done = []
+        for slot, n in batch:
+            st = self.state[slot]
+            n = max(1, min(n, self.prefill_chunks_left(slot)))
+            self.pages[slot] += n
+            st["chunk"] += n
+            for _ in range(n):
+                self.log.append(("chunk", st["req"].rid))
+            if self.prefill_chunks_left(slot) == 0:
+                done.append(slot)
+        return done
 
     def exec_decode(self):
         decode = [s for s in self.state
@@ -112,38 +133,6 @@ class FakeEngine:
         return slot
 
 
-class BatchFakeEngine(FakeEngine):
-    """FakeEngine speaking the batched varlen prefill protocol: every
-    chunk costs 16 budget tokens (overridable per rid via ``widths``) and
-    one capacity page, and a batch allocates all-or-nothing like the real
-    engine's phase A."""
-
-    def __init__(self, *a, widths=None, **kw):
-        super().__init__(*a, **kw)
-        self.widths = widths or {}
-
-    def pending_chunk_widths(self, slot):
-        w = self.widths.get(self.state[slot]["req"].rid, 16)
-        return [w] * self.prefill_chunks_left(slot)
-
-    def exec_prefill_chunk_batch(self, batch):
-        if self._used() + sum(n for _, n in batch) > self.capacity:
-            raise NeedPages(batch[0][0])
-        self.log.append(("batch", sorted(
-            self.state[s]["req"].rid for s, _ in batch)))
-        done = []
-        for slot, n in batch:
-            st = self.state[slot]
-            n = max(1, min(n, self.prefill_chunks_left(slot)))
-            self.pages[slot] += n
-            st["chunk"] += n
-            for _ in range(n):
-                self.log.append(("chunk", st["req"].rid))
-            if self.prefill_chunks_left(slot) == 0:
-                done.append(slot)
-        return done
-
-
 class SheddingFakeEngine(FakeEngine):
     """FakeEngine with lazy cold-page swap: everything but one hot (tail)
     page of a decoding sequence is sheddable, one page per call."""
@@ -181,7 +170,7 @@ def test_scheduler_prefill_interleaves_with_decode():
     request decodes — decode never waits for the whole prompt."""
     ex = FakeEngine(capacity=100, slots=2,
                     chunks={0: 6, 1: 1}, decode_steps={0: 2, 1: 6})
-    sched = Scheduler(SchedulerCfg(prefill_per_step=1))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK))
     sched.submit(_req(0))                        # long prompt, first in line
     sched.submit(_req(1))
     done = _drain(sched, ex)
@@ -198,7 +187,7 @@ def test_scheduler_shortest_prefill_first():
     prefills first (bounds short-request TTFT)."""
     ex = FakeEngine(capacity=100, slots=2,
                     chunks={0: 5, 1: 1}, decode_steps={0: 1, 1: 1})
-    sched = Scheduler(SchedulerCfg(prefill_per_step=1))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK))
     sched.submit(_req(0))
     sched.submit(_req(1))
     sched.tick(ex)
@@ -217,7 +206,8 @@ def test_scheduler_aging_unstarves_long_prefill():
         decode[rid] = 1
     ex = FakeEngine(capacity=100, slots=3, chunks=chunks,
                     decode_steps=decode)
-    sched = Scheduler(SchedulerCfg(prefill_per_step=1, starvation_ticks=2))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK,
+                                   starvation_ticks=2))
     for rid in sorted(chunks):
         sched.submit(_req(rid))
     done = _drain(sched, ex)
@@ -234,7 +224,7 @@ def test_scheduler_token_budget_batches_prefill():
     """With ``prefill_tokens`` set, ONE batched dispatch per tick advances
     every prefilling sequence that packs under the budget — not one
     dispatch per sequence — and everything still completes."""
-    ex = BatchFakeEngine(capacity=100, slots=4,
+    ex = FakeEngine(capacity=100, slots=4,
                          chunks={0: 2, 1: 2, 2: 2, 3: 2},
                          decode_steps={r: 2 for r in range(4)})
     sched = Scheduler(SchedulerCfg(chunk_pages=1, prefill_tokens=48))
@@ -256,7 +246,7 @@ def test_scheduler_budget_head_chunk_always_advances():
     """A chunk wider than the whole budget still makes progress — it is
     dispatched alone (the flat buffer is sized to hold any single
     chunk)."""
-    ex = BatchFakeEngine(capacity=100, slots=2, chunks={0: 1, 1: 1},
+    ex = FakeEngine(capacity=100, slots=2, chunks={0: 1, 1: 1},
                          decode_steps={0: 1, 1: 1},
                          widths={0: 128, 1: 16})
     sched = Scheduler(SchedulerCfg(chunk_pages=1, prefill_tokens=32))
@@ -272,7 +262,7 @@ def test_scheduler_budget_head_chunk_always_advances():
 def test_scheduler_batched_prefill_pressure_preempts_and_finishes():
     """NeedPages from a batched dispatch picks a victim and retries with a
     re-packed batch; overload degrades, never deadlocks."""
-    ex = BatchFakeEngine(capacity=4, slots=3,
+    ex = FakeEngine(capacity=4, slots=3,
                          chunks={0: 1, 1: 1, 2: 1},
                          decode_steps={0: 3, 1: 3, 2: 3})
     sched = Scheduler(SchedulerCfg(chunk_pages=1, prefill_tokens=64,
@@ -291,7 +281,8 @@ def test_scheduler_lazy_shed_keeps_victim_running():
     (not the preemption counter) moves."""
     ex = SheddingFakeEngine(capacity=4, slots=2, chunks={0: 1, 1: 1},
                             decode_steps={0: 4, 1: 4})
-    sched = Scheduler(SchedulerCfg(swap=True, lazy_swap=True))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, swap=True,
+                                   lazy_swap=True))
     sched.submit(_req(0))
     sched.submit(_req(1))
     done = _drain(sched, ex)
@@ -309,7 +300,8 @@ def test_scheduler_lazy_shed_falls_back_to_preemption():
                     chunks={0: 1, 1: 1, 2: 1},
                     decode_steps={0: 3, 1: 3, 2: 3})
     ex.exec_shed_cold = lambda slot, shard=None: 0
-    sched = Scheduler(SchedulerCfg(swap=True, lazy_swap=True))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, swap=True,
+                                   lazy_swap=True))
     for rid in range(3):
         sched.submit(_req(rid))
     done = _drain(sched, ex)
@@ -324,7 +316,7 @@ def test_scheduler_preempts_lowest_priority_newest():
     ex = FakeEngine(capacity=4, slots=3,
                     chunks={0: 1, 1: 1, 2: 1},
                     decode_steps={0: 3, 1: 3, 2: 3})
-    sched = Scheduler(SchedulerCfg(swap=True))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, swap=True))
     sched.submit(_req(0, priority=1))
     sched.submit(_req(1, priority=0))            # victim: low prio...
     sched.submit(_req(2, priority=0))            # ...and newest
@@ -346,7 +338,7 @@ def test_scheduler_low_priority_arrival_cannot_evict_high():
     # while it runs and cannot fit until it finishes
     ex = FakeEngine(capacity=4, slots=2, chunks={0: 1, 1: 1},
                     decode_steps={0: 3, 1: 3})
-    sched = Scheduler(SchedulerCfg(swap=True))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, swap=True))
     sched.submit(_req(0, priority=5))
     sched.submit(_req(1, priority=0))
     done = _drain(sched, ex)
@@ -358,7 +350,7 @@ def test_scheduler_low_priority_arrival_cannot_evict_high():
 def test_scheduler_recompute_mode_requeues():
     ex = FakeEngine(capacity=3, slots=2, chunks={0: 1, 1: 1},
                     decode_steps={0: 2, 1: 2})
-    sched = Scheduler(SchedulerCfg(swap=False))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, swap=False))
     sched.submit(_req(0))
     sched.submit(_req(1))
     done = _drain(sched, ex)
@@ -373,7 +365,7 @@ def test_scheduler_blocked_swap_in_holds_the_line():
     fit yet but the fresh request would (no starvation of swapped work)."""
     ex = FakeEngine(capacity=4, slots=2, chunks={0: 2, 1: 1, 2: 1},
                     decode_steps={0: 2, 1: 3, 2: 1})
-    sched = Scheduler(SchedulerCfg(swap=True))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, swap=True))
     for rid in (0, 1, 2):
         sched.submit(_req(rid))
     done = _drain(sched, ex)
@@ -394,7 +386,7 @@ def test_scheduler_sla_classes_map_to_priority():
     ex = FakeEngine(capacity=4, slots=3,
                     chunks={0: 1, 1: 1, 2: 1},
                     decode_steps={0: 3, 1: 3, 2: 3})
-    sched = Scheduler(SchedulerCfg(swap=True))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, swap=True))
     sched.submit(Request(rid=0, prompt=np.arange(4, dtype=np.int32),
                          sla="interactive", out=[]))
     sched.submit(Request(rid=1, prompt=np.arange(4, dtype=np.int32),
@@ -448,7 +440,7 @@ def test_scheduler_shard_tagged_pressure_picks_shard_victim():
     ex = ShardedFakeEngine(capacity=5, slots=3,
                            chunks={0: 1, 1: 1, 2: 1},
                            decode_steps={0: 4, 1: 4, 2: 4})
-    sched = Scheduler(SchedulerCfg(swap=True))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, swap=True))
     for rid in (0, 1, 2):
         sched.submit(_req(rid))
     done = _drain(sched, ex)
@@ -502,12 +494,8 @@ def test_prefill_tokens_auto_resolution_and_scheduler_wiring():
         == AUTO_PREFILL_CHUNKS * 2 * 16
     assert resolve_prefill_tokens(
         SchedulerCfg(chunk_pages=2, prefill_tokens=48), 16) == 48
-    assert resolve_prefill_tokens(
-        SchedulerCfg(chunk_pages=None, prefill_tokens="auto"), 16) is None
-    assert resolve_prefill_tokens(
-        SchedulerCfg(chunk_pages=2, prefill_tokens=None), 16) is None
 
-    ex = BatchFakeEngine(capacity=100, slots=4,
+    ex = FakeEngine(capacity=100, slots=4,
                          chunks={0: 2, 1: 2, 2: 2, 3: 2},
                          decode_steps={r: 2 for r in range(4)})
     sched = Scheduler(SchedulerCfg(chunk_pages=1, prefill_tokens="auto"))
@@ -592,7 +580,8 @@ def test_scheduler_failed_swap_in_falls_back_to_recompute_once():
                                  chunks={0: 2, 1: 1, 2: 1},
                                  decode_steps={0: 2, 1: 3, 2: 1},
                                  fail_swap_ins=1)
-    sched = Scheduler(SchedulerCfg(swap=True, fault_retries=2))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, swap=True,
+                                   fault_retries=2))
     for rid in (0, 1, 2):
         sched.submit(_req(rid))
     done = _drain(sched, ex)
@@ -616,7 +605,8 @@ def test_scheduler_fault_budget_exhaustion_quarantines():
     ex = DecodeFaultFakeEngine(capacity=100, slots=2,
                                chunks={0: 1, 1: 1},
                                decode_steps={0: 2, 1: 4}, bad_rid=0)
-    sched = Scheduler(SchedulerCfg(fault_retries=2, fault_backoff_ticks=1))
+    sched = Scheduler(SchedulerCfg(prefill_tokens=ONE_CHUNK, fault_retries=2,
+                                   fault_backoff_ticks=1))
     sched.submit(_req(0))
     sched.submit(_req(1))
     done = _drain(sched, ex)
@@ -639,8 +629,9 @@ def test_scheduler_admission_shedding_hysteresis():
     ex = AbortLogFakeEngine(capacity=100, slots=1,
                             chunks={r: 1 for r in range(8)},
                             decode_steps={r: 2 for r in range(8)})
-    sched = Scheduler(SchedulerCfg(admission=AdmissionCfg(
-        high_watermark=4, low_watermark=2, shed_below_priority=0)))
+    sched = Scheduler(SchedulerCfg(
+        prefill_tokens=ONE_CHUNK, admission=AdmissionCfg(
+            high_watermark=4, low_watermark=2, shed_below_priority=0)))
     sched.submit(_req(0))                        # admitted immediately
     fins = sched.tick(ex)
     for rid in (1, 2):

@@ -14,17 +14,13 @@ Scenarios (~30s each on CPU):
    — the chunked-prefill + preemption scheduler must finish every request
    with ZERO rejections, swapping under pressure. Refreshes the
    ``overload`` entry of BENCH_serving.json.
-3. Batched prefill: one token-budget varlen dispatch per tick
-   (benchmarks.serving.batched_prefill) must serve at least as fast as
-   the per-sequence chunked path; refreshes the ``batched_prefill``
-   entry of BENCH_serving.json.
-4. EngineCore front door (benchmarks.serving.engine_core): the same
-   workload through ``LLM`` only must hold batched-prefill + decode
-   throughput within 5% of the directly-driven engine (the PR-4-style
-   baseline refreshed in step 3), and the ``prefill_tokens="auto"``
-   budget controller must match or beat the fixed-budget short-TTFT
-   p50. Refreshes the ``engine_core`` entry of BENCH_serving.json.
-5. Spatial: the sequence-sharded backend on a 2-shard fake-device mesh
+3. EngineCore front door (benchmarks.serving.engine_core): a mixed
+   long/short workload through ``LLM`` must hold prefill + decode
+   throughput within 5% of the directly-driven engine, and the
+   ``prefill_tokens="auto"`` budget controller must match or beat the
+   fixed-budget short-TTFT p50. Refreshes the ``engine_core`` entry of
+   BENCH_serving.json.
+4. Spatial: the sequence-sharded backend on a 2-shard fake-device mesh
    in a subprocess (tools/smoke_spatial_prog.py): front-door parity with
    the paged backend, the ultra-long admit, lazy cold-page shedding on
    the sharded pools, and front-door throughput within 5% of the direct
@@ -123,46 +119,20 @@ def overload(cfg, params) -> bool:
     return ok
 
 
-def batched(cfg, params) -> dict | None:
-    """Batched varlen chunk prefill must never serve slower than the
-    per-sequence chunked path (and keeps the chunked TTFT win); refreshes
-    the ``batched_prefill`` entry of BENCH_serving.json. Returns the
-    metrics (the engine_core scenario's baseline) or None on failure."""
-    from benchmarks import serving as bench_serving
-    t0 = time.time()
-    try:
-        m = bench_serving.batched_prefill(cfg, params)
-    except AssertionError as e:
-        print(f"smoke_serve[batched]: FAIL ({e})")
-        return None
-    ok = m["batched"]["tok_s"] >= m["sequential"]["tok_s"]
-    if ok:      # never let a failing run overwrite the committed baseline
-        bench_serving.write_json(str(REPO / "BENCH_serving.json"),
-                                 {"batched_prefill": m})
-    dt = time.time() - t0
-    print(f"smoke_serve[batched]: batched {m['batched']['tok_s']} tok/s "
-          f"vs sequential {m['sequential']['tok_s']} (monolithic "
-          f"{m['monolithic']['tok_s']}, gap "
-          f"{m['batched_vs_monolithic_gap']}x; short TTFT p50 "
-          f"{m['batched']['ttft_p50_short_ms']}ms), {dt:.1f}s "
-          f"-> {'PASS' if ok else 'FAIL'}")
-    return m if ok else None
-
-
-def engine_core(cfg, params, baseline: dict | None) -> dict | None:
+def engine_core(cfg, params) -> dict | None:
     """The unified-API no-regression check (see benchmarks.serving
-    .engine_core): LLM front door within 5% of the just-measured direct
-    baseline, auto budget controller matches/beats fixed TTFT p50."""
+    .engine_core): LLM front door within 5% of the directly-driven
+    engine, auto budget controller matches/beats fixed TTFT p50."""
     from benchmarks import serving as bench_serving
     t0 = time.time()
     try:
-        m = bench_serving.engine_core(cfg, params, baseline)
+        m = bench_serving.engine_core(cfg, params)
     except AssertionError as e:
         print(f"smoke_serve[engine_core]: FAIL ({e})")
         return None
     dt = time.time() - t0
     print(f"smoke_serve[engine_core]: LLM {m['fixed']['tok_s']} tok/s "
-          f"(gap {m.get('vs_batched_gap', '-')}x vs direct), auto-budget "
+          f"(gap {m['vs_direct_gap']}x vs direct), auto-budget "
           f"{m['auto']['tok_s']} tok/s / "
           f"{m['auto']['ttft_p50_short_ms']}ms TTFT p50 (fixed "
           f"{m['fixed']['ttft_p50_short_ms']}ms, budget "
@@ -342,7 +312,7 @@ def bundle_disagg_smoke(cfg, params, out_dir: pathlib.Path) -> bool:
                            PagedEngineCfg(max_batch=4, page_size=16,
                                           n_pages=64, hot_pages=4,
                                           eos_id=-1),
-                           SchedulerCfg(chunk_pages=1)),
+                           SchedulerCfg(chunk_pages=1, prefill_tokens=16)),
         telemetry=tel)
     for i, n in enumerate((16, 33, 16, 40)):
         llm.submit((np.arange(n, dtype=np.int32) * 3 + i) % cfg.vocab,
@@ -399,9 +369,7 @@ def main() -> int:
 
     ok = basic(cfg, params)
     ok = overload(cfg, params) and ok
-    baseline = batched(cfg, params)
-    ok = (baseline is not None) and ok
-    core = engine_core(cfg, params, baseline)
+    core = engine_core(cfg, params)
     ok = (core is not None) and ok
     sp = spatial()
     ok = (sp is not None) and ok

@@ -79,11 +79,12 @@ def submit_all(llm, lengths, max_tokens=4):
 mixed = (6, 18, 35)
 paged = LLM(PagedServingEngine(cfg, params, PagedEngineCfg(
     max_batch=2, page_size=16, n_pages=24, hot_pages=4, eos_id=-1),
-    SchedulerCfg(chunk_pages=1)))
+    SchedulerCfg(chunk_pages=1, prefill_tokens=16)))
 want = submit_all(paged, mixed)
 sp = LLM(SpatialServingEngine(cfg, params, SpatialEngineCfg(
     n_shards=2, max_batch=2, page_size=16, n_pages_local=24,
-    hot_pages_local=4, eos_id=-1), SchedulerCfg(chunk_pages=1)))
+    hot_pages_local=4, eos_id=-1),
+    SchedulerCfg(chunk_pages=1, prefill_tokens=16)))
 got = submit_all(sp, mixed)
 assert got == want, f"2-shard parity broke:\n{got}\n{want}"
 assert sp.stats()["decode_compiles"] == 1
@@ -99,7 +100,8 @@ except ValueError:
     pass
 sp_small = LLM(SpatialServingEngine(cfg, params, SpatialEngineCfg(
     n_shards=2, max_batch=2, page_size=16, n_pages_local=8,
-    hot_pages_local=12, eos_id=-1), SchedulerCfg(chunk_pages=2)))
+    hot_pages_local=12, eos_id=-1),
+    SchedulerCfg(chunk_pages=2, prefill_tokens=32)))
 h = sp_small.submit(long_prompt, max_tokens=4, rid=9)
 done = sp_small.run_until_done(max_steps=20_000)
 assert len(done[9]) == 4 and all(0 <= t < cfg.vocab for t in done[9])
@@ -108,7 +110,8 @@ assert len(done[9]) == 4 and all(0 <= t < cfg.vocab for t in done[9])
 shed = LLM(SpatialServingEngine(cfg, params, SpatialEngineCfg(
     n_shards=2, max_batch=2, page_size=16, n_pages_local=6,
     hot_pages_local=2, recent_pages=2, eos_id=-1),
-    SchedulerCfg(chunk_pages=1, swap=True, lazy_swap=True)))
+    SchedulerCfg(chunk_pages=1, prefill_tokens=16, swap=True,
+                 lazy_swap=True)))
 for i in range(2):
     shed.submit((np.arange(80, dtype=np.int32) + i) % cfg.vocab,
                 max_tokens=48, rid=i)
